@@ -369,14 +369,21 @@ def test_engine_window_abandon_is_terminal():
 # --- compile cache knob ---------------------------------------------------
 
 
-def test_ensure_compile_cache_idempotent(tmp_path):
+@pytest.fixture
+def _no_cache_env(monkeypatch):
+    """These tests place a PRIVATE cache explicitly; an inherited
+    JAX_COMPILATION_CACHE_DIR would (by the one rule) win over it."""
+    monkeypatch.delenv(profiling.COMPILE_CACHE_ENV, raising=False)
+
+
+def test_ensure_compile_cache_idempotent(tmp_path, _no_cache_env):
     d = str(tmp_path / "xla-cache")
-    assert profiling.ensure_compile_cache(d) is True
-    assert profiling.ensure_compile_cache(d) is True  # repeat: no-op
+    assert profiling.ensure_compile_cache(d) == d
+    assert profiling.ensure_compile_cache(d) == d  # repeat: no-op
     assert jax.config.jax_compilation_cache_dir == profiling._COMPILE_CACHE_DIR
 
 
-def test_compile_cache_knob_via_engine(tmp_path):
+def test_compile_cache_knob_via_engine(tmp_path, _no_cache_env):
     d = str(tmp_path / "engine-cache")
     Settings.COMPILE_CACHE_DIR = d
     fed = _fed(2)
@@ -389,7 +396,9 @@ def test_compile_cache_knob_via_engine(tmp_path):
     assert os.path.isdir(d)
 
 
-def test_cache_hit_donating_round_trains_and_checkpoint_owns_bytes(tmp_path):
+def test_cache_hit_donating_round_trains_and_checkpoint_owns_bytes(
+    tmp_path, _no_cache_env
+):
     """A persistent-cache HIT on the donating round program must still
     train, and an export_state snapshot must survive a later in-place
     donating round byte-identically. Deserialized executables (unlike
@@ -397,7 +406,7 @@ def test_cache_hit_donating_round_trains_and_checkpoint_owns_bytes(tmp_path):
     donation for real: the output is written INTO the donated input
     buffer, so any zero-copy host view of pre-round state silently
     mutates — the checkpoint path must own its bytes."""
-    assert profiling.ensure_compile_cache(str(tmp_path / "hit-cache"))
+    profiling.ensure_compile_cache(str(tmp_path / "hit-cache"))
     xs, ys = _node_data(2)
 
     def one_round(fed):

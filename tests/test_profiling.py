@@ -315,7 +315,7 @@ def test_cost_model_xla_flops_on_compiled_matmul():
 
 def test_cost_model_mfu_math():
     class FakeDev:
-        device_kind = "TPU v5e"
+        device_kind = "TPU v5 lite"
 
     # 19.7 Tflop/s against a 197 Tflop/s peak = 10% MFU.
     assert profiling.cost_model.mfu(19.7e12, FakeDev()) == pytest.approx(0.1)

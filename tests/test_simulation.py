@@ -197,11 +197,18 @@ def test_chunking_respects_max_batch_nodes(monkeypatch):
     assert sorted(chunks) == [2, 3]
 
 
-def test_isolated_fit_matches_inline():
+def test_isolated_fit_matches_inline(monkeypatch):
     """Opt-in process isolation: the spawned-worker fit reproduces the
-    inline fit exactly (same export seed, same shuffle counters)."""
+    inline fit exactly (same export seed, same shuffle counters) — and
+    the worker runs on the CPU whatever platform its parent's
+    environment names (one process per chip: a parent holding the TPU
+    must not spawn children that try to open it). The inherited value
+    here is one the child cannot open, so the fit only succeeds if
+    ``_child_init`` pinned the CPU before backend init."""
     from tpfl.simulation import isolated
 
+    isolated.shutdown()  # a pool spawned earlier predates the setenv
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
     iso = make_learner("iso-twin", n=96, seed=5)
     inline = make_learner("iso-twin", n=96, seed=5)
     for ln in (iso, inline):

@@ -1,0 +1,340 @@
+"""Ask the TPU compiler about chip_smoke's programs WITHOUT a chip.
+
+The on-chip-measurement guide's third rehearsal: compile the real
+sizes for a *described* v5e (``topologies.get_topology_desc``) from a
+CPU-only sandbox, so that what the chip's compiler refuses — a
+mis-tiled kernel, too much VMEM, a program that does not fit 16 GB —
+costs no chip time. Run before the first chip call of a PR that
+touches the engine's round program, a Pallas kernel or the mesh:
+
+    JAX_PLATFORMS=cpu python tools/chip_rehearsal.py            # all
+    JAX_PLATFORMS=cpu python tools/chip_rehearsal.py flash ring # some
+
+Nothing executes: this says nothing about results or times, and a
+compile that passes here is never reported as a chip run. The cheap
+kernel cases also live in ``tests/test_chip_compile.py`` (tier-1);
+the 20-second engine windows stay here.
+
+The engine decides kernel-vs-emulator from ``jax.default_backend()``
+(still "cpu" here), so this script steers the ONE seam that decision
+goes through, ``tpfl.parallel.compat.on_tpu``.
+"""
+
+from __future__ import annotations
+
+import os
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+import json  # noqa: E402
+import re  # noqa: E402
+import time  # noqa: E402
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+from jax.experimental import topologies  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from tpfl.parallel import compat  # noqa: E402
+
+TOPOLOGY = "v5e:2x2"
+
+
+def described_devices():
+    """The four devices of a described (not attached) v5e 2x2 host."""
+    return topologies.get_topology_desc(
+        platform="tpu", topology_name=TOPOLOGY
+    ).devices
+
+
+def force_chip_branch() -> None:
+    """Make the Pallas entry points take their TPU branch while the
+    backend is still the CPU (see module docstring)."""
+    compat.on_tpu = lambda: True
+
+
+def no_persistent_cache() -> None:
+    """A described-topology executable is written to the persistent
+    cache but cannot be read back without a chip (the next compile
+    warns and recompiles) — keep it off around these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+
+
+def compile_report(name: str, jitted, args: tuple) -> dict:
+    """Lower + compile ``jitted`` for the described chip; one JSON-able
+    line of what the compiler said."""
+    t0 = time.perf_counter()
+    compiled = jitted.lower(*args).compile()
+    dt = time.perf_counter() - t0
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    return {
+        "case": name,
+        "compile_s": round(dt, 2),
+        "tpu_custom_call": text.count("tpu_custom_call"),
+        "all_reduce": len(re.findall(r" all-reduce(-start)?\(", text)),
+        "collective_permute": len(
+            re.findall(r" collective-permute(-start)?\(", text)
+        ),
+        "argument_gb": round(mem.argument_size_in_bytes / 1e9, 3),
+        "temp_gb": round(mem.temp_size_in_bytes / 1e9, 3),
+    }
+
+
+def _sds(tree, sharding_of):
+    """ShapeDtypeStructs carrying a sharding (``sharding_of`` is one
+    sharding, or a matching pytree of them)."""
+    if isinstance(sharding_of, jax.sharding.Sharding):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding_of),
+            tree,
+        )
+    return jax.tree_util.tree_map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        tree, sharding_of,
+    )
+
+
+# --- kernels ---------------------------------------------------------------
+
+
+def flash_case(shape, dtype, device) -> tuple:
+    """(jitted fwd+bwd of the flash kernel, args) at ``shape``."""
+    from tpfl.parallel.flash_kernel import flash_attention
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=False)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=SingleDeviceSharding(device))
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))), (x, x, x)
+
+
+def ring_case(devices, seq: int = 8192) -> tuple:
+    """(jitted fwd+bwd of ring attention's flash inner over a 4-device
+    ``sp`` mesh, args)."""
+    from functools import partial
+
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from tpfl.parallel.mesh import create_mesh
+    from tpfl.parallel.ring_attention import ring_attention
+
+    mesh = create_mesh({"sp": len(devices)}, devices=devices)
+    spec = PartitionSpec(None, "sp", None, None)
+    ring = compat.shard_map(
+        partial(
+            ring_attention, axis_name="sp", causal=True, impl="flash",
+            interpret=False,
+        ),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )
+
+    def loss(q, k, v):
+        return jnp.sum(ring(q, k, v).astype(jnp.float32) ** 2)
+
+    x = jax.ShapeDtypeStruct(
+        (1, seq, 8, 64), jnp.bfloat16, sharding=NamedSharding(mesh, spec)
+    )
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))), (x, x, x)
+
+
+def node_conv_case(x_shape, cout: int, device, nodes: int = 0) -> tuple:
+    """(jitted node_conv backward, args) — ``nodes`` > 0 vmaps it over
+    a leading node axis on both operands, as ``CNN(conv_impl="pallas")``
+    inside the engine's vmapped local train does."""
+    from tpfl.parallel.conv_kernel import node_conv
+
+    def loss(x, w):
+        return jnp.sum(node_conv(x, w, False).astype(jnp.float32) ** 2)
+
+    grad = jax.grad(loss, argnums=(0, 1))
+    w_shape = (3, 3, x_shape[-1], cout)
+    if nodes:
+        grad = jax.vmap(grad)
+        x_shape, w_shape = (nodes, *x_shape), (nodes, *w_shape)
+    sh = SingleDeviceSharding(device)
+    return jax.jit(grad), (
+        jax.ShapeDtypeStruct(x_shape, jnp.bfloat16, sharding=sh),
+        jax.ShapeDtypeStruct(w_shape, jnp.bfloat16, sharding=sh),
+    )
+
+
+def lm_step_case(seq: int, device) -> tuple:
+    """(chip_smoke's jitted TransformerLM + flash SGD step, args)."""
+    import chip_smoke
+
+    lm, tx, step = chip_smoke.lm_train_step(seq)
+    toks = jnp.zeros((1, seq), jnp.int32)
+    params = jax.eval_shape(
+        lambda: lm.init(jax.random.PRNGKey(0), toks[:, :128], train=False)
+    )["params"]
+    opt = jax.eval_shape(tx.init, params)
+    sh = SingleDeviceSharding(device)
+    return jax.jit(step), (_sds(params, sh), _sds(opt, sh), _sds(toks, sh))
+
+
+# --- engine windows ----------------------------------------------------------
+
+
+def engine_case(
+    module, n_nodes: int, batch_shape: tuple, input_shape: tuple,
+    devices, mesh_axes: "dict | None" = None, n_rounds: int = 2,
+    algorithm: str = "fedavg", telemetry: bool = False, codec: int = 0,
+    x_dtype=jnp.bfloat16, y_tail: tuple = (),
+) -> tuple:
+    """(the engine's own jitted DONATING window program, abstract args)
+    for ``module`` x ``n_nodes`` — built by ``_build_program`` exactly
+    as ``dispatch_window`` fetches it, lowered on shapes (a described
+    device holds no arrays)."""
+    from tpfl.parallel import FederationEngine
+    from tpfl.parallel.mesh import (
+        create_mesh,
+        federation_sharding,
+        global_model_shardings,
+        replicated,
+        stacked_model_shardings,
+    )
+
+    mesh = create_mesh(mesh_axes, devices=devices) if mesh_axes else None
+    eng = FederationEngine(module, n_nodes, mesh=mesh, algorithm=algorithm)
+    dummy = jnp.zeros(
+        (1, *input_shape), getattr(module, "input_dtype", jnp.float32)
+    )
+    variables = jax.eval_shape(
+        lambda: eng.module.init(jax.random.PRNGKey(0), dummy, train=False)
+    )
+    n = eng.padded_nodes
+
+    def stack(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct((n, *x.shape), x.dtype), tree
+        )
+
+    params = stack(variables["params"])
+    aux = stack({k: v for k, v in variables.items() if k != "params"})
+    kind = "scaffold" if algorithm == "scaffold" else ("aux" if aux else "plain")
+    c_locals = params if kind == "scaffold" else {}
+    c_global = variables["params"] if kind == "scaffold" else {}
+
+    def state_sh(tree, stacked=True):
+        """Where the engine places model state: as _shard_state (node-
+        stacked) / _shard_global (c_global) do on this mesh."""
+        if mesh is None:
+            return SingleDeviceSharding(devices[0])
+        if eng.model_axes > 1:
+            per_leaf = (
+                stacked_model_shardings if stacked else global_model_shardings
+            )
+            return per_leaf(mesh, tree, eng.layout)
+        return federation_sharding(mesh) if stacked else replicated(mesh)
+
+    node_sh = state_sh(None) if eng.model_axes <= 1 else federation_sharding(mesh)
+    xs = jax.ShapeDtypeStruct((n, *batch_shape, *input_shape), x_dtype)
+    ys = jax.ShapeDtypeStruct((n, *batch_shape, *y_tail), jnp.int32)
+    vec = jax.ShapeDtypeStruct((n,), jnp.float32)
+    args = (
+        _sds(params, state_sh(params)),
+        _sds(c_locals, state_sh(c_locals)),
+        _sds(c_global, state_sh(c_global, stacked=False)),
+        _sds(aux, state_sh(aux)),
+        _sds(xs, node_sh), _sds(ys, node_sh),
+        _sds(vec, node_sh), _sds(vec, node_sh),
+    )
+    if eng.model_axes > 1:
+        # What _prepare_args stashes for the 2D builder's explicit
+        # in/out shardings (donation aliasing).
+        eng._arg_shardings = tuple(
+            jax.tree_util.tree_map(lambda x: x.sharding, a) for a in args
+        )
+    fn = eng._build_program(
+        kind, 1, n_rounds, 1, True, telemetry, 0, codec, 0.05,
+        eng.model_axes, eng.layout.name,
+    )
+    return fn, args
+
+
+def cases(devices) -> dict:
+    """name -> thunk returning (jitted, args). Cheap ones first."""
+    from tpfl.learning.compression import resolve_engine_codec
+    from tpfl.models import CNN, ResNet18, TransformerLM
+
+    d0 = devices[0]
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    q8 = resolve_engine_codec("quant8")
+    cnn = lambda **kw: engine_case(  # noqa: E731
+        CNN(out_channels=10), 100, (4, 128), (32, 32, 3), devices, **kw
+    )
+    return {
+        "flash_8k": lambda: flash_case((1, 8192, 8, 64), bf16, d0),
+        "flash_32k": lambda: flash_case((1, 32768, 8, 64), bf16, d0),
+        "flash_4k_h16_d128": lambda: flash_case((2, 4096, 16, 128), bf16, d0),
+        "flash_2k_f32": lambda: flash_case((1, 2048, 8, 64), f32, d0),
+        "ring_flash_sp4": lambda: ring_case(devices),
+        # Cin=3 takes node_conv's XLA fallback by design (0 kernel
+        # calls): lane padding made the Pallas stem 42x its size.
+        "node_conv_c3_fallback": lambda: node_conv_case(
+            (128, 32, 32, 3), 32, d0
+        ),
+        "node_conv_c32": lambda: node_conv_case((128, 16, 16, 32), 64, d0),
+        "node_conv_c32_vmapped": lambda: node_conv_case(
+            (128, 16, 16, 32), 64, d0, nodes=100
+        ),
+        "lm_step_8k": lambda: lm_step_case(8192, d0),
+        "engine_cnn100": lambda: cnn(n_rounds=4),
+        "engine_cnn100_obs_q8": lambda: cnn(telemetry=True, codec=q8),
+        "engine_cnn100_scaffold": lambda: cnn(algorithm="scaffold"),
+        "engine_cnn100_pallas_conv": lambda: engine_case(
+            CNN(out_channels=10, conv_impl="pallas"), 100, (4, 128),
+            (32, 32, 3), devices,
+        ),
+        "engine_resnet18x16": lambda: engine_case(
+            ResNet18(out_channels=100), 16, (2, 128), (32, 32, 3), devices
+        ),
+        "engine_cnn100_nodes4": lambda: cnn(mesh_axes={"nodes": 4}),
+        "engine_lm_nodes2_model2": lambda: engine_case(
+            TransformerLM(
+                vocab=256, dim=512, heads=8, n_layers=4, max_len=2048
+            ),
+            2, (1, 8), (2048,), devices,
+            mesh_axes={"nodes": 2, "model": 2}, x_dtype=jnp.int32,
+            y_tail=(2048,),
+        ),
+    }
+
+
+def main(argv: list[str]) -> int:
+    devices = described_devices()
+    force_chip_branch()
+    no_persistent_cache()
+    table = cases(devices)
+    wanted = [k for k in table if not argv or any(a in k for a in argv)]
+    print(json.dumps({
+        "topology": TOPOLOGY, "device_kind": devices[0].device_kind,
+        "note": "compile-only rehearsal: NOT a chip run, no times or results",
+    }))
+    failed = 0
+    for name in wanted:
+        try:
+            fn, args = table[name]()
+            print(json.dumps(compile_report(name, fn, args)), flush=True)
+        except Exception as e:  # report every refusal, then fail
+            failed += 1
+            msg = str(e).strip().splitlines()
+            print(json.dumps({
+                "case": name, "refused": type(e).__name__,
+                "why": " | ".join(msg[:6])[:1200],
+            }), flush=True)
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

@@ -1,7 +1,5 @@
 import os, time
 import jax
-jax.config.update("jax_compilation_cache_dir", "/root/repo/.jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 import jax.numpy as jnp, numpy as np
 from tpfl.parallel.flash_kernel import flash_attention
 

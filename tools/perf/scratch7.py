@@ -4,8 +4,6 @@ import time
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 
 import jax.numpy as jnp
 import numpy as np

@@ -1,7 +1,7 @@
 """Drive: asynchronous buffered rounds (PR 10) — run from the repo root:
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-        python - < logs/drive_async_verify.py
+        python - < tools/verify/drive_async_verify.py
 
 Covers: (1) the async aggregator data path over REAL wire bytes
 (staleness-weighted fold of encode->decode round-tripped models,

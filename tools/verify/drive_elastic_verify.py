@@ -3,7 +3,7 @@
 Run from the repo root under the CPU-mesh env:
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-        python - < logs/drive_elastic_verify.py
+        python - < tools/verify/drive_elastic_verify.py
 
 Covers, end to end on an 8-virtual-device mesh:
   1. churn storm through a MembershipView with ZERO recompiles at a

@@ -3,7 +3,7 @@
 Run from the repo root under the virtual 8-device CPU mesh:
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-        python - < logs/drive_engine_async_verify.py
+        python - < tools/verify/drive_engine_async_verify.py
 
 Checks, end-to-end as a consumer would drive them:
   1. pipelined == sequential, byte for byte, on the 8-device mesh —

@@ -3,7 +3,7 @@
 Run from the repo root under the CPU-mesh env:
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-        python - < logs/drive_fleetobs_verify.py
+        python - < tools/verify/drive_fleetobs_verify.py
 
 Covers, end to end on real objects (no mocks, no pytest):
 

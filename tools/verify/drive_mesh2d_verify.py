@@ -2,7 +2,7 @@
 (ISSUE 15). Run from the repo root under the CPU-mesh env:
 
     JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-        python - < logs/drive_mesh2d_verify.py
+        python - < tools/verify/drive_mesh2d_verify.py
 
 Covers: SHARD_MODEL auto-mesh resolution, the federated TransformerLM
 end-to-end on 4x2 (parity vs single device, per-device shard-bytes

@@ -1,6 +1,6 @@
 """Drive: ISSUE-14 static-analysis suite + TRACE_CONTRACTS verification.
 
-Run from the repo root: ``JAX_PLATFORMS=cpu python - < logs/drive_static_analysis_verify.py``
+Run from the repo root: ``JAX_PLATFORMS=cpu python - < tools/verify/drive_static_analysis_verify.py``
 """
 from tpfl.settings import Settings
 

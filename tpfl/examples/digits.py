@@ -23,6 +23,7 @@ import time
 
 from tpfl.communication.grpc_transport import GrpcCommunicationProtocol
 from tpfl.communication.memory import InMemoryCommunicationProtocol
+from tpfl.examples import start_on_device
 from tpfl.learning.aggregators import (
     FedAvg,
     FedMedian,
@@ -215,6 +216,7 @@ def digits(args: argparse.Namespace) -> list[Node]:
 
 def main(argv: list[str] | None = None) -> None:
     args = parse_args(argv)
+    start_on_device()
     if args.profiling:
         import cProfile
         import pstats

@@ -116,6 +116,9 @@ def run_engine(args: argparse.Namespace) -> None:
     )
     import jax
 
+    from tpfl.examples import start_on_device
+
+    start_on_device()
     Settings.set_standalone_settings()
     Settings.from_env()  # TPFL_* overrides (CLI --profile rides these)
     Settings.SHARD_NODES = True
@@ -167,6 +170,9 @@ def run_grpc(args: argparse.Namespace) -> None:
 
     if args.port is None:
         raise SystemExit("grpc mode needs --port")
+    from tpfl.examples import start_on_device
+
+    start_on_device()
     Settings.set_standalone_settings()
     Settings.from_env()  # TPFL_* overrides (CLI --profile rides these)
     node = Node(

@@ -35,6 +35,9 @@ def parse_args(argv: list[str] | None = None) -> argparse.Namespace:
 
 def main(argv: list[str] | None = None) -> None:
     args = parse_args(argv)
+    from tpfl.examples import start_on_device
+
+    start_on_device()
     Settings.set_standalone_settings()
     Settings.from_env()  # TPFL_* overrides (CLI --profile rides these)
     node = Node(

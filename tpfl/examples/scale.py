@@ -193,7 +193,11 @@ def scale(args: argparse.Namespace) -> dict[str, float]:
 
 
 def main(argv: list[str] | None = None) -> None:
-    scale(parse_args(argv))
+    from tpfl.examples import start_on_device
+
+    args = parse_args(argv)
+    start_on_device()
+    scale(args)
 
 
 if __name__ == "__main__":

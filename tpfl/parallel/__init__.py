@@ -34,6 +34,7 @@ from tpfl.parallel.mesh import (
     NODE_AXIS,
     SpecLayout,
     create_mesh,
+    device_report,
     federation_sharding,
     global_model_shardings,
     layout_for_module,
@@ -41,6 +42,7 @@ from tpfl.parallel.mesh import (
     pad_node_weights,
     padded_node_count,
     replicated,
+    require_chip,
     shard_stacked,
     stacked_model_shardings,
     transformer_layout,
@@ -87,6 +89,8 @@ def __dir__():
 
 __all__ = [
     "create_mesh",
+    "device_report",
+    "require_chip",
     "federation_sharding",
     "replicated",
     "padded_node_count",

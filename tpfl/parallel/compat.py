@@ -1,28 +1,49 @@
-"""jax API compatibility shims for the parallel layer.
+"""What the parallel layer asks of the one installation it runs on.
 
-``jax.shard_map`` (top-level, ``check_vma=`` kwarg) only exists on
-newer jax releases; older ones (e.g. 0.4.x) ship it as
-``jax.experimental.shard_map.shard_map`` with the kwarg spelled
-``check_rep``. Every shard_map call site in tpfl (and the driver's
-``__graft_entry__``) routes through :func:`shard_map` so one shim
-covers both APIs — without it the whole sp/pp/ep tier is an
-ImportError on the older runtime.
+- :func:`shard_map` — ``jax.shard_map`` under the name every call site
+  in tpfl (and ``__graft_entry__``) already imports.
+- :func:`on_tpu` / :func:`pallas_interpret` — the ONE place the Pallas
+  entry points (flash, ring, conv) decide between the compiled kernel
+  and the interpret-mode emulator. The emulator exists for CPU tests; a
+  run that was meant for the chip must never land on it silently, so
+  the automatic choice logs once at WARNING when it picks the emulator,
+  and chip paths pass ``interpret=False`` explicitly.
 """
 
 from __future__ import annotations
 
-from typing import Any
+from typing import Optional
 
 import jax
 
+shard_map = jax.shard_map
 
-def shard_map(f: Any, mesh: Any, in_specs: Any, out_specs: Any, **kw: Any):
-    """``jax.shard_map`` when available, else the experimental one with
-    ``check_vma=`` translated to its old ``check_rep=`` spelling."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
+# unguarded: a racy double-read logs the warning twice at worst.
+_warned_emulator = False
 
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+
+def on_tpu() -> bool:
+    """True when JAX's default backend is a TPU."""
+    return jax.default_backend() == "tpu"
+
+
+def pallas_interpret(interpret: Optional[bool]) -> bool:
+    """Resolve a Pallas entry point's ``interpret`` argument: an
+    explicit True/False wins; None picks the compiled kernel on a TPU
+    and the emulator elsewhere, saying so once."""
+    global _warned_emulator
+    if interpret is not None:
+        return bool(interpret)
+    if on_tpu():
+        return False
+    if not _warned_emulator:
+        _warned_emulator = True
+        from tpfl.management.logger import logger
+
+        logger.warning(
+            "_kernels",
+            "Pallas kernels run in INTERPRET mode (emulator): the default "
+            f"backend is {jax.default_backend()!r}, not a TPU. Correct, "
+            "but orders of magnitude slower — never a device measurement.",
+        )
+    return True

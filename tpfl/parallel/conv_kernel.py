@@ -35,7 +35,9 @@ actor fits (``simulation/actor_pool.py:39-66``) — here the whole
 N-node round is one XLA program and this kernel is its backward.
 
 Restrictions (asserted): stride 1, SAME padding, odd square kernel —
-what the zoo CNN uses. Interprets on CPU (tests), compiles on TPU.
+what the zoo CNN uses. Layers with fewer than ``_MIN_LANE_CHANNELS``
+input channels (an RGB stem) take the forward-style XLA backward
+instead of the kernels. Interprets on CPU (tests), compiles on TPU.
 """
 
 from __future__ import annotations
@@ -49,7 +51,17 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpfl.parallel import compat
+
 _DN = ("NHWC", "HWIO", "NHWC")
+
+#: Narrowest input-channel count the Pallas backward takes. The kernels
+#: block ``[bb, H, W, C]`` with C on the 128-wide lane axis, so HBM
+#: operands are padded to 128/C times their size: at C=3 (an RGB stem)
+#: that is 42x — the chip's compiler refused the 100-node bench CNN's
+#: round program for it (19.8 GB of 15.75 GB HBM, PR 21 rehearsal).
+#: Narrower layers take the forward-style XLA backward (identical math).
+_MIN_LANE_CHANNELS = 8
 
 
 def _pick_bb(b: int, h: int, w: int, cin: int, cout: int, k: int,
@@ -134,8 +146,9 @@ def _nc_fwd(x, w, interpret):
 
 def _nc_bwd(interpret, res, g):
     x, w = res
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    if x.shape[-1] < _MIN_LANE_CHANNELS:
+        return _fs_bwd(res, g)
+    interpret = compat.pallas_interpret(interpret)
     b, h, w_, cin = x.shape
     k, k2, _, cout = w.shape
     assert k == k2 and k % 2 == 1, "NodeConv: odd square kernels only"

@@ -300,7 +300,10 @@ def launch(
     )
     # Children must see the forced device count BEFORE importing jax:
     # scrub any inherited force flag (the parent test process runs
-    # under conftest's 8-device XLA_FLAGS) and set our own.
+    # under conftest's 8-device XLA_FLAGS) and set our own. One
+    # process per chip: these gloo workers are CPU worlds, pinned
+    # through their environment (JAX_PLATFORMS below) — a parent
+    # holding a TPU never has children that try to open it.
     xla_flags = " ".join(
         tok
         for tok in os.environ.get("XLA_FLAGS", "").split()

@@ -68,10 +68,7 @@ def ensure_distributed(
         process_id = int(os.environ.get("TPFL_PROCESS_ID", "0") or 0)
     if not coordinator_address or int(num_processes) <= 1:
         return False
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", collectives)
-    except Exception:  # pragma: no cover - older/newer jaxlib naming
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", collectives)
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=int(num_processes),

@@ -732,10 +732,12 @@ class FederationEngine:
         # from the checkpointed n_nodes (see padded_nodes).
         self.valid = valid_node_mask(self.n_nodes, self.padded_nodes)
         if Settings.COMPILE_CACHE_DIR:
-            # Persistent compilation cache (COMPILE_CACHE_DIR): warm
-            # processes reload lowered executables instead of
-            # recompiling; the observatory's
-            # tpfl_compile_cache_warm_total counts the reloads.
+            # Persistent compilation cache (COMPILE_CACHE_DIR, unless
+            # JAX_COMPILATION_CACHE_DIR already placed it — see
+            # profiling.compile_cache_dir): warm processes reload
+            # lowered executables instead of recompiling; the
+            # observatory's tpfl_compile_cache_warm_total counts the
+            # reloads.
             profiling.ensure_compile_cache(str(Settings.COMPILE_CACHE_DIR))
 
     # --- state / data placement ---
@@ -2110,7 +2112,7 @@ class FederationEngine:
             )
         return kind, args, w, scales
 
-    def donation_report(
+    def _donating_program(
         self,
         params: Any,
         xs: Any,
@@ -2120,16 +2122,12 @@ class FederationEngine:
         n_rounds: int = 1,
         aux: Optional[Any] = None,
         scaffold_state: Optional[tuple[Any, Any]] = None,
-    ) -> dict:
-        """Compiled-HLO buffer-donation inspection of the DONATING
-        round program this engine would dispatch for these inputs
-        (same ``_prepare_args`` path, same Settings-resolved
-        telemetry/codec variant): lowers and compiles the program and
-        verifies every donated state leaf (params, SCAFFOLD variates,
-        aux) is aliased to an output buffer end-to-end — the
-        train+fold fusion costs no staging copy of the model state.
-        See :func:`donation_analysis` for the report schema; CI gates
-        ``clean``."""
+    ) -> tuple[Callable, tuple]:
+        """(the DONATING round program this engine would dispatch for
+        these inputs, its placed args) — same ``_prepare_args`` path,
+        same Settings-resolved telemetry/codec variant and cache key
+        as :meth:`dispatch_window`, so an inspection can never drift
+        from the program the real dispatch runs."""
         kind, args, w, _ = self._prepare_args(
             params, xs, ys, weights, n_rounds, aux, scaffold_state, None
         )
@@ -2146,7 +2144,50 @@ class FederationEngine:
                 else int(self.population.registered)
             ),
         )
-        return donation_analysis(fn, tuple(args))
+        return fn, tuple(args)
+
+    def donation_report(
+        self,
+        params: Any,
+        xs: Any,
+        ys: Any,
+        weights: Optional[Any] = None,
+        epochs: int = 1,
+        n_rounds: int = 1,
+        aux: Optional[Any] = None,
+        scaffold_state: Optional[tuple[Any, Any]] = None,
+    ) -> dict:
+        """Compiled-HLO buffer-donation inspection of the DONATING
+        round program this engine would dispatch for these inputs:
+        lowers and compiles the program and verifies every donated
+        state leaf (params, SCAFFOLD variates, aux) is aliased to an
+        output buffer end-to-end — the train+fold fusion costs no
+        staging copy of the model state. See :func:`donation_analysis`
+        for the report schema; CI gates ``clean``."""
+        fn, args = self._donating_program(
+            params, xs, ys, weights, epochs, n_rounds, aux, scaffold_state
+        )
+        return donation_analysis(fn, args)
+
+    def compiled_hlo(
+        self,
+        params: Any,
+        xs: Any,
+        ys: Any,
+        weights: Optional[Any] = None,
+        epochs: int = 1,
+        n_rounds: int = 1,
+        aux: Optional[Any] = None,
+        scaffold_state: Optional[tuple[Any, Any]] = None,
+    ) -> str:
+        """Compiled HLO text of that same donating program — what the
+        backend's compiler actually emitted (``tpu_custom_call`` for a
+        Pallas kernel, ``collective-permute`` / ``all-reduce`` for the
+        mesh legs). Lowering executes nothing: the inputs survive."""
+        fn, args = self._donating_program(
+            params, xs, ys, weights, epochs, n_rounds, aux, scaffold_state
+        )
+        return fn.lower(*args).compile().as_text()
 
     def round(
         self,

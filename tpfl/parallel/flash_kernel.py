@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpfl.parallel import compat
+
 _NEG_INF = -1e30  # large-negative instead of -inf: exp() stays exact, no NaNs
 
 
@@ -366,8 +368,7 @@ def flash_block_fwd(
     ring attention's per-step inner (the ring merges steps by
     logsumexp, so it needs the softmax residual, not just the output).
     Not differentiable on its own: the ring defines its own VJP."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = compat.pallas_interpret(interpret)
     b, s, h, d = q.shape
     blk = ring_block_size(s, block)
     # f32 out: the ring merges steps at f32 — a per-step downcast to
@@ -395,8 +396,7 @@ def flash_block_bwd(
     over the FULL attention row (all ring steps), so per-step
     contributions recomputed here sum exactly to the global gradient.
     Returns (dq, dk, dv) in the operands' dtypes."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = compat.pallas_interpret(interpret)
     b, s, h, d = q.shape
     blk = ring_block_size(s, block)
     d_pad = -(-d // 128) * 128
@@ -440,8 +440,7 @@ def flash_attention(
     Non-causal with a sequence that doesn't divide ``block`` falls back
     to the XLA blockwise path (pad keys would need extra masking; the
     causal mask already excludes the high-position pad keys)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = compat.pallas_interpret(interpret)
     b, s, h, d = q.shape
     blk = min(block, s)
     s_pad = -(-s // blk) * blk

@@ -54,6 +54,45 @@ FSDP_AXIS = "fsdp"
 TP_AXIS = "tp"
 
 
+def device_report() -> dict[str, Any]:
+    """The device triple every printed result names, as JAX reports it:
+    ``{"platform", "kind", "count"}``."""
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+
+
+def require_chip(min_count: int = 1) -> dict[str, Any]:
+    """:func:`device_report` for a path that only means something on the
+    accelerator (``chip_smoke.py``, ``bench.py``'s timed device tiers):
+    raises instead of letting JAX's silent CPU fallback produce numbers
+    under a device metric's name. A TPU kind missing from the peaks
+    table is an error too — utilisation against an unknown peak is not
+    a default, it is a guess."""
+    from tpfl.management.profiling import PEAK_FLOPS
+
+    report = device_report()
+    if report["platform"] != "tpu":
+        raise RuntimeError(
+            f"no TPU: JAX reports platform={report['platform']!r} "
+            f"kind={report['kind']!r} — this path measures the chip and "
+            "does not fall back to the CPU"
+        )
+    if report["kind"] not in PEAK_FLOPS:
+        raise RuntimeError(
+            f"device kind {report['kind']!r} is not in the peaks table "
+            f"(tpfl.management.profiling.PEAK_FLOPS: {sorted(PEAK_FLOPS)})"
+        )
+    if report["count"] < min_count:
+        raise RuntimeError(
+            f"need {min_count} chips, JAX reports {report['count']}"
+        )
+    return report
+
+
 def create_mesh(
     axes: Optional[dict[str, int]] = None,
     devices: Optional[Sequence[jax.Device]] = None,

@@ -26,6 +26,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from tpfl.parallel import compat
+from tpfl.parallel.compat import shard_map
+
 
 def _block_attend(q, k, v, acc, row_max, denom, mask):
     """Fold one K/V block into the running (acc, row_max, denom).
@@ -493,12 +496,13 @@ def ring_attention(
             f"got {impl!r}"
         )
     if impl == "auto":
-        impl = "flash" if jax.default_backend() == "tpu" else "xla"
+        impl = "flash" if compat.on_tpu() else "xla"
     if impl == "xla":
         return _ring_xla(q, k, v, axis_name, causal)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return _ring_flash(q, k, v, axis_name, causal, block, bool(interpret))
+    return _ring_flash(
+        q, k, v, axis_name, causal, block,
+        compat.pallas_interpret(interpret),
+    )
 
 
 def make_ring_attention(
@@ -518,8 +522,6 @@ def make_ring_attention(
             f"make_ring_attention impl must be one of 'auto', 'flash', "
             f"'xla'; got {impl!r}"
         )
-    from tpfl.parallel.compat import shard_map
-
     spec = PartitionSpec(None, axis_name, None, None)
 
     fn = shard_map(
