@@ -1957,9 +1957,11 @@ def _engine_async_tier(extra: dict) -> None:
       driver removes. Both drivers run the same windows with a
       calibrated ~20 ms host leg per window (data staging stand-in);
       the sequential driver blocks, works, then dispatches (gap =
-      host leg), the pipeline overlaps (gap = the honest
-      ``is_ready``-probed prep sliver). Gate: sequential gap >= 2x
-      the pipelined gap, and pipelined bytes == sequential bytes.
+      host leg); the pipeline reports NO gaps since PR 24 (it no
+      longer probes ``is_ready`` on its hot loop; device idleness is
+      the device trace's, PERF.md), so the gap gate is vacuous and
+      what this sub-tier still checks is pipelined bytes ==
+      sequential bytes.
     - extra.engine_async_determinism: two same-seed pipelined fedbuff
       runs end byte-identical — in-process at 1 device, and (CPU
       single-device hosts) in an 8-forced-virtual-device subprocess
@@ -2128,7 +2130,10 @@ def _engine_async_tier(extra: dict) -> None:
                     data_for=staged, prefetch=True,
                 )
                 assert done == RP
-                return tree_bytes(result[0]), list(pipe.idle_gaps)
+                # The pipeline no longer infers device idleness on the
+                # host (PERF.md, PR 24: the device trace's
+                # device_idle_pct reads it): no gaps to report.
+                return tree_bytes(result[0]), []
 
             run_sequential()  # warm: compile both window shapes
             seq_bytes, seq_gaps = run_sequential()

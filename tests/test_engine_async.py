@@ -11,6 +11,7 @@ device-mask lowering is deterministic; (d) pipeline shutdown (natural
 end AND mid-run interrupt) leaks no prefetch threads.
 """
 
+import contextlib
 import threading
 
 import jax
@@ -398,3 +399,112 @@ def test_fedbuff_feeds_async_controller():
     k, deadline = ctrl.round_open(3, n)
     assert 1 <= k <= n
     assert deadline > 0
+
+
+# --- the program's own names (ISSUE 24) -------------------------------------
+
+DISPATCH_SPANS = [
+    "tpfl:prepare_args", "tpfl:program_lookup", "tpfl:program_call",
+    "tpfl:dispatch",
+]
+FINALIZE_SPANS = ["tpfl:tele_fetch", "tpfl:replay_window", "tpfl:finalize"]
+
+
+def _engine_spans():
+    from tpfl.management.telemetry import flight
+
+    return [
+        e for e in flight.snapshot("engine")
+        if e["kind"] == "span" and e["name"].startswith("tpfl:")
+    ]
+
+
+def test_engine_spans_of_one_window_and_of_a_pipeline_run():
+    """Under TELEMETRY_ENABLED the engine's host phases land in the
+    ``engine`` flight ring under exactly the fixed names, each tagged
+    with its window's first round and nested under its parent."""
+    from tpfl.management.telemetry import flight
+
+    Settings.ENGINE_TELEMETRY = True
+    Settings.TELEMETRY_ENABLED = True
+    eng = _engine(4)
+    p = eng.init_params((28, 28))
+    dx, dy = eng.shard_data(*_data(4))
+    flight.clear("engine")
+    handle = eng.dispatch_window(p, dx, dy, n_rounds=2, donate=False)
+    handle.wait()
+    handle.finalize()
+    handle.finalize()  # the cached return is no second span
+    spans = _engine_spans()
+    assert [e["name"] for e in spans] == (
+        DISPATCH_SPANS + ["tpfl:wait"] + FINALIZE_SPANS
+    )
+    assert {e["trace"] for e in spans} == {"r0"}
+    by_name = {e["name"]: e for e in spans}
+    for child in DISPATCH_SPANS[:3]:
+        assert by_name[child]["parent"] == by_name["tpfl:dispatch"]["span"]
+    for child in FINALIZE_SPANS[:2]:
+        assert by_name[child]["parent"] == by_name["tpfl:finalize"]["span"]
+    assert by_name["tpfl:dispatch"]["parent"] == ""
+
+    flight.clear("engine")
+    WindowPipeline(eng).run(
+        p, dx, dy, n_rounds=4, window=2, donate=False,
+        data_for=lambda widx, start, k: None, prefetch=False,
+        snapshot_every=1, snapshot_to=lambda rounds, state: None,
+    )
+    spans = _engine_spans()
+    window = ["tpfl:data_take"] + DISPATCH_SPANS
+    assert [e["name"] for e in spans] == (
+        window + ["tpfl:pipeline_window"]  # window 0: nothing to finalize yet
+        + ["tpfl:snapshot"] + window + FINALIZE_SPANS + ["tpfl:pipeline_window"]
+        + FINALIZE_SPANS + ["tpfl:pipeline_window"]  # the closing finalize
+        + ["tpfl:snapshot"]
+    )
+    # The engine has run 2 rounds before: the windows start at 2 and 4.
+    assert [e["trace"] for e in spans if e["name"] == "tpfl:pipeline_window"] == [
+        "r2", "r4", "r4",
+    ]
+    assert [e["trace"] for e in spans if e["name"] == "tpfl:finalize"] == ["r2", "r4"]
+    windows = {e["span"] for e in spans if e["name"] == "tpfl:pipeline_window"}
+    for e in spans:
+        if e["name"] in ("tpfl:dispatch", "tpfl:finalize", "tpfl:data_take"):
+            assert e["parent"] in windows, e
+
+
+def test_round_body_legs_are_named_scopes_and_change_no_byte(monkeypatch):
+    """The five scopes are in the lowered program's debug metadata, each
+    only where its leg is compiled, and are metadata only: the same
+    seeded rounds traced with ``jax.named_scope`` disabled give the same
+    bytes and the same program text."""
+    scopes = (
+        "tpfl.train", "tpfl.optimizer", "tpfl.codec", "tpfl.telemetry",
+        "tpfl.fold",
+    )
+
+    def lower_and_run():
+        eng = _engine(4)
+        p = eng.init_params((28, 28))
+        dx, dy = eng.shard_data(*_data(4))
+        fn, args = eng._donating_program(p, dx, dy, n_rounds=2)
+        lowered = fn.lower(*args)
+        out = eng.run_rounds(p, dx, dy, n_rounds=2, donate=False)
+        return lowered.as_text(debug_info=True), lowered.as_text(), _bytes(out)
+
+    dense_debug, _, _ = lower_and_run()
+    assert "tpfl.train" in dense_debug and "tpfl.fold" in dense_debug
+    assert "tpfl.codec" not in dense_debug and "tpfl.telemetry" not in dense_debug
+
+    Settings.ENGINE_TELEMETRY = True
+    Settings.ENGINE_WIRE_CODEC = "quant8"
+    debug, text, out = lower_and_run()
+    for scope in scopes:
+        assert scope in debug, scope
+    assert "tpfl." not in text  # nothing but metadata carries them
+
+    monkeypatch.setattr(
+        jax, "named_scope", lambda name: contextlib.nullcontext()
+    )
+    bare_debug, bare_text, bare_out = lower_and_run()
+    assert not any(scope in bare_debug for scope in scopes)
+    assert bare_text == text and bare_out == out

@@ -349,6 +349,19 @@ def test_hbm_tracker_high_water_mark():
     assert tracker.peaks() == {"7": 300.0}
 
 
+def test_hbm_tracker_gauges_the_reserved_peak_where_reported():
+    from tpfl.management.telemetry import metrics
+
+    key = ("tpfl_hbm_peak_bytes_reserved", (("device", "hbm-test-9"),))
+    tracker = profiling.HbmTracker()
+    tracker.observe("hbm-test-9", {"bytes_in_use": 100})
+    assert key not in metrics.fold()["gauges"]  # not reported: no gauge
+    tracker.observe(
+        "hbm-test-9", {"bytes_in_use": 100, "peak_bytes_reserved": 900}
+    )
+    assert metrics.fold()["gauges"][key] == 900.0
+
+
 # --- perf regression gate -------------------------------------------------
 
 

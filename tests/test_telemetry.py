@@ -244,6 +244,52 @@ def test_span_gating_and_ring_bound():
         flight.clear("gate-n")
 
 
+def test_engine_span_off_records_nothing():
+    """Off, an engine span is only the profiler's annotation (a flag
+    test outside a profiler session): the flight ring stays empty."""
+    flight.clear("engine")
+    Settings.TELEMETRY_ENABLED = False
+    with tracing.engine_span("dispatch", 0):
+        with tracing.engine_span("prepare_args", 0):
+            pass
+    assert flight.snapshot("engine") == []
+
+
+def test_engine_span_on_records_window_and_parent():
+    flight.clear("engine")
+    Settings.TELEMETRY_ENABLED = True
+    try:
+        with tracing.engine_span("dispatch", 12):
+            with tracing.engine_span("prepare_args", 12):
+                pass
+            with tracing.engine_span("program_call", 12):
+                pass
+        with tracing.engine_span("wait", 12):
+            pass
+        with pytest.raises(ValueError):
+            with tracing.engine_span("finalize", 12):
+                raise ValueError("boom")
+        with tracing.engine_span("dispatch", 16):  # the stack was unwound
+            pass
+        spans = flight.snapshot("engine")
+    finally:
+        Settings.TELEMETRY_ENABLED = False
+        flight.clear("engine")
+    # Children close (and are recorded) before their parent.
+    assert [e["name"] for e in spans] == [
+        "tpfl:prepare_args", "tpfl:program_call", "tpfl:dispatch",
+        "tpfl:wait", "tpfl:finalize", "tpfl:dispatch",
+    ]
+    assert all(e["kind"] == "span" and e["node"] == "engine" for e in spans)
+    assert [e["trace"] for e in spans] == ["r12"] * 5 + ["r16"]
+    assert all(e["t0"] <= e["t1"] for e in spans)
+    args, call, dispatch, wait, finalize, later = spans
+    assert args["parent"] == call["parent"] == dispatch["span"]
+    assert dispatch["parent"] == wait["parent"] == later["parent"] == ""
+    assert dispatch["t0"] <= args["t0"] and call["t1"] <= dispatch["t1"]
+    assert finalize["error"].startswith("ValueError")
+
+
 def test_payload_tid_roundtrip_all_versions():
     import numpy as np
 

@@ -877,8 +877,10 @@ class HbmTracker:
     def sample(self) -> list[tuple[str, float, float]]:
         """[(device_id, bytes_in_use, peak_bytes)] for every local
         device exposing ``memory_stats``; updates the registry gauges
-        (``tpfl_hbm_bytes_in_use`` / ``tpfl_hbm_peak_bytes``, labeled
-        by device). Host-side reads only — zero device dispatches."""
+        (``tpfl_hbm_bytes_in_use`` / ``tpfl_hbm_peak_bytes``, and
+        ``tpfl_hbm_peak_bytes_reserved`` where the backend reports
+        ``peak_bytes_reserved``; labeled by device). Host-side reads
+        only — zero device dispatches."""
         if "jax" not in sys.modules:
             return []  # never the import that drags a backend in
         out: list[tuple[str, float, float]] = []
@@ -909,6 +911,14 @@ class HbmTracker:
         labels = {"device": dev}
         metrics.gauge("tpfl_hbm_bytes_in_use", in_use, labels=labels)
         metrics.gauge("tpfl_hbm_peak_bytes", peak, labels=labels)
+        if "peak_bytes_reserved" in stats:
+            # What a program RESERVED at its largest, temporaries
+            # included — several times the live arrays above, and the
+            # figure that decides whether a federation fits the chip.
+            metrics.gauge(
+                "tpfl_hbm_peak_bytes_reserved",
+                float(stats["peak_bytes_reserved"]), labels=labels,
+            )
         return dev, in_use, peak
 
     def observe(self, dev: str, stats: dict) -> tuple[str, float, float]:

@@ -31,10 +31,12 @@ bench.py telemetry tier), so timelines from repeated runs line up.
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import struct
+import threading
 import time
-from typing import Any, Optional
+from typing import Any, ContextManager, Iterator, Optional
 
 import msgpack
 
@@ -142,6 +144,48 @@ def maybe_span(
     return _Span(name, node, trace, attrs)
 
 
+#: What every engine span's name starts with, in the profiler's trace
+#: and in the flight ring alike.
+ENGINE_SPAN_PREFIX = "tpfl:"
+# Span ids of the engine spans open on this thread, outermost first.
+_open_engine_spans = threading.local()
+
+
+def engine_span(name: str, window: int) -> ContextManager[Any]:
+    """One host phase of an engine window (``tpfl:<name>``), on the
+    profiler's clock: always a ``jax.profiler.TraceAnnotation`` — with
+    no profiler session open that is a flag test — so a trace taken
+    through ``Settings.PROFILING_TRACE_DIR`` shows the engine's phases
+    beside the device's operations. With ``Settings.TELEMETRY_ENABLED``
+    the same phase is also a :class:`_Span` in the ``engine`` flight
+    ring: ``trace`` is ``r<window>`` (the window's first round — one id
+    per window) and ``parent`` the id of the engine span open around it
+    on this thread (``""`` for an outermost one)."""
+    import jax  # lazy: the gossip path traces without touching a backend
+
+    annotation = jax.profiler.TraceAnnotation(ENGINE_SPAN_PREFIX + name)
+    if not Settings.TELEMETRY_ENABLED:
+        return annotation
+    return _recorded_engine_span(annotation, name, window)
+
+
+@contextlib.contextmanager
+def _recorded_engine_span(
+    annotation: ContextManager[Any], name: str, window: int
+) -> Iterator[_Span]:
+    stack = _open_engine_spans.__dict__.setdefault("ids", [])
+    span = _Span(
+        ENGINE_SPAN_PREFIX + name, "engine", f"r{int(window)}",
+        {"parent": stack[-1] if stack else ""},
+    )
+    stack.append(span._entry["span"])
+    try:
+        with annotation, span:
+            yield span
+    finally:
+        stack.pop()
+
+
 def event(name: str, node: str, trace: str = "", **attrs: Any) -> None:
     """A point-in-time record (retry, breaker trip, quorum
     degradation) in the node's flight ring."""
@@ -207,7 +251,9 @@ def payload_trace_id(payload: Any) -> str:
 
 
 __all__ = [
+    "ENGINE_SPAN_PREFIX",
     "enabled",
+    "engine_span",
     "event",
     "export",
     "maybe_span",

@@ -108,7 +108,7 @@ from tpfl.learning.jax_learner import (
     default_optimizer,
     make_train_step,
 )
-from tpfl.management import profiling
+from tpfl.management import profiling, tracing
 from tpfl.parallel import ranksafe
 from tpfl.parallel.compat import shard_map
 from tpfl.parallel.distributed import global_put, is_multiprocess
@@ -455,10 +455,11 @@ class EngineWindow:
         """Block until the window's device work completes — the
         pipeline's ready-timestamp probe for the device-idle-gap
         accounting (and nothing else: finalize does the host work)."""
-        # host-sync: deliberate ready-probe — the pipeline calls this
-        # AFTER dispatching the next window, so the block measures
-        # device completion, never stalls the dispatch queue.
-        jax.block_until_ready(self._outs[4])
+        with tracing.engine_span("wait", self._window_start):
+            # host-sync: deliberate ready-probe — the pipeline calls this
+            # AFTER dispatching the next window, so the block measures
+            # device completion, never stalls the dispatch queue.
+            jax.block_until_ready(self._outs[4])
 
     # --- the window's host work ------------------------------------------
 
@@ -469,60 +470,63 @@ class EngineWindow:
         cached tuple."""
         if self._finalized:
             return self._result
-        out_params, out_c, out_cg, out_aux, losses = self._outs
-        if self._prof:
-            jax.block_until_ready(losses)
-            t2 = time.monotonic()
-            # The dispatch gap is paid ONCE for the whole window — the
-            # engine's core claim, visible in tpfl_round_attr_seconds.
-            # The window ordinal targets THIS window's open profiler
-            # record: under the pipeline, window N+1's record opened
-            # (at dispatch) before window N's closes here.
-            profiling.rounds.add(self._node_tag, "dispatch",
-                                 self._t1 - self._t0, round=self._ordinal)
-            profiling.rounds.add(self._node_tag, "train", t2 - self._t1,
-                                 round=self._ordinal)
-            profiling.rounds.end_round(self._node_tag, self._ordinal)
-        tele = self._tele
-        if tele is not None and any(
-            hasattr(v, "is_fully_addressable") and not v.is_fully_addressable
-            for v in tele.values()
-        ):
-            # Multi-process window: the per-node telemetry rows are
-            # sharded across processes, so no process holds the full
-            # window — the observatory fan-out is a single-host plane
-            # (documented in docs/scaling.md); run cross-host windows
-            # with ENGINE_TELEMETRY off, or read the per-process
-            # registry series instead.
-            tele = None
-        if tele is not None:
-            # One host sync per WINDOW — and when the non-blocking D2H
-            # copy (started at dispatch) has landed, not even that:
-            # np.asarray reads the host-resident buffer.
-            from tpfl.management import engine_obs
+        with tracing.engine_span("finalize", self._window_start):
+            out_params, out_c, out_cg, out_aux, losses = self._outs
+            if self._prof:
+                jax.block_until_ready(losses)
+                t2 = time.monotonic()
+                # The dispatch gap is paid ONCE for the whole window — the
+                # engine's core claim, visible in tpfl_round_attr_seconds.
+                # The window ordinal targets THIS window's open profiler
+                # record: under the pipeline, window N+1's record opened
+                # (at dispatch) before window N's closes here.
+                profiling.rounds.add(self._node_tag, "dispatch",
+                                     self._t1 - self._t0, round=self._ordinal)
+                profiling.rounds.add(self._node_tag, "train", t2 - self._t1,
+                                     round=self._ordinal)
+                profiling.rounds.end_round(self._node_tag, self._ordinal)
+            tele = self._tele
+            if tele is not None and any(
+                hasattr(v, "is_fully_addressable") and not v.is_fully_addressable
+                for v in tele.values()
+            ):
+                # Multi-process window: the per-node telemetry rows are
+                # sharded across processes, so no process holds the full
+                # window — the observatory fan-out is a single-host plane
+                # (documented in docs/scaling.md); run cross-host windows
+                # with ENGINE_TELEMETRY off, or read the per-process
+                # registry series instead.
+                tele = None
+            if tele is not None:
+                # One host sync per WINDOW — and when the non-blocking D2H
+                # copy (started at dispatch) has landed, not even that:
+                # np.asarray reads the host-resident buffer.
+                from tpfl.management import engine_obs
 
-            eng = self._engine
-            host_tele = {k: np.asarray(v) for k, v in tele.items()}
-            engine_obs.replay_window(
-                self._node_tag,
-                profiling.module_tag(eng.module),
-                self._window_start,
-                host_tele,
-                eng.n_nodes,
-                weights=np.asarray(self._w),
-                wall_seconds=time.monotonic() - self._t0,
-                dispatch_seconds=self._t1 - self._t0,
-                controller=eng.controller,
-            )
-        if self._kind == "scaffold":
-            result: tuple = (out_params, out_aux, (out_c, out_cg), losses)
-        elif self._has_aux:
-            result = (out_params, out_aux, losses)
-        else:
-            result = (out_params, losses)
-        self._finalized = True
-        self._result = result
-        return result
+                eng = self._engine
+                with tracing.engine_span("tele_fetch", self._window_start):
+                    host_tele = {k: np.asarray(v) for k, v in tele.items()}
+                with tracing.engine_span("replay_window", self._window_start):
+                    engine_obs.replay_window(
+                        self._node_tag,
+                        profiling.module_tag(eng.module),
+                        self._window_start,
+                        host_tele,
+                        eng.n_nodes,
+                        weights=np.asarray(self._w),
+                        wall_seconds=time.monotonic() - self._t0,
+                        dispatch_seconds=self._t1 - self._t0,
+                        controller=eng.controller,
+                    )
+            if self._kind == "scaffold":
+                result: tuple = (out_params, out_aux, (out_c, out_cg), losses)
+            elif self._has_aux:
+                result = (out_params, out_aux, losses)
+            else:
+                result = (out_params, losses)
+            self._finalized = True
+            self._result = result
+            return result
 
     def abandon(self) -> None:
         """Drop an in-flight window WITHOUT its host leg (no telemetry
@@ -1147,12 +1151,13 @@ class FederationEngine:
                     (loss, new_a), grads = jax.value_and_grad(
                         loss_of, has_aux=True
                     )(p)
-                if kind == "scaffold":
-                    grads = jax.tree_util.tree_map(
-                        lambda g, c: g + c.astype(g.dtype), grads, corr
-                    )
-                updates, o = opt.update(grads, o, p)
-                p = optax.apply_updates(p, updates)
+                with jax.named_scope("tpfl.optimizer"):
+                    if kind == "scaffold":
+                        grads = jax.tree_util.tree_map(
+                            lambda g, c: g + c.astype(g.dtype), grads, corr
+                        )
+                    updates, o = opt.update(grads, o, p)
+                    p = optax.apply_updates(p, updates)
                 return (p, o, new_a), loss
 
             if epochs <= 0:  # static: aggregation-only round
@@ -1453,11 +1458,12 @@ class FederationEngine:
 
         def round_body(params, c_locals, c_global, aux, xs, ys, w, valid,
                        scale, arrive, tau):
-            trained, new_c, new_aux, losses = jax.vmap(
-                lambda p, ci, a, x, y: local_train(
-                    p, ci, c_global, a, x, y, epochs
-                )
-            )(params, c_locals, aux, xs, ys)
+            with jax.named_scope("tpfl.train"):
+                trained, new_c, new_aux, losses = jax.vmap(
+                    lambda p, ci, a, x, y: local_train(
+                        p, ci, c_global, a, x, y, epochs
+                    )
+                )(params, c_locals, aux, xs, ys)
             if fedbuff:
                 # FedBuff intake: only ARRIVING nodes fold this round,
                 # each weighted by the gRPC aggregator's staleness
@@ -1467,43 +1473,52 @@ class FederationEngine:
                 sw = (1.0 + tau) ** f32(-stale_exp)
                 w = w * arrive * sw
             if a_ndim:
-                trained = jax.tree_util.tree_map(
-                    lambda t: (
-                        scale.reshape((-1,) + (1,) * (t.ndim - 1)).astype(
-                            t.dtype
-                        )
-                        * t
-                    ),
-                    trained,
-                )
+                # Under the exchange leg's scope: what a node puts on
+                # the wire is scaled here, before the codec sees it.
+                with jax.named_scope("tpfl.codec"):
+                    trained = jax.tree_util.tree_map(
+                        lambda t: (
+                            scale.reshape((-1,) + (1,) * (t.ndim - 1)).astype(
+                                t.dtype
+                            )
+                            * t
+                        ),
+                        trained,
+                    )
             if codec:
                 # The exchange leg: every node's contribution passes
                 # the wire round-trip BEFORE stats and fold, so the
                 # telemetry carry and the psum both see exactly what a
                 # receiver would decode.
-                trained = jax.tree_util.tree_map(
-                    lambda t: jax.vmap(codec_fn)(t), trained
-                )
+                with jax.named_scope("tpfl.codec"):
+                    trained = jax.tree_util.tree_map(
+                        lambda t: jax.vmap(codec_fn)(t), trained
+                    )
             if telemetry:
-                upd = jax.tree_util.tree_map(
-                    lambda t, p: t.astype(f32) - p.astype(f32),
-                    trained, params,
+                with jax.named_scope("tpfl.telemetry"):
+                    upd = jax.tree_util.tree_map(
+                        lambda t, p: t.astype(f32) - p.astype(f32),
+                        trained, params,
+                    )
+                    t_sq = per_node_sq(trained)
+                    s_sq = per_node_sq(params)
+                    node_stats = {
+                        "update_norm": jnp.sqrt(per_node_sq(upd)),
+                        "cos_ref": per_node_dot(trained, params)
+                        / jnp.sqrt(jnp.maximum(t_sq * s_sq, 1e-12)),
+                    }
+                    if fedbuff:
+                        # τ on arrival rounds, −1 on in-flight rounds —
+                        # so the host fan-out distinguishes "arrived
+                        # fresh" (τ=0) from "did not arrive".
+                        node_stats["staleness"] = (
+                            tau * arrive - (1.0 - arrive)
+                        )
+            with jax.named_scope("tpfl.fold"):
+                out_params, out_c, out_cg, out_aux = fold(
+                    trained, new_c, new_aux, c_locals, c_global, aux, w,
+                    valid,
                 )
-                t_sq = per_node_sq(trained)
-                s_sq = per_node_sq(params)
-                node_stats = {
-                    "update_norm": jnp.sqrt(per_node_sq(upd)),
-                    "cos_ref": per_node_dot(trained, params)
-                    / jnp.sqrt(jnp.maximum(t_sq * s_sq, 1e-12)),
-                }
-                if fedbuff:
-                    # τ on arrival rounds, −1 on in-flight rounds — so
-                    # the host fan-out distinguishes "arrived fresh"
-                    # (τ=0) from "did not arrive".
-                    node_stats["staleness"] = tau * arrive - (1.0 - arrive)
-            out_params, out_c, out_cg, out_aux = fold(
-                trained, new_c, new_aux, c_locals, c_global, aux, w, valid
-            )
             if fedbuff:
                 # Only arrivals take the fold broadcast; stragglers
                 # keep their local training (params, variates, aux) —
@@ -1516,69 +1531,73 @@ class FederationEngine:
                         new, local,
                     )
 
-                out_params = jax.tree_util.tree_map(
-                    took_fold, out_params, trained
-                )
-                if kind == "scaffold":
-                    out_c = jax.tree_util.tree_map(took_fold, out_c, new_c)
-                if kind != "plain":
-                    out_aux = jax.tree_util.tree_map(
-                        took_fold, out_aux, new_aux
+                with jax.named_scope("tpfl.fold"):
+                    out_params = jax.tree_util.tree_map(
+                        took_fold, out_params, trained
                     )
+                    if kind == "scaffold":
+                        out_c = jax.tree_util.tree_map(
+                            took_fold, out_c, new_c
+                        )
+                    if kind != "plain":
+                        out_aux = jax.tree_util.tree_map(
+                            took_fold, out_aux, new_aux
+                        )
             if telemetry:
-                # out_params rows are IDENTICAL by construction (the
-                # fold broadcasts the aggregate to every node), so the
-                # global-model stats need one row per device, not the
-                # full [n, P] sweep: row 0 of each local shard,
-                # mean-reduced over devices by the same masked-mean
-                # machinery (all devices hold the same aggregate; their
-                # round-start rows coincide after the first fold).
-                first = valid * (
-                    jnp.arange(valid.shape[0]) == 0
-                ).astype(f32)
-                moved_sq = jnp.zeros((), f32)
-                out_sq = jnp.zeros((), f32)
-                for o, p in zip(
-                    jax.tree_util.tree_leaves(out_params),
-                    jax.tree_util.tree_leaves(params),
-                ):
-                    o0 = o[0].astype(f32)
-                    p0 = p[0].astype(f32)
-                    moved_sq = moved_sq + jnp.sum((o0 - p0) ** 2)
-                    out_sq = out_sq + jnp.sum(o0 * o0)
-                zero = jnp.zeros((valid.shape[0],), f32)
-                participation = psum_(jnp.sum((w > 0).astype(f32)))
-                # Per-node wire payload bytes under the active codec —
-                # a static constant of the leaf shapes (computed at
-                # trace time from the SAME per-leaf policy the host
-                # payload path applies); the per-round series is
-                # participation-dependent and rides the carry.
-                bpm = compression.wire_bytes_per_model(
-                    jax.tree_util.tree_map(
-                        lambda t: jax.ShapeDtypeStruct(
-                            t.shape[1:], t.dtype
+                with jax.named_scope("tpfl.telemetry"):
+                    # out_params rows are IDENTICAL by construction (the
+                    # fold broadcasts the aggregate to every node), so the
+                    # global-model stats need one row per device, not the
+                    # full [n, P] sweep: row 0 of each local shard,
+                    # mean-reduced over devices by the same masked-mean
+                    # machinery (all devices hold the same aggregate; their
+                    # round-start rows coincide after the first fold).
+                    first = valid * (
+                        jnp.arange(valid.shape[0]) == 0
+                    ).astype(f32)
+                    moved_sq = jnp.zeros((), f32)
+                    out_sq = jnp.zeros((), f32)
+                    for o, p in zip(
+                        jax.tree_util.tree_leaves(out_params),
+                        jax.tree_util.tree_leaves(params),
+                    ):
+                        o0 = o[0].astype(f32)
+                        p0 = p[0].astype(f32)
+                        moved_sq = moved_sq + jnp.sum((o0 - p0) ** 2)
+                        out_sq = out_sq + jnp.sum(o0 * o0)
+                    zero = jnp.zeros((valid.shape[0],), f32)
+                    participation = psum_(jnp.sum((w > 0).astype(f32)))
+                    # Per-node wire payload bytes under the active codec —
+                    # a static constant of the leaf shapes (computed at
+                    # trace time from the SAME per-leaf policy the host
+                    # payload path applies); the per-round series is
+                    # participation-dependent and rides the carry.
+                    bpm = compression.wire_bytes_per_model(
+                        jax.tree_util.tree_map(
+                            lambda t: jax.ShapeDtypeStruct(
+                                t.shape[1:], t.dtype
+                            ),
+                            trained,
                         ),
-                        trained,
-                    ),
-                    codec, topk_frac,
-                )
-                round_stats = {
-                    "delta_norm": masked_mean(
-                        zero.at[0].set(jnp.sqrt(moved_sq)), first
-                    ),
-                    "model_norm": masked_mean(
-                        zero.at[0].set(jnp.sqrt(out_sq)), first
-                    ),
-                    "participation": participation,
-                    "weight_mass": psum_(jnp.sum(w.astype(f32))),
-                    "wire_bytes": participation * f32(bpm),
-                }
-                if host_axis is not None:
-                    # The DCN leg ships ONE model-shaped partial per
-                    # host per round (the fold's cross-host
-                    # all-reduce), codec'd like the node exchange —
-                    # same per-model bytes constant, hosts copies.
-                    round_stats["dcn_bytes"] = f32(hosts) * f32(bpm)
+                        codec, topk_frac,
+                    )
+                    round_stats = {
+                        "delta_norm": masked_mean(
+                            zero.at[0].set(jnp.sqrt(moved_sq)), first
+                        ),
+                        "model_norm": masked_mean(
+                            zero.at[0].set(jnp.sqrt(out_sq)), first
+                        ),
+                        "participation": participation,
+                        "weight_mass": psum_(jnp.sum(w.astype(f32))),
+                        "wire_bytes": participation * f32(bpm),
+                    }
+                    if host_axis is not None:
+                        # The DCN leg ships ONE model-shaped partial per
+                        # host per round (the fold's cross-host
+                        # all-reduce), codec'd like the node exchange —
+                        # same per-model bytes constant, hosts copies.
+                        round_stats["dcn_bytes"] = f32(hosts) * f32(bpm)
                 return (
                     out_params, out_c, out_cg, out_aux, losses,
                     (node_stats, round_stats),
@@ -1610,15 +1629,23 @@ class FederationEngine:
 
         def tele_write(tele, r, losses, node_stats, round_stats):
             tele = dict(tele)
-            tele["loss"] = tele["loss"].at[r].set(losses.astype(f32))
-            for k, v in node_stats.items():
-                tele[k] = tele[k].at[r].set(v)
-            for k, v in round_stats.items():
-                tele[k] = tele[k].at[r].set(v)
+            with jax.named_scope("tpfl.telemetry"):
+                tele["loss"] = tele["loss"].at[r].set(losses.astype(f32))
+                for k, v in node_stats.items():
+                    tele[k] = tele[k].at[r].set(v)
+                for k, v in round_stats.items():
+                    tele[k] = tele[k].at[r].set(v)
             return tele
 
-        def multi(params, c_locals, c_global, aux, xs, ys, weights, valid,
-                  *extra):
+        # The function's name is the XLA module's (``jit_tpfl_window``):
+        # what a profiler trace lists the window under. It is also the
+        # only part of the named scopes' change that the persistent
+        # compile cache can see — scope names are debug metadata, which
+        # JAX strips from the cache key, so under the old name a warm
+        # cache would serve the executable compiled BEFORE the scopes
+        # existed, with none of them in it (PERF.md, PR 24).
+        def tpfl_window(params, c_locals, c_global, aux, xs, ys, weights,
+                        valid, *extra):
             extra = list(extra)
             scales = extra.pop(0) if a_ndim else None
             arrivals, taus = (
@@ -1673,7 +1700,7 @@ class FederationEngine:
             return lax.fori_loop(0, n_rounds, body, init)
 
         if not sharded:
-            return multi
+            return tpfl_window
 
         if host_axis is not None:
             # 3D mesh: the stacked node axis shards over hosts x nodes
@@ -1710,7 +1737,7 @@ class FederationEngine:
                 tele_specs["dcn_bytes"] = repl
             out_specs = out_specs + (tele_specs,)
         return shard_map(
-            multi,
+            tpfl_window,
             mesh=mesh,
             in_specs=tuple(in_specs),
             out_specs=out_specs,
@@ -2296,105 +2323,111 @@ class FederationEngine:
         :meth:`EngineWindow.finalize`, which the pipeline overlaps
         with the next window's device time. :meth:`run_rounds` ==
         ``dispatch_window(...).finalize()``."""
-        kind, args, w, scales = self._prepare_args(
-            params, xs, ys, weights, n_rounds, aux, scaffold_state,
-            attack_scales, schedule,
-        )
-        if donate is None:
-            donate = bool(Settings.ENGINE_DONATE)
-        tele_on, codec, frac = self._resolve_variant()
-        a_ndim = 0 if scales is None else int(scales.ndim)
-        fedbuff = schedule is not None
-        # Resolved at DISPATCH (0.0 for sync windows) and threaded into
-        # the cache key: the staleness exponent is a trace-time
-        # constant of the fedbuff fold weighting.
-        stale_exp = (
-            float(Settings.ASYNC_STALENESS_EXP) if fedbuff else 0.0
-        )
-        model_axes, mesh_layout = self.model_axes, self.layout.name
-        # The elastic key axes, resolved at dispatch like the knobs:
-        # the padded capacity tier this window is shaped for, and the
-        # mesh's node-axis size the lowering closed over — a tier
-        # promotion or a restore onto another mesh shape must select
-        # its own cache slot, never mutate a compiled program. The
-        # cross-host / cross-device axes follow suit: the hosts-axis
-        # size the two-level psum closed over, and the registered
-        # population census the window's cohort was sampled from.
-        capacity = int(self.padded_nodes)
-        mesh_nodes = mesh_axis_size(self.mesh)
-        mesh_hosts = mesh_axis_size(self.mesh, HOST_AXIS)
-        pop_size = (
-            0 if self.population is None else int(self.population.registered)
-        )
-        fn = self._wrapped_program(
-            kind, epochs, n_rounds, w.ndim, donate, tele_on, a_ndim,
-            codec, frac, model_axes, mesh_layout, fedbuff, stale_exp,
-            capacity, mesh_nodes, mesh_hosts, pop_size,
-        )
-        if Settings.TRACE_CONTRACTS:
-            # Dispatch-time contract: the fetched program's build-time
-            # stamp must match THIS dispatch's resolved knob values.
-            concurrency.check_contract(
-                fn,
-                {
-                    "ENGINE_TELEMETRY": bool(tele_on),
-                    "ENGINE_WIRE_CODEC": int(codec),
-                    "WIRE_TOPK_FRAC": float(frac),
-                    "ENGINE_DONATE": bool(donate),
-                    "SHARD_MODEL": int(model_axes),
-                    "SHARD_LAYOUT": str(mesh_layout),
-                    "ASYNC_STALENESS_EXP": float(stale_exp),
-                    "SHARD_HOSTS": int(mesh_hosts),
-                    "POPULATION_CLIENTS": int(pop_size),
-                },
-            )
-        if Settings.RANK_CONTRACTS:
-            # Dispatch receipt: append this program's (cache key,
-            # lowered-HLO fingerprint) digest to the per-process
-            # ordered log — crosshost.launch compares the sequences
-            # across ranks (tpfl.parallel.ranksafe, the rank pass's
-            # runtime half).
-            receipt_key = (
-                kind, int(epochs), int(n_rounds), int(w.ndim),
-                bool(donate), bool(tele_on), int(a_ndim), int(codec),
-                float(frac), int(model_axes), str(mesh_layout),
-                bool(fedbuff), float(stale_exp), int(capacity),
-                int(mesh_nodes), int(mesh_hosts), int(pop_size),
-            )
-            ranksafe.record_dispatch(
-                receipt_key, self._hlo_digest(receipt_key, args)
-            )
-
-        prof = profiling.rounds.enabled()
-        node_tag = f"engine:{profiling.module_tag(self.module)}"
         window_start = self._rounds_done
-        if prof:
-            self._windows += 1
-            profiling.rounds.begin_round(node_tag, self._windows)
-        t0 = time.monotonic() if (prof or tele_on) else 0.0
-        try:
-            out = fn(*args)
-        except Exception as e:
-            self._dump_flight(e, kind, n_rounds)
-            raise
-        tele = None
-        if tele_on:
-            out_params, out_c, out_cg, out_aux, losses, tele = out
-            # Start the carry's device→host copy NOW, non-blocking:
-            # it lands while the device (and the host) move on, so
-            # finalize's np.asarray reads host memory instead of
-            # stalling the dispatch pipeline.
-            start_host_copy(tele)
-        else:
-            out_params, out_c, out_cg, out_aux, losses = out
-        self._rounds_done += n_rounds
-        t1 = time.monotonic() if (prof or tele_on) else 0.0
-        return EngineWindow(
-            self, kind, aux is not None,
-            (out_params, out_c, out_cg, out_aux, losses), tele, w,
-            n_rounds, window_start, self._windows, prof, node_tag,
-            t0, t1,
-        )
+        with tracing.engine_span("dispatch", window_start):
+            with tracing.engine_span("prepare_args", window_start):
+                kind, args, w, scales = self._prepare_args(
+                    params, xs, ys, weights, n_rounds, aux, scaffold_state,
+                    attack_scales, schedule,
+                )
+            with tracing.engine_span("program_lookup", window_start):
+                if donate is None:
+                    donate = bool(Settings.ENGINE_DONATE)
+                tele_on, codec, frac = self._resolve_variant()
+                a_ndim = 0 if scales is None else int(scales.ndim)
+                fedbuff = schedule is not None
+                # Resolved at DISPATCH (0.0 for sync windows) and threaded into
+                # the cache key: the staleness exponent is a trace-time
+                # constant of the fedbuff fold weighting.
+                stale_exp = (
+                    float(Settings.ASYNC_STALENESS_EXP) if fedbuff else 0.0
+                )
+                model_axes, mesh_layout = self.model_axes, self.layout.name
+                # The elastic key axes, resolved at dispatch like the knobs:
+                # the padded capacity tier this window is shaped for, and the
+                # mesh's node-axis size the lowering closed over — a tier
+                # promotion or a restore onto another mesh shape must select
+                # its own cache slot, never mutate a compiled program. The
+                # cross-host / cross-device axes follow suit: the hosts-axis
+                # size the two-level psum closed over, and the registered
+                # population census the window's cohort was sampled from.
+                capacity = int(self.padded_nodes)
+                mesh_nodes = mesh_axis_size(self.mesh)
+                mesh_hosts = mesh_axis_size(self.mesh, HOST_AXIS)
+                pop_size = (
+                    0 if self.population is None else int(self.population.registered)
+                )
+                fn = self._wrapped_program(
+                    kind, epochs, n_rounds, w.ndim, donate, tele_on, a_ndim,
+                    codec, frac, model_axes, mesh_layout, fedbuff, stale_exp,
+                    capacity, mesh_nodes, mesh_hosts, pop_size,
+                )
+                if Settings.TRACE_CONTRACTS:
+                    # Dispatch-time contract: the fetched program's build-time
+                    # stamp must match THIS dispatch's resolved knob values.
+                    concurrency.check_contract(
+                        fn,
+                        {
+                            "ENGINE_TELEMETRY": bool(tele_on),
+                            "ENGINE_WIRE_CODEC": int(codec),
+                            "WIRE_TOPK_FRAC": float(frac),
+                            "ENGINE_DONATE": bool(donate),
+                            "SHARD_MODEL": int(model_axes),
+                            "SHARD_LAYOUT": str(mesh_layout),
+                            "ASYNC_STALENESS_EXP": float(stale_exp),
+                            "SHARD_HOSTS": int(mesh_hosts),
+                            "POPULATION_CLIENTS": int(pop_size),
+                        },
+                    )
+                if Settings.RANK_CONTRACTS:
+                    # Dispatch receipt: append this program's (cache key,
+                    # lowered-HLO fingerprint) digest to the per-process
+                    # ordered log — crosshost.launch compares the sequences
+                    # across ranks (tpfl.parallel.ranksafe, the rank pass's
+                    # runtime half).
+                    receipt_key = (
+                        kind, int(epochs), int(n_rounds), int(w.ndim),
+                        bool(donate), bool(tele_on), int(a_ndim), int(codec),
+                        float(frac), int(model_axes), str(mesh_layout),
+                        bool(fedbuff), float(stale_exp), int(capacity),
+                        int(mesh_nodes), int(mesh_hosts), int(pop_size),
+                    )
+                    ranksafe.record_dispatch(
+                        receipt_key, self._hlo_digest(receipt_key, args)
+                    )
+
+            prof = profiling.rounds.enabled()
+            node_tag = f"engine:{profiling.module_tag(self.module)}"
+            if prof:
+                self._windows += 1
+                profiling.rounds.begin_round(node_tag, self._windows)
+            t0 = time.monotonic() if (prof or tele_on) else 0.0
+            # A new program is traced and compiled inside its first
+            # call: a recompile shows as one long program_call.
+            with tracing.engine_span("program_call", window_start):
+                try:
+                    out = fn(*args)
+                except Exception as e:
+                    self._dump_flight(e, kind, n_rounds)
+                    raise
+                tele = None
+                if tele_on:
+                    out_params, out_c, out_cg, out_aux, losses, tele = out
+                    # Start the carry's device→host copy NOW, non-blocking:
+                    # it lands while the device (and the host) move on, so
+                    # finalize's np.asarray reads host memory instead of
+                    # stalling the dispatch pipeline.
+                    start_host_copy(tele)
+                else:
+                    out_params, out_c, out_cg, out_aux, losses = out
+            self._rounds_done += n_rounds
+            t1 = time.monotonic() if (prof or tele_on) else 0.0
+            return EngineWindow(
+                self, kind, aux is not None,
+                (out_params, out_c, out_cg, out_aux, losses), tele, w,
+                n_rounds, window_start, self._windows, prof, node_tag,
+                t0, t1,
+            )
 
     def _hlo_digest(self, key: tuple, args: tuple) -> str:
         """Lowered-HLO fingerprint of the cached program behind

@@ -21,11 +21,11 @@ run the engine free:
            | stage N+2's data on the prefetch thread ; dispatch N+2 ...
 
 Steady state: the device's dispatch queue is never empty, so dispatch
-RTT and host work vanish from wall clock; the measured inter-window
-device-idle gap (:attr:`WindowPipeline.idle_gaps`, fed from the
-``jax.Array.is_ready`` probe before each dispatch) collapses to the
-argument-prep sliver — the ``engine_async`` bench tier gates the ≥2x
-cut vs sequential dispatch.
+RTT and host work vanish from wall clock. How idle the device really
+is between windows is read from a device trace, not inferred here
+(``device_idle_pct`` and the idle time by ``tpfl:`` span, PERF.md): each
+loop iteration is a ``tpfl:pipeline_window`` span
+(:func:`tpfl.management.tracing.engine_span`) around its children.
 
 Determinism: the pipeline reorders HOST work only — the device sees
 the identical program sequence over the identical buffers, so
@@ -48,11 +48,10 @@ every take and on shutdown — no thread outlives :meth:`run`.
 from __future__ import annotations
 
 import threading
-import time
 from typing import Any, Callable, Optional
 
 from tpfl import concurrency
-from tpfl.management.telemetry import metrics
+from tpfl.management import tracing
 from tpfl.parallel.engine import (
     EngineWindow,
     FederationEngine,
@@ -153,20 +152,6 @@ class WindowPrefetcher:
             t.join()
 
 
-def _outputs_ready(window: EngineWindow) -> bool:
-    """True when the window's device work has provably completed (the
-    non-blocking ``jax.Array.is_ready`` probe; backends without it
-    report False — unknown counts as busy, so the idle-gap accounting
-    under-reports rather than invents idleness)."""
-    probe = getattr(window.losses, "is_ready", None)
-    if probe is None:
-        return False
-    try:
-        return bool(probe())
-    except Exception:
-        return False
-
-
 class WindowPipeline:
     """Free-running multi-window driver over one engine.
 
@@ -179,11 +164,6 @@ class WindowPipeline:
     over the same per-window data.
 
     Attributes:
-        idle_gaps: measured device-idle gap (seconds) before each
-            dispatch after the first — the time the device's queue sat
-            provably empty while the host prepared the next window
-            (see :func:`_outputs_ready`). The ``engine_async`` bench
-            tier compares these against the sequential driver's gaps.
         windows_run: dispatched window count from the last :meth:`run`.
     """
 
@@ -191,11 +171,9 @@ class WindowPipeline:
         self.engine = engine
         # unguarded: written only by the run() thread; cross-thread
         # readers (bench/tests) read after run() returns.
-        # ephemeral: per-run diagnostics — every run() resets them; the
+        # ephemeral: per-run diagnostic — every run() resets it; the
         # durable cadence state rides the engine snapshot
         # (_materialize_snapshot -> engine.export_state).
-        self.idle_gaps: list[float] = []
-        # ephemeral: per-run diagnostics (see idle_gaps).
         self.windows_run = 0
         # Cross-thread stop flag (interrupt_for / Node.stop) — honored
         # at exactly the between-dispatch granularity should_stop is.
@@ -300,7 +278,6 @@ class WindowPipeline:
             if (prefetch and data_for is not None)
             else None
         )
-        self.idle_gaps = []
         self.windows_run = 0
         self._abort.clear()
         if owner is not None:
@@ -318,97 +295,91 @@ class WindowPipeline:
         cur_xs, cur_ys = xs, ys
         try:
             while done < int(n_rounds):
-                if snap_pending is not None:
-                    self._materialize_snapshot(snap_pending, snapshot_to)
-                    snap_pending = None
-                if self._abort.is_set() or (
-                    should_stop is not None and should_stop()
-                ):
-                    break
-                k = min(window, int(n_rounds) - done)
-                if weights_for is not None:
-                    # The elastic re-mask seam: membership churn since
-                    # the last window lands here as a weight-vector
-                    # edit — same program, same shapes, zero recompile.
-                    w = weights_for(widx)
-                    per_round_w = getattr(w, "ndim", 1) == 2
-                # This window's data: taken from the prefetch thread
-                # (staged while the previous window ran) or computed
-                # inline — same supplier, same bytes.
-                if data_for is not None:
-                    staged = (
-                        prefetcher.take(widx)
-                        if (prefetcher is not None and widx > 0)
-                        else data_for(widx, done, k)
-                    )
-                    if staged is not None:
-                        cur_xs, cur_ys = staged
-                idle_probe = pending is not None and _outputs_ready(pending)
-                t_probe = time.monotonic()
-                handle = eng.dispatch_window(
-                    params,
-                    cur_xs,
-                    cur_ys,
-                    weights=(w[done:done + k] if per_round_w else w),
-                    epochs=epochs,
-                    n_rounds=k,
-                    aux=aux,
-                    scaffold_state=scaffold_state,
-                    donate=donate,
-                    schedule=(
-                        None if schedule is None else schedule.window(done, k)
-                    ),
-                )
-                t_disp = time.monotonic()
-                if pending is not None:
-                    # Idle-gap accounting: if the previous window's
-                    # outputs were ALREADY ready before we started
-                    # building this dispatch, the device queue sat
-                    # empty at least for the prep sliver we just
-                    # measured; otherwise the queue never drained.
-                    self.idle_gaps.append(
-                        (t_disp - t_probe) if idle_probe else 0.0
-                    )
-                # Stage the NEXT window's data while the device works
-                # and before this host thread dives into finalize.
-                nxt = done + k
-                if prefetcher is not None and nxt < int(n_rounds):
-                    prefetcher.start(
-                        widx + 1, nxt, min(window, int(n_rounds) - nxt)
-                    )
-                if pending is not None:
-                    # Window N's host leg (telemetry replay, profiler
-                    # rows) overlaps window N+1's device leg.
-                    result = pending.finalize()
-                # Chain the output futures straight into the next
-                # dispatch — the double buffer: with donation on these
-                # are the only live copy of the federation state.
-                params = handle.params
-                if scaffold:
-                    aux = handle.aux
-                    scaffold_state = handle.scaffold_state
-                elif has_aux:
-                    aux = handle.aux
-                pending = handle
-                done += k
-                widx += 1
-                self.windows_run += 1
-                if snap_every and widx % snap_every == 0:
-                    # Cadence checkpoint: start the non-blocking D2H
-                    # copy NOW (it completes while the device runs this
-                    # window); np.asarray at the next loop top reads
-                    # host memory — the copy_to_host_async host leg.
-                    start_host_copy(params)
-                    if aux is not None:
-                        start_host_copy(aux)
-                    if scaffold:
-                        start_host_copy(scaffold_state)
-                    snap_pending = (
-                        done,
+                # The window about to be dispatched, by its first round
+                # (the engine's own count: what its spans are tagged with).
+                first = eng._rounds_done
+                with tracing.engine_span("pipeline_window", first):
+                    if snap_pending is not None:
+                        with tracing.engine_span("snapshot", first):
+                            self._materialize_snapshot(snap_pending, snapshot_to)
+                        snap_pending = None
+                    if self._abort.is_set() or (
+                        should_stop is not None and should_stop()
+                    ):
+                        break
+                    k = min(window, int(n_rounds) - done)
+                    if weights_for is not None:
+                        # The elastic re-mask seam: membership churn since
+                        # the last window lands here as a weight-vector
+                        # edit — same program, same shapes, zero recompile.
+                        w = weights_for(widx)
+                        per_round_w = getattr(w, "ndim", 1) == 2
+                    # This window's data: taken from the prefetch thread
+                    # (staged while the previous window ran) or computed
+                    # inline — same supplier, same bytes.
+                    if data_for is not None:
+                        with tracing.engine_span("data_take", first):
+                            staged = (
+                                prefetcher.take(widx)
+                                if (prefetcher is not None and widx > 0)
+                                else data_for(widx, done, k)
+                            )
+                        if staged is not None:
+                            cur_xs, cur_ys = staged
+                    handle = eng.dispatch_window(
                         params,
-                        aux,
-                        scaffold_state if scaffold else None,
+                        cur_xs,
+                        cur_ys,
+                        weights=(w[done:done + k] if per_round_w else w),
+                        epochs=epochs,
+                        n_rounds=k,
+                        aux=aux,
+                        scaffold_state=scaffold_state,
+                        donate=donate,
+                        schedule=(
+                            None if schedule is None else schedule.window(done, k)
+                        ),
                     )
+                    # Stage the NEXT window's data while the device works
+                    # and before this host thread dives into finalize.
+                    nxt = done + k
+                    if prefetcher is not None and nxt < int(n_rounds):
+                        prefetcher.start(
+                            widx + 1, nxt, min(window, int(n_rounds) - nxt)
+                        )
+                    if pending is not None:
+                        # Window N's host leg (telemetry replay, profiler
+                        # rows) overlaps window N+1's device leg.
+                        result = pending.finalize()
+                    # Chain the output futures straight into the next
+                    # dispatch — the double buffer: with donation on these
+                    # are the only live copy of the federation state.
+                    params = handle.params
+                    if scaffold:
+                        aux = handle.aux
+                        scaffold_state = handle.scaffold_state
+                    elif has_aux:
+                        aux = handle.aux
+                    pending = handle
+                    done += k
+                    widx += 1
+                    self.windows_run += 1
+                    if snap_every and widx % snap_every == 0:
+                        # Cadence checkpoint: start the non-blocking D2H
+                        # copy NOW (it completes while the device runs this
+                        # window); np.asarray at the next loop top reads
+                        # host memory — the copy_to_host_async host leg.
+                        start_host_copy(params)
+                        if aux is not None:
+                            start_host_copy(aux)
+                        if scaffold:
+                            start_host_copy(scaffold_state)
+                        snap_pending = (
+                            done,
+                            params,
+                            aux,
+                            scaffold_state if scaffold else None,
+                        )
         finally:
             if owner is not None:
                 with _ACTIVE_LOCK:
@@ -424,16 +395,14 @@ class WindowPipeline:
                     pending.abandon()
                     result = None
                 else:
-                    result = pending.finalize()
+                    with tracing.engine_span(
+                        "pipeline_window", pending._window_start
+                    ):
+                        result = pending.finalize()
         if snap_pending is not None and not self._abort.is_set():
             # The run ended with a copy still in flight (final window
             # hit the cadence): no further dispatch will donate these
             # buffers, so materializing here is safe and loses nothing.
-            self._materialize_snapshot(snap_pending, snapshot_to)
-        if self.idle_gaps:
-            metrics.gauge(
-                "tpfl_engine_idle_gap_seconds",
-                float(sum(self.idle_gaps) / len(self.idle_gaps)),
-                labels={"driver": "pipeline"},
-            )
+            with tracing.engine_span("snapshot", eng._rounds_done):
+                self._materialize_snapshot(snap_pending, snapshot_to)
         return result, done

@@ -472,7 +472,7 @@ class Settings:
     sets), ``ratio(a, b)`` (counter ``a`` per counter ``b`` —
     e.g. DCN bytes per engine round) and ``op`` one of ``< <= > >=``.
     Example: ``"rate(tpfl_engine_rounds_total) >= 2.0;
-    gauge(tpfl_engine_idle_gap_seconds) <= 0.5"``. Signals are
+    gauge(tpfl_engine_loss) <= 2.5"``. Signals are
     EWMA-smoothed (``SLO_EWMA``); ``SLO_BREACH_WINDOWS`` consecutive
     violating evaluations emit a ``slo_breach`` flight event and bump
     ``tpfl_slo_breach_total`` — bench's offline baseline gate brought
