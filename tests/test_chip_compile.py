@@ -65,3 +65,46 @@ def test_narrow_stem_takes_the_xla_backward(described):
     fn, args = rehearsal.cases(described)["node_conv_c3_fallback"]()
     report = rehearsal.compile_report("node_conv_c3_fallback", fn, args)
     assert report["tpu_custom_call"] == 0, report
+
+
+def test_lm_head_owns_its_loss_in_the_compiled_window(described):
+    """A window of GPT-2 small's width and vocabulary (one block, two
+    silos) compiled for the described v5e: with the head owning its
+    loss, no float32 array of the logits' full ``[.., seq, vocab]``
+    extent is materialised, no gather reads one (the label's logit is
+    a compare-and-select), and the bias gradient comes out of a matmul
+    (on the TPU a ``convolution`` at the root of an output fusion), not
+    a reduction of its own."""
+    import re
+
+    from tpfl.models.head_loss import _ONES_ROWS
+
+    fn, args = rehearsal.cases(described)["engine_gpt2_head_x2"]()
+    text = fn.lower(*args).compile().as_text()
+    ops = rehearsal.estimated_operations(text)  # what is materialised
+    full_logits = re.compile(r"\[[\d,]*1024,50257\]")
+    assert any(full_logits.search(op["shape"]) for op in ops)  # bf16 ones are
+    wide = [op for op in ops if re.search(r"f32\[[\d,]*1024,50257\]", op["shape"])]
+    assert not wide, wide
+
+    shape_of = {
+        m.group(1): m.group(2)
+        for m in map(rehearsal.OP_HEAD.match, text.splitlines()) if m
+    }
+    for line in text.splitlines():
+        if " gather(" in line:
+            operand = re.search(r" gather\(%?([\w.\-]+)", line).group(1)
+            assert not full_logits.search(shape_of.get(operand, "")), line
+
+    bias_grads = [
+        op for op in ops if op["shape"] == f"f32[{_ONES_ROWS},2,50257]"
+        and op["op_name_tail"].endswith("head_cross_entropy/dot_general")
+    ]
+    assert len(bias_grads) == 1, [op["shape"] for op in ops][:40]
+    line = next(
+        ln for ln in text.splitlines()
+        if re.match(rf"\s*%{re.escape(bias_grads[0]['name'])} = ", ln)
+    )
+    called = re.search(r"calls=(%[\w.\-]+)", line).group(1)
+    start = text.index(f"\n{called} (")
+    assert " convolution(" in text[start:text.index("\n}", start)]

@@ -9,6 +9,17 @@ touches the engine's round program, a Pallas kernel or the mesh:
 
     JAX_PLATFORMS=cpu python tools/chip_rehearsal.py            # all
     JAX_PLATFORMS=cpu python tools/chip_rehearsal.py flash ring # some
+    JAX_PLATFORMS=cpu python tools/chip_rehearsal.py --ops 30 gpt2s_silo_1chip
+
+``--ops N`` also lists each compiled program's N largest operations by
+the compiler's own ``estimated_cycles`` (name, output shape, the tail
+of its ``op_name``, cycles, and milliseconds at the clock that
+``benchmark/peaks.json``'s peak implies) — a ranking of variants
+between chip calls, NOT a time: the estimates ran 1.0x to 1.45x of
+the measured time for matmuls and 3.5x for an element-wise pass
+(PERF.md §5, PR 26). A name of
+``BENCHMARK.json``'s ``workloads`` selects that cell's window program
+(``benchmark/rehearse.py::window_program``).
 
 Nothing executes: this says nothing about results or times, and a
 compile that passes here is never reported as a chip run. The cheap
@@ -42,6 +53,15 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 from tpfl.parallel import compat  # noqa: E402
 
 TOPOLOGY = "v5e:2x2"
+#: Multiply-accumulates a cycle of one chip's matrix units (v5e: four
+#: 128 x 128 MXUs); with the peaks table's FLOP/s it gives the clock
+#: that turns ``estimated_cycles`` into milliseconds.
+MXU_MACS_PER_CYCLE = {"TPU v5 lite": 4 * 128 * 128}
+#: ``%name = <result shape> opcode(`` at the head of an HLO instruction.
+OP_HEAD = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) [\w\-]+\(")
+_OP_CYCLES = re.compile(r'"estimated_cycles":"(\d+)"')
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_LAYOUT = re.compile(r"\{[^{}]*\}")
 
 
 def described_devices():
@@ -67,9 +87,44 @@ def no_persistent_cache() -> None:
     compilation_cache.reset_cache()
 
 
-def compile_report(name: str, jitted, args: tuple) -> dict:
+def clock_hz(device_kind: str) -> float:
+    """The matrix units' clock implied by the benchmark's peaks table."""
+    from benchmark import cells
+
+    peak = cells.load_peaks(device_kind)["bf16_flops_per_s"]
+    return peak / (2 * MXU_MACS_PER_CYCLE[device_kind])
+
+
+def estimated_operations(text: str) -> list:
+    """The operations of a compiled program's text that carry the TPU
+    compiler's ``estimated_cycles`` — those that run as operations of
+    their own: fusions, copies, reductions, not what sits inside a
+    fusion — largest first. One inside a loop counts once, as it stands
+    in the text; ``conditional`` branches and custom calls carry no
+    estimate."""
+    rows = []
+    for line in text.splitlines():
+        cycles = _OP_CYCLES.search(line)
+        head = OP_HEAD.match(line)
+        if not (cycles and head):
+            continue
+        op_name = _OP_NAME.search(line)
+        rows.append({
+            "name": head.group(1),
+            "shape": _LAYOUT.sub("", head.group(2)),
+            "op_name_tail": "/".join(
+                op_name.group(1).split("/")[-3:]) if op_name else "",
+            "cycles": int(cycles.group(1)),
+        })
+    return sorted(rows, key=lambda r: -r["cycles"])
+
+
+def compile_report(
+    name: str, jitted, args: tuple, ops: int = 0, hz: float = 0.0
+) -> dict:
     """Lower + compile ``jitted`` for the described chip; one JSON-able
-    line of what the compiler said."""
+    line of what the compiler said (with ``ops``, its largest
+    operations under ``estimated``)."""
     t0 = time.perf_counter()
     compiled = jitted.lower(*args).compile()
     dt = time.perf_counter() - t0
@@ -85,6 +140,19 @@ def compile_report(name: str, jitted, args: tuple) -> dict:
         ),
         "argument_gb": round(mem.argument_size_in_bytes / 1e9, 3),
         "temp_gb": round(mem.temp_size_in_bytes / 1e9, 3),
+        **({"estimated": _estimate(text, ops, hz)} if ops else {}),
+    }
+
+
+def _estimate(text: str, ops: int, hz: float) -> dict:
+    rows = estimated_operations(text)
+    total = sum(r["cycles"] for r in rows)
+    return {
+        "operations": len(rows), "cycles": total,
+        "est_ms": round(total / hz * 1e3, 2),
+        "top": [
+            dict(r, est_ms=round(r["cycles"] / hz * 1e3, 3)) for r in rows[:ops]
+        ],
     }
 
 
@@ -300,6 +368,16 @@ def cases(devices) -> dict:
             ResNet18(out_channels=100), 16, (2, 128), (32, 32, 3), devices
         ),
         "engine_cnn100_nodes4": lambda: cnn(mesh_axes={"nodes": 4}),
+        # GPT-2 small's published width, heads, context and vocabulary,
+        # ONE block, 2 silos: the head and its loss at their real
+        # shapes in seconds (the benchmark's cell takes half a minute).
+        "engine_gpt2_head_x2": lambda: engine_case(
+            TransformerLM(
+                vocab=50257, dim=768, heads=12, n_layers=1, max_len=1024
+            ),
+            2, (1, 4), (1024,), devices, n_rounds=1, x_dtype=jnp.int32,
+            y_tail=(1024,),
+        ),
         "engine_lm_nodes2_model2": lambda: engine_case(
             TransformerLM(
                 vocab=256, dim=512, heads=8, n_layers=4, max_len=2048
@@ -311,11 +389,30 @@ def cases(devices) -> dict:
     }
 
 
+def cell_cases(devices, names: list) -> dict:
+    """The window programs of the ``BENCHMARK.json`` cells among
+    ``names``, as the benchmark's own rehearsal builds them."""
+    from benchmark import cells, rehearse
+
+    known = {w["name"] for w in cells.load_benchmark()["workloads"]}
+    return {
+        name: lambda name=name: rehearse.window_program(
+            cells.load_cell(name), list(devices)
+        )
+        for name in names if name in known
+    }
+
+
 def main(argv: list[str]) -> int:
+    ops = 0
+    if "--ops" in argv:
+        at = argv.index("--ops")
+        ops, argv = int(argv[at + 1]), argv[:at] + argv[at + 2:]
     devices = described_devices()
     force_chip_branch()
     no_persistent_cache()
-    table = cases(devices)
+    hz = clock_hz(devices[0].device_kind) if ops else 0.0
+    table = {**cases(devices), **cell_cases(devices, argv)}
     wanted = [k for k in table if not argv or any(a in k for a in argv)]
     print(json.dumps({
         "topology": TOPOLOGY, "device_kind": devices[0].device_kind,
@@ -325,7 +422,11 @@ def main(argv: list[str]) -> int:
     for name in wanted:
         try:
             fn, args = table[name]()
-            print(json.dumps(compile_report(name, fn, args)), flush=True)
+            report = compile_report(name, fn, args, ops, hz)
+            top = report.get("estimated", {}).pop("top", [])
+            print(json.dumps(report), flush=True)
+            for row in top:
+                print(json.dumps(row), flush=True)
         except Exception as e:  # report every refusal, then fail
             failed += 1
             msg = str(e).strip().splitlines()

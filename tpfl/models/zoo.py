@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from tpfl.learning.model import TpflModel
+from tpfl.models.head_loss import head_cross_entropy
 
 
 class MLP(nn.Module):
@@ -305,8 +306,16 @@ class TransformerLM(nn.Module):
     # the replicated layout.
     spec_layout = "transformer"
 
+    # The output head owns its training loss: ``__call__(tokens,
+    # targets=y)`` returns the mean over tokens of
+    # ``cross_entropy_loss(logits, y)`` as one operation
+    # (tpfl.models.head_loss) instead of the logits. The engine's local
+    # training asks for it when its loss is that canonical one
+    # (docs/parallelism.md, "What the engine reads off a module").
+    owns_cross_entropy = True
+
     @nn.compact
-    def __call__(self, tokens, train: bool = False):
+    def __call__(self, tokens, train: bool = False, targets=None):
         if tokens.shape[1] > self.max_len:
             raise ValueError(
                 f"Sequence length {tokens.shape[1]} exceeds max_len="
@@ -325,5 +334,12 @@ class TransformerLM(nn.Module):
                 attention_fn=self.attention_fn,
             )(x, train=train)
         x = nn.LayerNorm(dtype=self.compute_dtype)(x)
-        logits = nn.Dense(self.vocab, dtype=self.compute_dtype)(x)
-        return logits.astype(jnp.float32)
+        head = nn.Dense(self.vocab, dtype=self.compute_dtype)
+        if targets is None:
+            return head(x).astype(jnp.float32)
+        if self.is_initializing():
+            head(x)  # creates Dense_0's kernel and bias under their names
+        weights = head.variables["params"]
+        return head_cross_entropy(
+            x, weights["kernel"], weights["bias"], targets
+        )

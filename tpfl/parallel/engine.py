@@ -671,6 +671,14 @@ class FederationEngine:
         self.learning_rate = float(learning_rate)
         self._opt = (optimizer_factory or default_optimizer)(learning_rate)
         self._loss_fn = loss_fn
+        #: The module's head computes the mean training loss itself
+        #: (``owns_cross_entropy``: ``apply(..., targets=y)``) and the
+        #: engine's loss is the canonical one it owns; local training
+        #: then never sees logits. Static per engine, and recorded in
+        #: the window programs' observatory names (``:hl``).
+        self.head_owns_loss = loss_fn is cross_entropy_loss and bool(
+            getattr(module, "owns_cross_entropy", False)
+        )
         self.seed = seed
         self.aux_mode = aux_mode
         self.algorithm = algorithm
@@ -1115,6 +1123,19 @@ class FederationEngine:
         opt, loss_fn, module = self._opt, self._loss_fn, self.module
         prox = self._make_prox()
         lr = self.learning_rate
+        head_owns_loss = self.head_owns_loss
+
+        def batch_loss(variables, x, y, train, mutable=False):
+            """(mean loss of one batch, the collections it mutated or
+            None): the ONE place every kind turns a batch into its
+            loss. A module whose head owns the canonical loss computes
+            it itself from ``targets``; any other returns logits."""
+            targets = {"targets": y} if head_owns_loss else {}
+            out = module.apply(
+                variables, x, train=train, mutable=mutable, **targets
+            )
+            out, mutated = (out, None) if mutable is False else out
+            return (out if head_owns_loss else loss_fn(out, y).mean()), mutated
 
         def local_train(params, c_i, c_g, aux, xb, yb, epochs):
             p0 = params  # round-start weights (FedProx anchor)
@@ -1133,20 +1154,20 @@ class FederationEngine:
                 if kind == "plain":
 
                     def loss_of(pp):
-                        logits = module.apply({"params": pp}, x, train=False)
-                        return loss_fn(logits, y).mean() + prox(pp, p0)
+                        loss, _ = batch_loss({"params": pp}, x, y, False)
+                        return loss + prox(pp, p0)
 
                     loss, grads = jax.value_and_grad(loss_of)(p)
                     new_a = a
                 else:
 
                     def loss_of(pp):
-                        logits, new_a = module.apply(
-                            {"params": pp, **a}, x, train=True, mutable=list(a)
+                        loss, new_a = batch_loss(
+                            {"params": pp, **a}, x, y, True, list(a)
                         )
                         if kind == "scaffold":
-                            return loss_fn(logits, y).mean(), new_a
-                        return loss_fn(logits, y).mean() + prox(pp, p0), new_a
+                            return loss, new_a
+                        return loss + prox(pp, p0), new_a
 
                     (loss, new_a), grads = jax.value_and_grad(
                         loss_of, has_aux=True
@@ -1972,6 +1993,7 @@ class FederationEngine:
                 + (f":c{int(capacity)}" if capacity else "")
                 + (f":h{int(mesh_hosts)}" if int(mesh_hosts) > 1 else "")
                 + (f":pop{int(pop_size)}" if pop_size else "")
+                + (":hl" if self.head_owns_loss else "")
             )
             wrapped = profiling.observatory.wrap(
                 self.program(*key),
