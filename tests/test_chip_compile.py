@@ -47,6 +47,7 @@ def described():
         ("flash_8k", 3, 0),
         ("flash_4k_h16_d128", 3, 0),
         ("ring_flash_sp4", 6, 1),
+        ("ssm_scan_8k_x2", 2, 0),
         ("node_conv_c32", 2, 0),
         ("node_conv_c32_vmapped", 2, 0),
     ],

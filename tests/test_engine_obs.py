@@ -175,7 +175,7 @@ def test_fanout_convergence_and_registry_series():
     ledger.contrib.reset()
     ledger.convergence.reset()
     try:
-        _run_windowed(rounds=3)
+        eng = _run_windowed(rounds=3)
         folded = metrics.fold()
         names = {
             k[0]
@@ -193,12 +193,14 @@ def test_fanout_convergence_and_registry_series():
             "tpfl_convergence_delta_norm",
         ):
             assert expect in names, expect
-        # The window summary event landed in the engine's flight ring.
-        nodes = [n for n in flight.nodes() if n.startswith("engine:")]
-        assert nodes
+        # The window summary event landed in THIS engine's flight ring
+        # (rings are process-wide: an engine of another test file that
+        # ran in this worker keeps its own, under its module's tag).
+        node = f"engine:{profiling.module_tag(eng.module)}"
+        assert node in flight.nodes()
         events = [
             e
-            for e in flight.snapshot(nodes[0])
+            for e in flight.snapshot(node)
             if e.get("name") == "engine_window"
         ]
         assert events and events[-1]["rounds"] == 3

@@ -185,6 +185,31 @@ def flash_case(shape, dtype, device) -> tuple:
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2))), (x, x, x)
 
 
+def scan_case(shape, states: int, silos: int, device) -> tuple:
+    """(jitted fwd+bwd of the selective scan's Pallas kernels under the
+    engine's vmap over ``silos``, args) for ``shape = (batch, S, D)``."""
+    from tpfl.parallel.selective_scan import selective_scan
+
+    def loss(c, delta, a, bmat, cmat, dskip):
+        out = jax.vmap(
+            lambda *x: selective_scan(*x, impl="kernel")
+        )(c, delta, a, bmat, cmat, dskip)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    sharding = SingleDeviceSharding(device)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        (silos, *shape), dtype, sharding=sharding
+    )
+    d = shape[-1]
+    small = (*shape[:-1], states)
+    args = (
+        sds(shape, jnp.bfloat16), sds(shape, jnp.float32),
+        sds((d, states), jnp.float32), sds(small, jnp.bfloat16),
+        sds(small, jnp.bfloat16), sds((d,), jnp.float32),
+    )
+    return jax.jit(jax.grad(loss, argnums=tuple(range(6)))), args
+
+
 def ring_case(devices, seq: int = 8192) -> tuple:
     """(jitted fwd+bwd of ring attention's flash inner over a 4-device
     ``sp`` mesh, args)."""
@@ -347,6 +372,9 @@ def cases(devices) -> dict:
         "flash_4k_h16_d128": lambda: flash_case((2, 4096, 16, 128), bf16, d0),
         "flash_2k_f32": lambda: flash_case((1, 2048, 8, 64), f32, d0),
         "ring_flash_sp4": lambda: ring_case(devices),
+        # The Mamba layer of the benchmark's SambaY cell: one 8192-token
+        # sequence a silo, 5120 channels x 16 states, two silos vmapped.
+        "ssm_scan_8k_x2": lambda: scan_case((1, 8192, 5120), 16, 2, d0),
         # Cin=3 takes node_conv's XLA fallback by design (0 kernel
         # calls): lane padding made the Pallas stem 42x its size.
         "node_conv_c3_fallback": lambda: node_conv_case(

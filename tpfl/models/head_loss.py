@@ -45,7 +45,7 @@ def _logits(hidden, kernel, bias):
         hidden, kernel.astype(dtype),
         (((hidden.ndim - 1,), (0,)), ((), ())),
     )
-    return y + bias.astype(dtype)
+    return y if bias is None else y + bias.astype(dtype)
 
 
 def _is_label(logits, targets):
@@ -58,8 +58,9 @@ def _is_label(logits, targets):
 def head_cross_entropy(hidden, kernel, bias, targets):
     """Mean over tokens of the cross-entropy of the vocabulary
     projection ``hidden [..., d] @ kernel [d, V] + bias [V]`` (computed
-    in ``hidden.dtype``) against integer ``targets [...]``; a float32
-    scalar. Differentiable in ``hidden``, ``kernel`` and ``bias``."""
+    in ``hidden.dtype``; ``bias`` may be None) against integer
+    ``targets [...]``; a float32 scalar. Differentiable in ``hidden``,
+    ``kernel`` and ``bias``."""
     return _forward(hidden, kernel, bias, targets)[0]
 
 
@@ -95,6 +96,8 @@ def _backward(residuals, g):
         dlogits, kernel.astype(dtype),
         (((dlogits.ndim - 1,), (1,)), ((), ())),
     )
+    if bias is None:
+        return d_hidden, d_kernel.astype(kernel.dtype), None, None
     # The bias gradient on the matrix unit, accumulated in float32.
     # HIGHEST costs nothing on bf16 operands (ones and dlogits are exact
     # in one pass) and keeps a float32 compute dtype at a float32 sum.

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from tpfl.learning.model import TpflModel
 from tpfl.models.head_loss import head_cross_entropy
+from tpfl.models.sambay import SambaYLM
 
 
 class MLP(nn.Module):
@@ -218,7 +219,8 @@ def create_model(
     """Initialize a flax module into a :class:`TpflModel`.
 
     ``module`` may be a module instance or a zoo name ("mlp", "cnn",
-    "resnet18"). ``input_shape`` excludes the batch dimension.
+    "resnet18", "transformer_lm", "sambay_lm"). ``input_shape`` excludes
+    the batch dimension.
     """
     if isinstance(module, str):
         zoo: dict[str, Callable[..., nn.Module]] = {
@@ -226,6 +228,7 @@ def create_model(
             "cnn": CNN,
             "resnet18": ResNet18,
             "transformer_lm": TransformerLM,
+            "sambay_lm": SambaYLM,
         }
         if module not in zoo:
             raise KeyError(f"Unknown model {module!r}; have {sorted(zoo)}")

@@ -61,12 +61,24 @@ def blockwise_attention(
     v: jnp.ndarray,
     causal: bool = False,
     block_size: Optional[int] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Single-device flash-style attention, blocked over BOTH queries
     and keys: peak score memory is O(block²) per (batch, head), never
     O(S²) or O(S·block). The causal inner loop's trip count is the
     query block index + 1, so fully-masked future K/V blocks are never
-    computed (≈2× fewer FLOPs). q/k/v: [B, S, H, D] -> [B, S, H, D].
+    computed (≈2× fewer FLOPs). q: [B, S, Hq, D], k: [B, S, Hkv, D],
+    v: [B, S, Hkv, Dv] -> [B, S, Hq, Dv].
+
+    **Grouped key/value heads**: ``Hq`` may be a multiple of ``Hkv``;
+    query head ``h`` reads key/value head ``h // (Hq // Hkv)``. The
+    group's query heads are laid side by side as extra query ROWS of
+    their key/value head's block, so the block loop and its matmuls are
+    the equal-head ones with taller tiles. **Value width**: ``Dv`` need
+    not be ``D``. **Band**: ``window`` (causal only) keeps the keys with
+    ``0 <= q - k < window``; key blocks wholly outside the band are
+    skipped on both sides. ``k`` / ``v`` may come from anywhere (another
+    layer's projections): nothing here assumes they were made with ``q``.
 
     Differentiable with a RECOMPUTE backward (``jax.custom_vjp``): the
     forward banks only the output and per-row logsumexp; the backward
@@ -76,7 +88,16 @@ def blockwise_attention(
     instead stash O(S·block) score residuals per step, which at 32k
     tokens produced a program the TPU compiler could not build (the
     r3 bench's ``blockwise_fwdbwd_32k`` compile failure)."""
-    b, s, h, d = q.shape
+    b, s, hq, d = q.shape
+    hkv = k.shape[2]
+    groups = hq // hkv
+    if groups * hkv != hq or v.shape[2] != hkv:
+        raise ValueError(
+            f"query heads ({hq}) must be a multiple of the key heads "
+            f"({hkv}), and values must have the keys' heads ({v.shape[2]})"
+        )
+    if window is not None and not causal:
+        raise ValueError("a band (window) is causal: pass causal=True")
     block = block_size or min(s, 512)
     n_blocks = -(-s // block)
     pad = n_blocks * block - s
@@ -84,51 +105,85 @@ def blockwise_attention(
         q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    out = _blockwise(q, k, v, causal, block, s)
+    if groups > 1:
+        # [B, nb, block, Hkv, G, D] -> [B, nb, G * block, Hkv, D]
+        q = q.reshape(b, n_blocks, block, hkv, groups, d)
+        q = jnp.moveaxis(q, 4, 2).reshape(b, -1, hkv, d)
+    out = _blockwise(q, k, v, causal, block, s, groups, window)
+    if groups > 1:
+        out = out.reshape(b, n_blocks, groups, block, hkv, -1)
+        out = jnp.moveaxis(out, 2, 4).reshape(b, n_blocks * block, hq, -1)
     return out[:, :s]
 
 
-def _bw_mask(q_idx, k_idx, s_len: int, causal: bool):
+def _bw_mask(q_idx, k_idx, s_len: int, causal: bool, window=None):
     mask = jnp.broadcast_to(
         k_idx[None, :] < s_len, (q_idx.shape[0], k_idx.shape[0])
     )
     if causal:
         mask = mask & (q_idx[:, None] >= k_idx[None, :])
+    if window is not None:
+        mask = mask & (q_idx[:, None] - k_idx[None, :] < window)
     return mask
 
 
-def _blockwise_fwd_core(q, k, v, causal: bool, block: int, s_len: int):
-    """Padded q/k/v [B, nb·block, H, D] -> (out, lse[B, H, nb·block]).
-    lse rows with no visible key get +LARGE so the backward's
-    exp(s - lse) is exactly 0 for them."""
-    b, sp, h, d = q.shape
+def _in_band(pred, i, j, block: int, window):
+    """``pred`` (block j is not in query block i's future) narrowed to
+    the key blocks that reach into the band: the nearest pair of rows
+    of blocks i and j is ``(i - j - 1) * block + 1`` apart."""
+    if window is None:
+        return pred
+    return pred & (i - j <= -(-(window - 1) // block))
+
+
+def _query_rows(local_idx, groups: int):
+    """Sequence offsets of a query block's rows: a group's heads lie
+    side by side as ``groups`` runs of the block's positions."""
+    return jnp.tile(local_idx, groups) if groups > 1 else local_idx
+
+
+def _blockwise_fwd_core(
+    q, k, v, causal: bool, block: int, s_len: int, groups: int = 1,
+    window=None,
+):
+    """Padded k [B, nb·block, H, D], v [.., Dv] and q [B, nb·rows, H, D]
+    with ``rows = groups·block`` query rows a block (a key head's
+    ``groups`` query heads side by side) -> (out [B, nb·rows, H, Dv],
+    lse [B, H, nb·rows]). lse rows with no visible key get +LARGE so
+    the backward's exp(s - lse) is exactly 0 for them."""
+    b, sp, h, d = k.shape
+    d_v = v.shape[-1]
     n_blocks = sp // block
-    qb = q.reshape(b, n_blocks, block, h, d)
+    rows = groups * block
+    qb = q.reshape(b, n_blocks, rows, h, d)
     kb = k.reshape(b, n_blocks, block, h, d)
-    vb = v.reshape(b, n_blocks, block, h, d)
+    vb = v.reshape(b, n_blocks, block, h, d_v)
     local_idx = jnp.arange(block)
+    q_local = _query_rows(local_idx, groups)
 
     def per_q_block(i):
         q_i = qb[:, i]
-        q_idx = i * block + local_idx
+        q_idx = i * block + q_local
 
         def body(j, carry):
             def attend(c):
                 k_j = jax.lax.dynamic_index_in_dim(kb, j, axis=1, keepdims=False)
                 v_j = jax.lax.dynamic_index_in_dim(vb, j, axis=1, keepdims=False)
                 k_idx = j * block + local_idx
-                mask = _bw_mask(q_idx, k_idx, s_len, causal)
+                mask = _bw_mask(q_idx, k_idx, s_len, causal, window)
                 return _block_attend(q_i, k_j, v_j, *c, mask)
 
             if causal:
-                # Blocks above the diagonal are fully masked: cond skips
-                # their compute at runtime.
-                return jax.lax.cond(j <= i, attend, lambda c: c, carry)
+                # Blocks above the diagonal (and, with a band, those
+                # wholly behind it) are fully masked: cond skips their
+                # compute at runtime.
+                visible = _in_band(j <= i, i, j, block, window)
+                return jax.lax.cond(visible, attend, lambda c: c, carry)
             return attend(carry)
 
-        acc = jnp.zeros((b, h, block, d), jnp.float32)
-        row_max = jnp.full((b, h, block), -jnp.inf, jnp.float32)
-        denom = jnp.zeros((b, h, block), jnp.float32)
+        acc = jnp.zeros((b, h, rows, d_v), jnp.float32)
+        row_max = jnp.full((b, h, rows), -jnp.inf, jnp.float32)
+        denom = jnp.zeros((b, h, rows), jnp.float32)
         acc, row_max, denom = jax.lax.fori_loop(
             0, n_blocks, body, (acc, row_max, denom)
         )
@@ -139,42 +194,50 @@ def _blockwise_fwd_core(q, k, v, causal: bool, block: int, s_len: int):
         return jnp.moveaxis(out, 1, 2), lse  # [B, block, H, D], [B,H,block]
 
     blocks, lses = jax.lax.map(per_q_block, jnp.arange(n_blocks))
-    out = jnp.moveaxis(blocks, 0, 1).reshape(b, n_blocks * block, h, d)
-    # lses: [nb, B, H, block] -> [B, H, nb, block] -> [B, H, S']
-    lse = jnp.moveaxis(lses, 0, 2).reshape(b, h, n_blocks * block)
+    out = jnp.moveaxis(blocks, 0, 1).reshape(b, n_blocks * rows, h, d_v)
+    # lses: [nb, B, H, rows] -> [B, H, nb, rows] -> [B, H, S']
+    lse = jnp.moveaxis(lses, 0, 2).reshape(b, h, n_blocks * rows)
     return out.astype(q.dtype), lse
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _blockwise(q, k, v, causal: bool, block: int, s_len: int):
-    out, _ = _blockwise_fwd_core(q, k, v, causal, block, s_len)
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _blockwise(
+    q, k, v, causal: bool, block: int, s_len: int, groups: int = 1,
+    window=None,
+):
+    out, _ = _blockwise_fwd_core(q, k, v, causal, block, s_len, groups, window)
     return out
 
 
-def _blockwise_vjp_fwd(q, k, v, causal, block, s_len):
-    out, lse = _blockwise_fwd_core(q, k, v, causal, block, s_len)
+def _blockwise_vjp_fwd(q, k, v, causal, block, s_len, groups, window):
+    out, lse = _blockwise_fwd_core(
+        q, k, v, causal, block, s_len, groups, window
+    )
     return out, (q, k, v, out, lse)
 
 
-def _blockwise_vjp_bwd(causal, block, s_len, res, g):
+def _blockwise_vjp_bwd(causal, block, s_len, groups, window, res, g):
     """Flash-style recompute backward: P = exp(S - lse) per block pair;
     dq sweep over query blocks, dk/dv sweep over key blocks. Peak
     transient is O(block²) per (batch, head) — no stored residuals."""
     q, k, v, out, lse = res
-    b, sp, h, d = q.shape
+    b, sp, h, d = k.shape
+    d_v = v.shape[-1]
     n_blocks = sp // block
+    rows = groups * block
     scale = 1.0 / jnp.sqrt(d)
     g32 = g.astype(jnp.float32)
     delta = jnp.einsum(
         "bshd,bshd->bhs", g32, out.astype(jnp.float32)
     )  # [B, H, S']
-    qb = q.reshape(b, n_blocks, block, h, d)
+    qb = q.reshape(b, n_blocks, rows, h, d)
     kb = k.reshape(b, n_blocks, block, h, d)
-    vb = v.reshape(b, n_blocks, block, h, d)
-    gb = g32.reshape(b, n_blocks, block, h, d)
-    lse_b = lse.reshape(b, h, n_blocks, block)
-    delta_b = delta.reshape(b, h, n_blocks, block)
+    vb = v.reshape(b, n_blocks, block, h, d_v)
+    gb = g32.reshape(b, n_blocks, rows, h, d_v)
+    lse_b = lse.reshape(b, h, n_blocks, rows)
+    delta_b = delta.reshape(b, h, n_blocks, rows)
     local_idx = jnp.arange(block)
+    q_local = _query_rows(local_idx, groups)
 
     def p_ds(i, j, q_i, k_j, v_j, g_i, lse_i, delta_i):
         """Recompute P and dS for the (i, j) block pair."""
@@ -182,8 +245,8 @@ def _blockwise_vjp_bwd(causal, block, s_len, res, g):
             jnp.einsum("bqhd,bkhd->bhqk", q_i, k_j).astype(jnp.float32)
             * scale
         )
-        mask = _bw_mask(i * block + local_idx, j * block + local_idx,
-                        s_len, causal)
+        mask = _bw_mask(i * block + q_local, j * block + local_idx,
+                        s_len, causal, window)
         p = jnp.where(mask[None, None], jnp.exp(s_ij - lse_i[..., None]), 0.0)
         dp = jnp.einsum("bqhd,bkhd->bhqk", g_i, v_j.astype(jnp.float32))
         ds = p * (dp - delta_i[..., None]) * scale
@@ -205,10 +268,11 @@ def _blockwise_vjp_bwd(causal, block, s_len, res, g):
                 )
 
             if causal:
-                return jax.lax.cond(j <= i, go, lambda x: x, dq)
+                visible = _in_band(j <= i, i, j, block, window)
+                return jax.lax.cond(visible, go, lambda x: x, dq)
             return go(dq)
 
-        dq = jnp.zeros((b, block, h, d), jnp.float32)
+        dq = jnp.zeros((b, rows, h, d), jnp.float32)
         return jax.lax.fori_loop(0, n_blocks, body, dq)
 
     def dkv_block(j):
@@ -230,18 +294,19 @@ def _blockwise_vjp_bwd(causal, block, s_len, res, g):
                 return dk, dv
 
             if causal:
-                return jax.lax.cond(i >= j, go, lambda c: c, carry)
+                visible = _in_band(i >= j, i, j, block, window)
+                return jax.lax.cond(visible, go, lambda c: c, carry)
             return go(carry)
 
         dk = jnp.zeros((b, block, h, d), jnp.float32)
-        dv = jnp.zeros((b, block, h, d), jnp.float32)
+        dv = jnp.zeros((b, block, h, d_v), jnp.float32)
         return jax.lax.fori_loop(0, n_blocks, body, (dk, dv))
 
     dq = jax.lax.map(dq_block, jnp.arange(n_blocks))
     dk, dv = jax.lax.map(dkv_block, jnp.arange(n_blocks))
 
     def unblk(x):
-        return jnp.moveaxis(x, 0, 1).reshape(b, sp, h, d)
+        return jnp.moveaxis(x, 0, 1).reshape(b, -1, *x.shape[3:])
 
     return (
         unblk(dq).astype(q.dtype),
