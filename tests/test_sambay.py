@@ -125,18 +125,20 @@ def test_selective_scan_under_the_engine_s_vmap_and_in_bfloat16():
 # --- attention ---------------------------------------------------------------
 
 
-def _full_attention(q, k, v, window):
+def _full_attention(q, k, v, window, causal=True, precision=None):
     """S x S scores; query head h reads key head h // groups."""
     groups = q.shape[2] // k.shape[2]
     k, v = (jnp.repeat(t, groups, axis=2) for t in (k, v))
     s = q.shape[1]
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(q.shape[-1])
+    scores = jnp.einsum(
+        "bqhd,bkhd->bhqk", q, k, precision=precision
+    ) / jnp.sqrt(q.shape[-1])
     pos = jnp.arange(s)
-    mask = pos[:, None] >= pos[None, :]
+    mask = (pos[:, None] >= pos[None, :]) | (not causal)
     if window is not None:
         mask &= pos[:, None] - pos[None, :] < window
     probs = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v, precision=precision)
 
 
 @pytest.mark.parametrize(
@@ -168,6 +170,142 @@ def test_blockwise_attention_groups_value_width_and_band(hq, hkv, d, dv, window,
     for name, g, g_ref in zip("qkv", *grads):
         # (window 1: dq and dk are exactly zero, so an absolute floor.)
         np.testing.assert_allclose(g, g_ref, rtol=1e-4, atol=1e-5, err_msg=name)
+
+
+# What the one-sweep backward (PR 28) has to keep: name -> (hq, hkv, d, dv,
+# window, s, block, causal).
+_BACKWARD_SHAPES = {
+    "equal_heads": (4, 4, 8, 8, None, 64, 16, True),
+    "grouped_heads": (8, 4, 8, 8, None, 64, 16, True),
+    "wide_value": (4, 2, 8, 16, None, 64, 16, True),
+    "band": (6, 2, 8, 4, 20, 64, 8, True),
+    "ragged_padding": (4, 2, 8, 16, None, 40, 16, True),
+    "non_causal_ragged": (4, 2, 8, 8, None, 40, 16, False),
+}
+# float32: today's tolerance (every step in float32; only the order of the
+# sums differs from the S x S form). bfloat16: the tile matmuls read P and
+# dS rounded to bf16's 8 bits beside bf16 q, k, v, dO, so the error is the
+# inputs' own rounding, ~2^-8; ISSUE 28's desk check read 3.4e-3-4.6e-3 of
+# the gradient's norm, and a dropped term or a wrong mask reads 1e-1 and up.
+_BACKWARD_TOLERANCE = {jnp.float32: 1e-5, jnp.bfloat16: 1e-2}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=lambda d: d.__name__)
+@pytest.mark.parametrize("shape", list(_BACKWARD_SHAPES))
+def test_blockwise_attention_gradients_match_the_full_form(shape, dtype):
+    hq, hkv, d, dv, window, s, block, causal = _BACKWARD_SHAPES[shape]
+    keys = jax.random.split(jax.random.PRNGKey(28), 4)
+    q = jax.random.normal(keys[0], (2, s, hq, d)).astype(dtype)
+    k = jax.random.normal(keys[1], (2, s, hkv, d)).astype(dtype)
+    v = jax.random.normal(keys[2], (2, s, hkv, dv)).astype(dtype)
+    w = jax.random.normal(keys[3], (2, s, hq, dv)).astype(dtype)
+
+    def mine(q, k, v):
+        out = blockwise_attention(
+            q, k, v, causal=causal, block_size=block, window=window
+        )
+        assert out.dtype == dtype
+        return jnp.sum(out.astype(jnp.float32) * w.astype(jnp.float32))
+
+    def ref(q, k, v):
+        out = _full_attention(q, k, v, window, causal, precision="highest")
+        return jnp.sum(out * w.astype(jnp.float32))
+
+    got = jax.jit(jax.grad(mine, argnums=(0, 1, 2)))(q, k, v)
+    want = jax.grad(ref, argnums=(0, 1, 2))(
+        *(t.astype(jnp.float32) for t in (q, k, v))
+    )
+    for name, g, g_ref in zip("qkv", got, want):
+        assert g.dtype == dtype and g.shape == g_ref.shape
+        err = jnp.linalg.norm(g.astype(jnp.float32) - g_ref) / jnp.linalg.norm(g_ref)
+        assert float(err) < _BACKWARD_TOLERANCE[dtype], (name, float(err))
+
+
+def _dot_generals(jaxpr):
+    """Every ``dot_general`` equation of a jaxpr, inner jaxprs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from _dot_generals(inner)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=lambda d: d.__name__)
+def test_blockwise_attention_backward_is_one_sweep_on_input_dtype_operands(dtype):
+    """The gradient's jaxpr: two tile matmuls forward (scores, P V) and
+    five backward (scores, dP, dV, dK, dQ) — a second sweep would show as
+    seven. No operand is wider than the inputs: P, dS, q, k, v and dO
+    enter as bf16 when the inputs are bf16. The two score matmuls round
+    to the inputs' dtype (``ring_attention._scores`` says why); the other
+    five keep their float32 accumulator."""
+    hq, hkv, d, dv, s, block = 4, 2, 8, 16, 64, 16
+    q = jnp.zeros((1, s, hq, d), dtype)
+    k = jnp.zeros((1, s, hkv, d), dtype)
+    v = jnp.zeros((1, s, hkv, dv), dtype)
+
+    def loss(q, k, v):
+        out = blockwise_attention(q, k, v, causal=True, block_size=block)
+        return jnp.sum(out.astype(jnp.float32))
+
+    dots = list(_dot_generals(
+        jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v).jaxpr
+    ))
+    assert len(dots) == 2 + 5
+    tile = (hq // hkv * block, block)
+    accumulators = []
+    for eqn in dots:
+        operands = [x.aval for x in eqn.invars]
+        assert all(x.dtype == dtype for x in operands), operands
+        # Each is a matmul of the block pair: a tile comes out of it or
+        # goes into it.
+        shapes = [x.shape[-2:] for x in operands] + [eqn.outvars[0].aval.shape[-2:]]
+        assert tile in shapes or tile[::-1] in shapes, shapes
+        accumulators.append(eqn.outvars[0].aval.dtype)
+        if accumulators[-1] == jnp.float32:
+            assert eqn.params["preferred_element_type"] in (None, jnp.float32)
+    narrow = [a for a in accumulators if a != jnp.float32]
+    assert len(narrow) == (2 if dtype == jnp.bfloat16 else 0), accumulators
+
+
+def test_blockwise_attention_backward_steps_through_the_heads(monkeypatch):
+    """A step of the backward takes the most key heads whose float32
+    score tile is within ``_STEP_TILE_BYTES``; heads never mix, so the
+    gradient does not depend on how many a step takes."""
+    import importlib
+
+    # (``tpfl.parallel`` re-exports a function under the module's name.)
+    ring_attention = importlib.import_module("tpfl.parallel.ring_attention")
+    heads = ring_attention._heads_per_step
+    limit = ring_attention._STEP_TILE_BYTES
+    tile = 4 * 2 * 32 * 16  # float32 [B = 2, one head, rows = 32, block = 16]
+    assert tile * 4 <= limit and heads(2, 4, 32, 16) == 4  # all of them
+    monkeypatch.setattr(ring_attention, "_STEP_TILE_BYTES", 2 * tile)
+    assert heads(2, 4, 32, 16) == 2
+    assert heads(2, 6, 32, 16) == 2 and heads(2, 7, 32, 16) == 1  # a divisor
+    assert heads(2, 4, 64, 16) == 1 and heads(64, 4, 32, 16) == 1  # never none
+    monkeypatch.setattr(ring_attention, "_STEP_TILE_BYTES", limit)
+
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    q = jax.random.normal(keys[0], (2, 40, 8, 8))
+    k = jax.random.normal(keys[1], (2, 40, 4, 8))
+    v = jax.random.normal(keys[2], (2, 40, 4, 16))
+    w = jax.random.normal(keys[3], (2, 40, 8, 16))
+
+    def grads(q):
+        return jax.grad(lambda *x: jnp.sum(blockwise_attention(
+            *x, causal=True, block_size=16, window=20
+        ) * w), argnums=(0, 1, 2))(q, k, v)
+
+    whole = grads(q)
+    for limit in (2 * tile, 1):  # two heads a step, one
+        monkeypatch.setattr(ring_attention, "_STEP_TILE_BYTES", limit)
+        # ... alone and under vmap, as the engine runs it (float32: only
+        # the matmuls' own summation order may differ).
+        for got in (grads(q), jax.tree.map(lambda x: x[0], jax.vmap(grads)(q[None]))):
+            for name, g, g_whole in zip("qkv", got, whole):
+                np.testing.assert_allclose(
+                    g, g_whole, rtol=1e-5, atol=1e-6, err_msg=name
+                )
 
 
 def test_blockwise_attention_refuses_what_it_cannot_mean():

@@ -30,16 +30,36 @@ from tpfl.parallel import compat
 from tpfl.parallel.compat import shard_map
 
 
+def _mm(spec: str, a, b):
+    """A tile matmul on its operands in the dtype they arrive in, with a
+    float32 accumulator (``flash_kernel._mm``'s rule): float32 copies of
+    bf16 operands buy no precision — at default precision the MXU
+    multiplies float32 operands in bf16 anyway. float32 operands (the
+    exactness tests) compute in float32 throughout."""
+    return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
+
+
+def _scores(q, k):
+    """``q k^T`` of a block pair as float32, ROUNDED to the inputs' dtype
+    on its way (the einsum's own output): on the v5e a bf16 score tile
+    written and read back beats the float32 accumulator taken directly —
+    2.4x on the forward at GPT-2's shapes (PERF.md §6, PR 28), where a
+    float32 tile over four silos no longer fits the fast memory."""
+    return jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+
+
 def _block_attend(q, k, v, acc, row_max, denom, mask):
     """Fold one K/V block into the running (acc, row_max, denom).
 
     q: [B, Lq, H, D], k/v: [B, Lk, H, D]; mask: [Lq, Lk] boolean or
     None. Online softmax: rescale previous accumulators by
-    exp(old_max - new_max), add this block's exp-weighted values.
+    exp(old_max - new_max), add this block's exp-weighted values. P is
+    rounded to the values' dtype for its matmul alone (the denominator
+    sums the float32 P) — the standard flash recipe.
     """
     scale = 1.0 / jnp.sqrt(q.shape[-1])
     # [B, H, Lq, Lk]
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
+    scores = _scores(q, k) * scale
     if mask is not None:
         scores = jnp.where(mask[None, None], scores, -jnp.inf)
     block_max = jnp.max(scores, axis=-1)  # [B, H, Lq]
@@ -48,8 +68,8 @@ def _block_attend(q, k, v, acc, row_max, denom, mask):
     correction = jnp.exp(jnp.where(row_max == -jnp.inf, -jnp.inf, row_max - new_max))
     p = jnp.exp(scores - new_max[..., None])  # [B, H, Lq, Lk]
     p = jnp.where(jnp.isnan(p), 0.0, p)  # -inf - -inf rows
-    acc = acc * correction[..., None] + jnp.einsum(
-        "bhqk,bkhd->bhqd", p, v.astype(jnp.float32)
+    acc = acc * correction[..., None] + _mm(
+        "bhqk,bkhd->bhqd", p.astype(v.dtype), v
     )
     denom = denom * correction + jnp.sum(p, axis=-1)
     return acc, new_max, denom
@@ -82,12 +102,20 @@ def blockwise_attention(
 
     Differentiable with a RECOMPUTE backward (``jax.custom_vjp``): the
     forward banks only the output and per-row logsumexp; the backward
-    re-derives P = exp(S - lse) block by block in two sweeps (dq over
-    query blocks, dk/dv over key blocks — the standard flash VJP at
-    the XLA level). Reverse-mode through the forward's scan would
-    instead stash O(S·block) score residuals per step, which at 32k
-    tokens produced a program the TPU compiler could not build (the
-    r3 bench's ``blockwise_fwdbwd_32k`` compile failure)."""
+    re-derives P = exp(S - lse) block by block in ONE sweep that visits
+    each visible block pair once and feeds dq, dk and dv from the same
+    P and dS (FlashAttention-2's VJP at the XLA level,
+    ``_blockwise_vjp_bwd``), a few key heads a step. Reverse-mode
+    through the forward's scan would instead stash O(S·block) score
+    residuals per step, which at 32k tokens produced a program the TPU
+    compiler could not build (the r3 bench's ``blockwise_fwdbwd_32k``
+    compile failure).
+
+    The tile matmuls (``P V``, ``dO V^T``, ``dS K``, ``dS^T Q``,
+    ``P^T dO``) read P and dS rounded to the inputs' dtype beside q, k,
+    v and dO as they arrive, and accumulate in float32; the running max,
+    denominator, ``acc``, lse and delta are float32. float32 inputs
+    compute in float32 throughout."""
     b, s, hq, d = q.shape
     hkv = k.shape[2]
     groups = hq // hkv
@@ -127,13 +155,19 @@ def _bw_mask(q_idx, k_idx, s_len: int, causal: bool, window=None):
     return mask
 
 
+def _band_reach(block: int, window: int) -> int:
+    """How many key blocks behind its own a query block's band reaches:
+    the nearest pair of rows of blocks i and j is
+    ``(i - j - 1) * block + 1`` apart."""
+    return -(-(window - 1) // block)
+
+
 def _in_band(pred, i, j, block: int, window):
     """``pred`` (block j is not in query block i's future) narrowed to
-    the key blocks that reach into the band: the nearest pair of rows
-    of blocks i and j is ``(i - j - 1) * block + 1`` apart."""
+    the key blocks that reach into the band."""
     if window is None:
         return pred
-    return pred & (i - j <= -(-(window - 1) // block))
+    return pred & (i - j <= _band_reach(block, window))
 
 
 def _query_rows(local_idx, groups: int):
@@ -142,6 +176,7 @@ def _query_rows(local_idx, groups: int):
     return jnp.tile(local_idx, groups) if groups > 1 else local_idx
 
 
+@jax.named_scope("block_attention")
 def _blockwise_fwd_core(
     q, k, v, causal: bool, block: int, s_len: int, groups: int = 1,
     window=None,
@@ -216,100 +251,125 @@ def _blockwise_vjp_fwd(q, k, v, causal, block, s_len, groups, window):
     return out, (q, k, v, out, lse)
 
 
+# A step of the backward works on float32 score tiles [B, heads, rows,
+# block] of one block pair — S / P, dP and dS together, where the
+# two-sweep form it replaced held one at a time. Measured on the v5e
+# (PERF.md §6, PR 28): a step whose tiles outgrow the chip's fast memory
+# sends dS through HBM, and one sweep then LOSES to two (GPT-2's cell,
+# all twelve heads a step: 5.25 ms a call against 4.76; three heads a
+# step: 4.03). So a step takes the most key heads whose float32 tile is
+# within this many bytes. That is the tile this function can see: under
+# the engine's vmap it is as many times larger as the chip holds silos.
+_STEP_TILE_BYTES = 12 << 20
+
+
+def _heads_per_step(b: int, h: int, rows: int, block: int) -> int:
+    """The most key heads, a divisor of ``h``, whose float32 score tile
+    ``[b, heads, rows, block]`` is within ``_STEP_TILE_BYTES`` (one head
+    where none is)."""
+    head_bytes = 4 * b * rows * block
+    return max(
+        n for n in range(1, h + 1)
+        if h % n == 0 and (n == 1 or n * head_bytes <= _STEP_TILE_BYTES)
+    )
+
+
+@jax.named_scope("block_attention")
 def _blockwise_vjp_bwd(causal, block, s_len, groups, window, res, g):
-    """Flash-style recompute backward: P = exp(S - lse) per block pair;
-    dq sweep over query blocks, dk/dv sweep over key blocks. Peak
-    transient is O(block²) per (batch, head) — no stored residuals."""
+    """dq, dk, dv of padded, grouped-row q / k / v (``_blockwise_fwd_core``'s
+    shapes) from the forward's ``out`` and ``lse`` and the cotangent ``g``:
+    a flash-style recompute backward in ONE sweep (FlashAttention-2's, at
+    the XLA level). Each visible (query block i, key block j) pair is
+    visited once, ``_heads_per_step`` key heads a step (heads never mix):
+    S, P = exp(S - lse), dP and dS are computed once and feed all three of
+    dQ, dK, dV — 5 tile matmuls a step. The outer loop runs over key
+    blocks and carries ``dk_j`` / ``dv_j``; ``dq`` grows in its whole
+    float32 buffer by slice-add. Peak transient is O(block²) per (batch,
+    head) — no stored residuals, and no array the size of q / k / v
+    besides the three gradients.
+
+    The inner loop's BOUNDS are the causal / band limits, so invisible
+    pairs are never entered and the ``dq`` buffer never passes through a
+    ``cond`` (whose identity branch would copy it). Nothing
+    differentiates through this function, so dynamic bounds are free."""
     q, k, v, out, lse = res
     b, sp, h, d = k.shape
     d_v = v.shape[-1]
     n_blocks = sp // block
     rows = groups * block
+    heads = _heads_per_step(b, h, rows, block)
+    chunks = h // heads
     scale = 1.0 / jnp.sqrt(d)
-    g32 = g.astype(jnp.float32)
-    delta = jnp.einsum(
-        "bshd,bshd->bhs", g32, out.astype(jnp.float32)
+    delta = jnp.moveaxis(
+        jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1),
+        1, 2,
     )  # [B, H, S']
     qb = q.reshape(b, n_blocks, rows, h, d)
     kb = k.reshape(b, n_blocks, block, h, d)
     vb = v.reshape(b, n_blocks, block, h, d_v)
-    gb = g32.reshape(b, n_blocks, rows, h, d_v)
+    gb = g.reshape(b, n_blocks, rows, h, d_v)
     lse_b = lse.reshape(b, h, n_blocks, rows)
     delta_b = delta.reshape(b, h, n_blocks, rows)
     local_idx = jnp.arange(block)
     q_local = _query_rows(local_idx, groups)
+    block_of = partial(jax.lax.dynamic_index_in_dim, keepdims=False)
+    heads_of = partial(jax.lax.dynamic_slice_in_dim, slice_size=heads)
+    put_heads = jax.lax.dynamic_update_slice_in_dim
 
-    def p_ds(i, j, q_i, k_j, v_j, g_i, lse_i, delta_i):
-        """Recompute P and dS for the (i, j) block pair."""
-        s_ij = (
-            jnp.einsum("bqhd,bkhd->bhqk", q_i, k_j).astype(jnp.float32)
-            * scale
+    def key_block(dq, j):
+        k_j = block_of(kb, j, axis=1)
+        v_j = block_of(vb, j, axis=1)
+        k_idx = j * block + local_idx
+
+        def step(t, carry):
+            dq, dk, dv = carry
+            i, h0 = jax.lax.div(t, chunks), jax.lax.rem(t, chunks) * heads
+            q_i = heads_of(block_of(qb, i, axis=1), h0, axis=2)
+            g_i = heads_of(block_of(gb, i, axis=1), h0, axis=2)
+            k_c = heads_of(k_j, h0, axis=2)
+            v_c = heads_of(v_j, h0, axis=2)
+            lse_i = heads_of(block_of(lse_b, i, axis=2), h0, axis=1)
+            delta_i = heads_of(block_of(delta_b, i, axis=2), h0, axis=1)
+            s_ij = _scores(q_i, k_c) * scale
+            mask = _bw_mask(i * block + q_local, k_idx, s_len, causal, window)
+            p = jnp.where(
+                mask[None, None], jnp.exp(s_ij - lse_i[..., None]), 0.0
+            )
+            dp = _mm("bqhd,bkhd->bhqk", g_i, v_c)
+            ds = p * (dp - delta_i[..., None]) * scale
+            dv_c = _mm("bhqk,bqhd->bkhd", p.astype(g.dtype), g_i)
+            dk_c = _mm("bhqk,bqhd->bkhd", ds.astype(q.dtype), q_i)
+            dq_i = _mm("bhqk,bkhd->bqhd", ds.astype(k.dtype), k_c)
+            dv = put_heads(dv, dv_c + heads_of(dv, h0, axis=2), h0, axis=2)
+            dk = put_heads(dk, dk_c + heads_of(dk, h0, axis=2), h0, axis=2)
+            at = (0, i, 0, h0, 0)
+            dq_i = dq_i[:, None] + jax.lax.dynamic_slice(
+                dq, at, (b, 1, rows, heads, d)
+            )
+            return jax.lax.dynamic_update_slice(dq, dq_i, at), dk, dv
+
+        # Query blocks that see key block j: from the diagonal down to
+        # the band's reach; each in ``chunks`` steps.
+        first = j if causal else 0
+        last = (
+            n_blocks if window is None
+            else jnp.minimum(n_blocks, j + _band_reach(block, window) + 1)
         )
-        mask = _bw_mask(i * block + q_local, j * block + local_idx,
-                        s_len, causal, window)
-        p = jnp.where(mask[None, None], jnp.exp(s_ij - lse_i[..., None]), 0.0)
-        dp = jnp.einsum("bqhd,bkhd->bhqk", g_i, v_j.astype(jnp.float32))
-        ds = p * (dp - delta_i[..., None]) * scale
-        return p, ds
-
-    def dq_block(i):
-        q_i = qb[:, i]
-        g_i = gb[:, i]
-        lse_i = lse_b[:, :, i]
-        delta_i = delta_b[:, :, i]
-
-        def body(j, dq):
-            def go(dq):
-                k_j = jax.lax.dynamic_index_in_dim(kb, j, axis=1, keepdims=False)
-                v_j = jax.lax.dynamic_index_in_dim(vb, j, axis=1, keepdims=False)
-                _, ds = p_ds(i, j, q_i, k_j, v_j, g_i, lse_i, delta_i)
-                return dq + jnp.einsum(
-                    "bhqk,bkhd->bqhd", ds, k_j.astype(jnp.float32)
-                )
-
-            if causal:
-                visible = _in_band(j <= i, i, j, block, window)
-                return jax.lax.cond(visible, go, lambda x: x, dq)
-            return go(dq)
-
-        dq = jnp.zeros((b, rows, h, d), jnp.float32)
-        return jax.lax.fori_loop(0, n_blocks, body, dq)
-
-    def dkv_block(j):
-        k_j = kb[:, j]
-        v_j = vb[:, j]
-
-        def body(i, carry):
-            def go(carry):
-                dk, dv = carry
-                q_i = jax.lax.dynamic_index_in_dim(qb, i, axis=1, keepdims=False)
-                g_i = jax.lax.dynamic_index_in_dim(gb, i, axis=1, keepdims=False)
-                lse_i = lse_b[:, :, i]
-                delta_i = delta_b[:, :, i]
-                p, ds = p_ds(i, j, q_i, k_j, v_j, g_i, lse_i, delta_i)
-                dv = dv + jnp.einsum("bhqk,bqhd->bkhd", p, g_i)
-                dk = dk + jnp.einsum(
-                    "bhqk,bqhd->bkhd", ds, q_i.astype(jnp.float32)
-                )
-                return dk, dv
-
-            if causal:
-                visible = _in_band(i >= j, i, j, block, window)
-                return jax.lax.cond(visible, go, lambda c: c, carry)
-            return go(carry)
-
         dk = jnp.zeros((b, block, h, d), jnp.float32)
         dv = jnp.zeros((b, block, h, d_v), jnp.float32)
-        return jax.lax.fori_loop(0, n_blocks, body, (dk, dv))
+        dq, dk, dv = jax.lax.fori_loop(
+            first * chunks, last * chunks, step, (dq, dk, dv)
+        )
+        return dq, (dk, dv)
 
-    dq = jax.lax.map(dq_block, jnp.arange(n_blocks))
-    dk, dv = jax.lax.map(dkv_block, jnp.arange(n_blocks))
+    dq = jnp.zeros((b, n_blocks, rows, h, d), jnp.float32)
+    dq, (dk, dv) = jax.lax.scan(key_block, dq, jnp.arange(n_blocks))
 
     def unblk(x):
         return jnp.moveaxis(x, 0, 1).reshape(b, -1, *x.shape[3:])
 
     return (
-        unblk(dq).astype(q.dtype),
+        dq.reshape(q.shape).astype(q.dtype),
         unblk(dk).astype(k.dtype),
         unblk(dv).astype(v.dtype),
     )
