@@ -4,6 +4,7 @@ hand-computed expectations, missing-info errors)."""
 
 import threading
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -456,6 +457,44 @@ def test_fedavg_streaming_fold_matches_reference_math():
     )
     assert out2.get_contributors() == ["a", "b", "c"]
     assert out2.get_num_samples() == 6
+
+
+def test_fedavg_fold_of_64_contributors_holds_one_model(monkeypatch):
+    """Aggregation memory is flat in the contributor count: the fold is
+    one running accumulator the size of ONE model after every
+    contribution — the O(N x model) stack is never built."""
+    from tpfl.learning.aggregators import aggregator as agg_mod
+
+    def no_stack(models):
+        raise AssertionError("stack_models materialises N models")
+
+    monkeypatch.setattr(agg_mod, "stack_models", no_stack)
+    agg = FedAvg("t")
+    models = [mk_model(i, 1 + i % 3, [f"n{i}"]) for i in range(64)]
+    one_model = sum(
+        leaf.nbytes
+        for leaf in jax.tree_util.tree_leaves(models[0].get_parameters())
+    )
+    st = agg.acc_init(models[0])
+    held = set()
+    for m in models:
+        st = agg.accumulate(st, m)
+        held.add(
+            sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(st.acc))
+        )
+    assert st.count == 64
+    # The same few bytes after the 1st contribution and after the 64th.
+    assert len(held) == 1 and held.pop() < 3 * one_model
+    out = agg.finalize(st)
+    want = sum(i * (1 + i % 3) for i in range(64)) / sum(
+        1 + i % 3 for i in range(64)
+    )
+    np.testing.assert_allclose(
+        np.asarray(out.get_parameters()["w"]), want, rtol=1e-5
+    )
+    np.testing.assert_allclose(
+        np.asarray(agg.aggregate(models).get_parameters()["w"]), want, rtol=1e-5
+    )
 
 
 def test_eager_stream_reduces_on_arrival_and_closes_with_finalize():

@@ -395,11 +395,14 @@ def test_stale_flood_quarantined_and_readmitted_via_aggregator():
 # --- e2e: controller determinism + the stale-flooding fleet ----------------
 
 
-@pytest.mark.slow
-def test_controller_serialized_same_seed_identical_trajectories():
+@pytest.mark.parametrize(
+    "rounds", [2, pytest.param(4, marks=pytest.mark.slow)]
+)
+def test_controller_serialized_same_seed_identical_trajectories(rounds):
     """Two same-seed serialized runs with the adaptive controller on
     produce identical K/deadline trajectories at every node (the
-    virtual-clock observation discipline), and stay byte-identical."""
+    virtual-clock observation discipline), and stay byte-identical —
+    across the runs and across the nodes of a run."""
     from tpfl.attacks import controller_trajectories, run_seeded_experiment
     from tpfl.attacks.harness import final_model_digests
     from tpfl.communication.faults import TrainerSpeedPlan
@@ -416,7 +419,7 @@ def test_controller_serialized_same_seed_identical_trajectories():
             slow_frac=0.34, base_delay=0.05, skew=5.0, seed=151,
         )
         exp = run_seeded_experiment(
-            151, 3, 4, epochs=1, speed_plan=plan,
+            151, 3, rounds, epochs=1, speed_plan=plan,
             samples_per_node=60, batch_size=20, timeout=180.0,
         )
         return final_model_digests(exp), controller_trajectories(exp)
@@ -425,6 +428,7 @@ def test_controller_serialized_same_seed_identical_trajectories():
     assert t1 == t2
     assert all(traj for traj in t1.values())  # every node decided
     assert d1 == d2
+    assert len(set(d1.values())) == 1
 
 
 @pytest.mark.slow

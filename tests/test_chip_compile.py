@@ -48,8 +48,6 @@ def described():
         ("flash_4k_h16_d128", 3, 0),
         ("ring_flash_sp4", 6, 1),
         ("ssm_scan_8k_x2", 2, 0),
-        ("node_conv_c32", 2, 0),
-        ("node_conv_c32_vmapped", 2, 0),
     ],
 )
 def test_kernel_compiles_for_described_v5e(described, case, kernels, permutes):
@@ -57,15 +55,6 @@ def test_kernel_compiles_for_described_v5e(described, case, kernels, permutes):
     report = rehearsal.compile_report(case, fn, args)
     assert report["tpu_custom_call"] == kernels, report
     assert report["collective_permute"] >= permutes, report
-
-
-def test_narrow_stem_takes_the_xla_backward(described):
-    """Cin=3 would pad 42x on the lane axis (the chip's compiler refused
-    the 100-node CNN round program for it): node_conv routes it through
-    the forward-style XLA backward — no kernel call in the program."""
-    fn, args = rehearsal.cases(described)["node_conv_c3_fallback"]()
-    report = rehearsal.compile_report("node_conv_c3_fallback", fn, args)
-    assert report["tpu_custom_call"] == 0, report
 
 
 def test_lm_head_owns_its_loss_in_the_compiled_window(described):

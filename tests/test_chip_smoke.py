@@ -1,7 +1,6 @@
 """chip_smoke.py's contract, as far as a CPU can hold it to it: the one
-compile-cache rule, the device helper that refuses a CPU, a smoke that
-never prints ``"ok": true`` without a chip or after a failed phase, and
-a bench that exits non-zero when a tier recorded ``_error``.
+compile-cache rule, the device helper that refuses a CPU, and a smoke
+that never prints ``"ok": true`` without a chip or after a failed phase.
 
 The ``slow`` walk-through rehearses the one-chip phases' control flow
 at toy sizes (the on-chip-measurement guide's first rehearsal) — run it
@@ -18,7 +17,7 @@ import jax
 import pytest
 
 REPO = Path(__file__).resolve().parent.parent
-sys.path.insert(0, str(REPO))  # chip_smoke / bench live at the root
+sys.path.insert(0, str(REPO))  # chip_smoke lives at the root
 
 import chip_smoke  # noqa: E402
 
@@ -166,40 +165,6 @@ def test_failing_phase_ends_the_run_with_no_ok_line(bad, exc):
         chip_smoke.run_phases(phases, DEVICE, emit=lines.append)
     assert ran == ["first"]
     assert len(lines) == 1 and '"ok"' not in lines[0]
-
-
-# --- bench: a failed tier is a failed run --------------------------------
-
-
-def test_bench_exits_nonzero_when_a_tier_recorded_error(monkeypatch, capsys):
-    import bench
-
-    assert bench._tier_errors({"a": 1, "x_error": "boom", "b_error": ""}) == [
-        "b_error", "x_error"
-    ]
-    monkeypatch.setattr(
-        bench, "_chaos_tier", lambda extra: extra.update(chaos_error="boom")
-    )
-    monkeypatch.setattr(sys, "argv", ["bench.py", "--tiers", "chaos"])
-    monkeypatch.setattr(profiling, "ensure_compile_cache", lambda d=None: "")
-    with pytest.raises(SystemExit) as exit_info:
-        bench.main()
-    assert exit_info.value.code not in (0, None)
-    out = capsys.readouterr()
-    doc = json.loads(out.out.strip().splitlines()[-1])
-    assert doc["extra"]["chaos_error"] == "boom"
-    assert (doc["platform"], doc["device_kind"]) == ("cpu", "cpu")
-    assert doc["device_count"] == len(jax.devices())
-    assert "chaos_error" in out.err
-
-
-def test_bench_device_tiers_refuse_a_cpu(monkeypatch):
-    import bench
-
-    monkeypatch.setattr(sys, "argv", ["bench.py", "--tiers", "sim1000"])
-    monkeypatch.setattr(profiling, "ensure_compile_cache", lambda d=None: "")
-    with pytest.raises(RuntimeError, match="no TPU"):
-        bench.main()
 
 
 # --- CPU walk-through of the one-chip phases (slow, not tier-1) ----------

@@ -116,6 +116,49 @@ def test_pipeline_byte_identical_with_fedbuff_and_telemetry():
     assert _bytes(ps) == _bytes(pp)
 
 
+@pytest.mark.parametrize("mesh_devices", [None, 8])
+def test_pipelined_fedbuff_same_seed_byte_identical(mesh_devices):
+    """Two from-scratch pipelined FedBuff runs with one seed end on the
+    same bytes, at 1 device and on the 8-device ``nodes`` mesh."""
+    mesh = (
+        create_mesh({"nodes": mesh_devices}) if mesh_devices else None
+    )
+    sched = FedBuffSchedule.from_periods([1 + (i % 3) for i in range(8)], 6)
+
+    def run():
+        p, _, _ = _run_pipelined(8, mesh, 6, 2, schedule=sched)
+        return _bytes(p)
+
+    assert run() == run()
+
+
+def test_learner_prefetch_changes_no_byte_and_leaks_no_thread():
+    """``ENGINE_PREFETCH`` is a host-side overlap only: a
+    ``FederationLearner`` fit with it on and off gives the same model
+    bytes, and no ``tpfl-window-prefetch`` thread outlives the fit."""
+    from tpfl.learning.dataset import synthetic_mnist
+    from tpfl.models import create_model
+    from tpfl.parallel import FederationLearner
+
+    ds = synthetic_mnist(n_train=256, n_test=32, seed=0, noise=0.4)
+    Settings.SHARD_ROUNDS_PER_DISPATCH = 2
+
+    def fit_bytes(prefetch):
+        Settings.ENGINE_PREFETCH = prefetch
+        learner = FederationLearner(
+            model=create_model("mlp", (28, 28), seed=7, hidden_sizes=(16,)),
+            data=ds,
+            n_local_nodes=4,
+            local_rounds=4,
+            batch_size=16,
+            seed=0,
+        )
+        return _bytes(learner.fit().get_parameters())
+
+    assert fit_bytes(False) == fit_bytes(True)
+    assert not [t for t in threading.enumerate() if "prefetch" in t.name]
+
+
 def test_donation_still_clean():
     """The dispatch_window refactor kept end-to-end buffer aliasing:
     every donated state leaf still aliases an output buffer."""

@@ -252,6 +252,31 @@ def test_wire_bytes_carry_and_registry_series():
     Settings.ENGINE_TELEMETRY = False
 
 
+def test_exchange_bytes_from_the_carry_drop_3x_under_quant8():
+    """The production scrape path, as byte COUNTS: the per-round
+    exchange bytes the device-side carry records, read back through
+    ``run_rounds``' fan-out as the ``tpfl_engine_wire_bytes`` gauge —
+    quant8 moves at least 3x fewer than dense (f32 models sit just
+    under 4x; envelope overhead is a host concept, on neither side)."""
+    Settings.ENGINE_TELEMETRY = True
+
+    def gauge(codec):
+        metrics.reset()
+        _run(None, codec, rounds=2)
+        vals = [
+            v
+            for k, v in metrics.fold()["gauges"].items()
+            if k[0] == "tpfl_engine_wire_bytes"
+        ]
+        assert vals, codec
+        return float(vals[-1])
+
+    dense_bytes, quant_bytes = gauge("dense"), gauge("quant8")
+    Settings.ENGINE_TELEMETRY = False
+    assert quant_bytes > 0
+    assert dense_bytes >= 3 * quant_bytes, (dense_bytes, quant_bytes)
+
+
 # --- (e) donation ---------------------------------------------------------
 
 

@@ -180,12 +180,49 @@ def test_detections_dedup_across_observers():
     assert "sign_flip" in det["flagged"]["adv"]["reasons"]
     assert "honest-0" in det["peers"]
 
-    # Same inputs -> byte-identical verdict (the bench ledger tier
-    # asserts this across whole federation runs).
+    # Same inputs -> byte-identical verdict.
     import json
 
     again = ledger.contrib.detections()
     assert json.dumps(det, sort_keys=True) == json.dumps(again, sort_keys=True)
+
+
+def test_detections_byte_identical_across_arrival_orders():
+    """The determinism receipt: the deduped verdict is a function of
+    WHAT was contributed, not of the order gossip delivered it in or of
+    which observer saw it — two "runs" of one seeded round, recorded in
+    opposite arrival orders by differently named observers, give a
+    byte-identical flag surface."""
+    import json
+
+    Settings.LEDGER_ENABLED = True
+    ref = _ref_params()
+    flip_params = sign_flip()(ref)
+
+    def contributions():
+        out = [(_honest(ref, i), f"honest-{i}") for i in range(4)]
+        return out + [(flip_params, "adv")]
+
+    def run(observers, reverse):
+        ledger.contrib.reset()
+        for obs in observers:
+            ledger.contrib.open_round(obs, 0, ref)
+            items = contributions()
+            for params, who in reversed(items) if reverse else items:
+                ledger.contrib.record(obs, _model(params, who))
+        det = ledger.contrib.detections()
+        return json.dumps(
+            [
+                {k: e[k] for k in ("peer", "round", "flagged", "reasons")}
+                for e in det["entries"]
+            ],
+            sort_keys=True,
+        ), set(det["flagged"])
+
+    surface_a, flagged_a = run(("obs-a", "obs-b"), reverse=False)
+    surface_b, flagged_b = run(("obs-c",), reverse=True)
+    assert flagged_a == flagged_b == {"adv"}
+    assert surface_a == surface_b
 
 
 # --- disabled path --------------------------------------------------------

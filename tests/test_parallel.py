@@ -5,6 +5,7 @@ vs the sequential aggregator path, mask semantics, sharded trainer."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import optax
 import pytest
 
 from tpfl.learning.dataset import synthetic_mnist, RandomIIDPartitionStrategy
@@ -1074,7 +1075,7 @@ def test_moe_rejects_mismatched_experts_and_drops_invalid_routes():
     np.testing.assert_array_equal(out, x)
 
 
-# --- per-node conv backward lowerings (tpfl.parallel.conv_kernel) ---
+# --- per-node conv backward lowering (tpfl.models.zoo.conv_fwd_style) ---
 
 
 def test_conv_fwd_style_grads_match_autodiff():
@@ -1082,11 +1083,11 @@ def test_conv_fwd_style_grads_match_autodiff():
     convs must produce the SAME gradients as plain autodiff through
     lax.conv — including under vmap over a nodes axis (the federation
     composition)."""
-    from tpfl.parallel.conv_kernel import _DN, conv_fwd_style
+    from tpfl.models.zoo import conv_fwd_style
 
     rng = np.random.default_rng(0)
     ref = lambda x, w: jax.lax.conv_general_dilated(
-        x, w, (1, 1), "SAME", dimension_numbers=_DN)
+        x, w, (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC"))
 
     for shape in [(2, 8, 8, 3, 5), (2, 6, 10, 7, 4)]:
         B, H, W, Cin, Cout = shape
@@ -1117,69 +1118,45 @@ def test_conv_fwd_style_grads_match_autodiff():
     )
 
 
-def test_pallas_conv_backward_matches_autodiff_interpret():
-    """node_conv: the Pallas im2col backward (dW accumulate + dx
-    transposed-conv kernels, interpret mode on CPU) matches autodiff,
-    including non-square spatial dims and under vmap."""
-    from tpfl.parallel.conv_kernel import _DN, node_conv
-
-    rng = np.random.default_rng(1)
-    ref = lambda x, w: jax.lax.conv_general_dilated(
-        x, w, (1, 1), "SAME", dimension_numbers=_DN)
-
-    # Cin >= 8 takes the Pallas kernels; the narrow (4, 8, 8, 3, 5) case
-    # takes node_conv's forward-style XLA fallback (lane padding, see
-    # conv_kernel._MIN_LANE_CHANNELS) and must agree just the same.
-    for shape in [
-        (4, 8, 8, 8, 5), (2, 16, 16, 32, 8), (2, 6, 10, 9, 3),
-        (4, 8, 8, 3, 5),
-    ]:
-        B, H, W, Cin, Cout = shape
-        x = jnp.asarray(rng.normal(size=(B, H, W, Cin)), jnp.float32)
-        w = jnp.asarray(rng.normal(size=(3, 3, Cin, Cout)), jnp.float32)
-        out_k = node_conv(x, w, True)
-        out_r = ref(x, w)
-        np.testing.assert_allclose(
-            np.asarray(out_k), np.asarray(out_r), rtol=1e-5, atol=1e-5
-        )
-        gx_k, gw_k = jax.grad(
-            lambda a, b: jnp.sum(node_conv(a, b, True) ** 2), argnums=(0, 1)
-        )(x, w)
-        gx_r, gw_r = jax.grad(
-            lambda a, b: jnp.sum(ref(a, b) ** 2), argnums=(0, 1)
-        )(x, w)
-        np.testing.assert_allclose(
-            np.asarray(gx_k), np.asarray(gx_r), rtol=1e-4, atol=1e-3
-        )
-        np.testing.assert_allclose(
-            np.asarray(gw_k), np.asarray(gw_r), rtol=1e-4, atol=1e-3
-        )
-
-    n = 3
-    xs = jnp.asarray(rng.normal(size=(n, 2, 8, 8, 8)), jnp.float32)
-    ws = jnp.asarray(rng.normal(size=(n, 3, 3, 8, 4)), jnp.float32)
-    gk = jax.grad(lambda ws: jnp.sum(
-        jax.vmap(lambda x, w: node_conv(x, w, True))(xs, ws) ** 2))(ws)
-    gr = jax.grad(lambda ws: jnp.sum(jax.vmap(ref)(xs, ws) ** 2))(ws)
-    np.testing.assert_allclose(
-        np.asarray(gk), np.asarray(gr), rtol=1e-4, atol=1e-3
-    )
-
-
 def test_cnn_conv_impls_share_param_tree_and_forward():
     """CNN conv_impl variants must be drop-in interchangeable: same
-    param tree (paths+shapes), same init values, same forward."""
+    param tree (paths+shapes), same init values, same forward — and the
+    same gradients: ``fwd_bwd``'s reformulated backward against plain
+    autodiff through ``nn.Conv``."""
     from tpfl.models import CNN
 
-    x = jnp.asarray(
-        np.random.default_rng(0).normal(size=(2, 32, 32, 3)), jnp.float32
-    )
-    outs, trees = [], []
-    for impl in ("fwd_bwd", "xla", "pallas"):
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.normal(size=(2, 32, 32, 3)), jnp.float32)
+    y = jnp.asarray(rng.integers(0, 10, (2,)))
+    outs, trees, grads = [], [], []
+    for impl in ("fwd_bwd", "xla"):
         m = CNN(out_channels=10, conv_impl=impl, compute_dtype=jnp.float32)
         v = m.init(jax.random.PRNGKey(7), x, train=False)
         trees.append(jax.tree_util.tree_structure(v["params"]))
         outs.append(m.apply(v, x, train=False))
-    assert trees[0] == trees[1] == trees[2]
+
+        def loss(params, m=m):
+            logits = m.apply({"params": params}, x, train=True)
+            return optax.softmax_cross_entropy_with_integer_labels(
+                logits, y
+            ).mean()
+
+        grads.append(jax.grad(loss)(v["params"]))
+    assert trees[0] == trees[1]
     np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(outs[1]), atol=1e-6)
-    np.testing.assert_allclose(np.asarray(outs[0]), np.asarray(outs[2]), atol=1e-6)
+    for a, b in zip(
+        jax.tree_util.tree_leaves(grads[0]), jax.tree_util.tree_leaves(grads[1])
+    ):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5
+        )
+
+
+def test_cnn_conv_impl_pallas_is_gone():
+    """The Pallas conv lost its only measurement and went (PR 29): the
+    value raises, naming the two that remain."""
+    from tpfl.models import CNN
+
+    x = jnp.zeros((1, 32, 32, 3), jnp.float32)
+    with pytest.raises(ValueError, match="fwd_bwd.*xla"):
+        CNN(conv_impl="pallas").init(jax.random.PRNGKey(0), x, train=False)

@@ -13,24 +13,18 @@ Coverage map:
   xla_flops path on a compiled matmul, MFU math against a fake device.
 - HbmTracker: high-water-mark semantics over injected memory_stats.
 - Compiled-program cache gauges (collector) + clears counter.
-- Perf regression gate: compare_to_baseline semantics (directions,
-  tolerances, booleans, missing/required), and the bench.py --check
-  CLI passing the committed baseline against itself while failing an
-  injected 20% regression.
 - Experiment profile_dir capture + maybe_trace being a no-op without a
   directory.
 """
 
-import json
 import pathlib
-import subprocess
 import sys
 import time
 
 import pytest
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
-sys.path.insert(0, str(REPO))  # `tools` / bench imports
+sys.path.insert(0, str(REPO))  # `tools` imports
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -283,13 +277,12 @@ def test_cost_model_mlp_flops_vs_hand_computed():
     ) == 3 * 2 * mults * 64
 
 
-def test_cost_model_cnn_flops_match_bench_hand_formula():
+def test_cost_model_cnn_flops_match_hand_formula():
     from tpfl.models import CNN
 
     cnn = CNN(out_channels=10)
     got = profiling.cost_model.analytic_fwd_mults(cnn, (32, 32, 3))
-    # The hand formula bench.py used inline before the dedupe (3x3 SAME
-    # convs, 2x2 max-pools, dense head) — byte-for-byte the same math.
+    # By hand: 3x3 SAME convs, 2x2 max-pools, dense head.
     h = w = 32
     cin = 3
     mults = 0
@@ -360,137 +353,6 @@ def test_hbm_tracker_gauges_the_reserved_peak_where_reported():
         "hbm-test-9", {"bytes_in_use": 100, "peak_bytes_reserved": 900}
     )
     assert metrics.fold()["gauges"][key] == 900.0
-
-
-# --- perf regression gate -------------------------------------------------
-
-
-def _gate_baseline():
-    return {
-        "metrics": {
-            "thr": {"path": "value", "baseline": 100.0, "tolerance": 0.2},
-            "bytes": {
-                "path": "extra.bytes",
-                "baseline": 1000,
-                "direction": "lower",
-                "tolerance": 0.2,
-            },
-            "flag": {
-                "path": "extra.ok",
-                "baseline": True,
-                "tolerance": 0.0,
-                "required": True,
-            },
-            "optional": {"path": "extra.absent", "baseline": 5.0},
-        }
-    }
-
-
-def test_gate_passes_within_tolerance_and_skips_missing():
-    verdict = profiling.compare_to_baseline(
-        {"value": 85.0, "extra": {"bytes": 1150, "ok": True}},
-        _gate_baseline(),
-    )
-    assert verdict["pass"]
-    assert {e["metric"] for e in verdict["skipped"]} == {"optional"}
-
-
-def test_gate_fails_on_20pct_throughput_regression():
-    verdict = profiling.compare_to_baseline(
-        {"value": 79.9, "extra": {"bytes": 1000, "ok": True}},
-        _gate_baseline(),
-    )
-    assert not verdict["pass"]
-    bad = [e for e in verdict["checked"] if not e["ok"]]
-    assert [e["metric"] for e in bad] == ["thr"]
-
-
-def test_gate_direction_lower_and_required_and_booleans():
-    base = _gate_baseline()
-    # Bytes growing past tolerance regresses a lower-is-better metric.
-    assert not profiling.compare_to_baseline(
-        {"value": 100.0, "extra": {"bytes": 1300, "ok": True}}, base
-    )["pass"]
-    # A required metric missing from the run fails the gate.
-    assert not profiling.compare_to_baseline(
-        {"value": 100.0, "extra": {"bytes": 900}}, base
-    )["pass"]
-    # A False acceptance boolean fails its exact-tolerance check.
-    assert not profiling.compare_to_baseline(
-        {"value": 100.0, "extra": {"bytes": 900, "ok": False}}, base
-    )["pass"]
-
-
-def _synthesize_results(baseline: dict) -> dict:
-    """A results document that hits every baseline path at exactly the
-    baseline value (the 'committed baseline passes against itself'
-    acceptance case)."""
-    doc: dict = {"extra": {}}
-    for spec in baseline["metrics"].values():
-        cur = doc
-        parts = spec["path"].split(".")
-        for part in parts[:-1]:
-            cur = cur.setdefault(part, {})
-        cur[parts[-1]] = spec["baseline"]
-    return doc
-
-
-@pytest.mark.parametrize("baseline_name", ["BENCH_BASELINE.json", "BENCH_BASELINE_CPU.json"])
-def test_bench_check_cli_passes_committed_baseline_and_fails_regression(
-    tmp_path, baseline_name
-):
-    """bench.py --check exits 0 on the committed baseline's own values
-    and nonzero on an injected >=20% regression (satellite acceptance;
-    the --results path runs no tiers, so this is subprocess-cheap)."""
-    baseline_path = REPO / baseline_name
-    baseline = json.loads(baseline_path.read_text())
-    ok_doc = _synthesize_results(baseline)
-    ok_file = tmp_path / "ok.json"
-    ok_file.write_text(json.dumps(ok_doc))
-
-    def run(results_file):
-        return subprocess.run(
-            [
-                sys.executable,
-                str(REPO / "bench.py"),
-                "--check",
-                str(baseline_path),
-                "--results",
-                str(results_file),
-            ],
-            capture_output=True,
-            text=True,
-            timeout=120,
-            cwd=str(REPO),
-        )
-
-    proc = run(ok_file)
-    assert proc.returncode == 0, proc.stderr
-    verdict = json.loads(proc.stdout.strip().splitlines()[-1])["check"]
-    assert verdict["pass"] and verdict["checked"]
-
-    # Degrade every higher-is-better numeric metric by 20%+eps, inflate
-    # every lower-is-better one likewise: the gate must catch it.
-    bad_doc = _synthesize_results(baseline)
-    for spec in baseline["metrics"].values():
-        base = spec["baseline"]
-        if isinstance(base, bool) or not isinstance(base, (int, float)):
-            continue
-        factor = (
-            1.0 + spec.get("tolerance", 0.2) + 0.05
-            if spec.get("direction", "higher") == "lower"
-            else 1.0 - spec.get("tolerance", 0.2) - 0.05
-        )
-        cur = bad_doc
-        parts = spec["path"].split(".")
-        for part in parts[:-1]:
-            cur = cur[part]
-        cur[parts[-1]] = base * factor
-    bad_file = tmp_path / "bad.json"
-    bad_file.write_text(json.dumps(bad_doc))
-    proc = run(bad_file)
-    assert proc.returncode != 0
-    assert "PERF REGRESSION" in proc.stderr
 
 
 # --- trace wrap / Experiment capture --------------------------------------

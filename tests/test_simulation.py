@@ -160,6 +160,26 @@ def test_unequal_partition_sizes_batch_with_padding():
     assert big.get_model().get_num_samples() == 160
 
 
+def test_job_signature_reads_device_leaves_and_keeps_sharing():
+    """``job_signature`` is computed from the leaves' shapes and dtypes
+    where they live: a model whose parameters are device arrays signs
+    like its host twin (no ``np.asarray`` copy path), and two learners
+    of one architecture still share a batched program."""
+    import jax.numpy as jnp
+
+    from tpfl.simulation.batched_fit import job_signature
+
+    on_device = make_learner("sig-0")
+    model = on_device.get_model()
+    model.set_parameters(
+        [jnp.asarray(p) for p in model.get_parameters_list()]
+    )
+    sig = job_signature(on_device)
+    assert sig[2] and all(dtype == "float32" for _shape, dtype in sig[2])
+    assert job_signature(make_learner("sig-1", seed=1)) == sig
+    assert job_signature(make_learner("sig-2", hidden=(8,))) != sig
+
+
 def test_heterogeneous_jobs_fall_back():
     """Different architectures can't batch; both still train."""
     a = make_learner("het-a", hidden=(16,))

@@ -240,27 +240,6 @@ def ring_case(devices, seq: int = 8192) -> tuple:
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2))), (x, x, x)
 
 
-def node_conv_case(x_shape, cout: int, device, nodes: int = 0) -> tuple:
-    """(jitted node_conv backward, args) — ``nodes`` > 0 vmaps it over
-    a leading node axis on both operands, as ``CNN(conv_impl="pallas")``
-    inside the engine's vmapped local train does."""
-    from tpfl.parallel.conv_kernel import node_conv
-
-    def loss(x, w):
-        return jnp.sum(node_conv(x, w, False).astype(jnp.float32) ** 2)
-
-    grad = jax.grad(loss, argnums=(0, 1))
-    w_shape = (3, 3, x_shape[-1], cout)
-    if nodes:
-        grad = jax.vmap(grad)
-        x_shape, w_shape = (nodes, *x_shape), (nodes, *w_shape)
-    sh = SingleDeviceSharding(device)
-    return jax.jit(grad), (
-        jax.ShapeDtypeStruct(x_shape, jnp.bfloat16, sharding=sh),
-        jax.ShapeDtypeStruct(w_shape, jnp.bfloat16, sharding=sh),
-    )
-
-
 def lm_step_case(seq: int, device) -> tuple:
     """(chip_smoke's jitted TransformerLM + flash SGD step, args)."""
     import chip_smoke
@@ -375,23 +354,10 @@ def cases(devices) -> dict:
         # The Mamba layer of the benchmark's SambaY cell: one 8192-token
         # sequence a silo, 5120 channels x 16 states, two silos vmapped.
         "ssm_scan_8k_x2": lambda: scan_case((1, 8192, 5120), 16, 2, d0),
-        # Cin=3 takes node_conv's XLA fallback by design (0 kernel
-        # calls): lane padding made the Pallas stem 42x its size.
-        "node_conv_c3_fallback": lambda: node_conv_case(
-            (128, 32, 32, 3), 32, d0
-        ),
-        "node_conv_c32": lambda: node_conv_case((128, 16, 16, 32), 64, d0),
-        "node_conv_c32_vmapped": lambda: node_conv_case(
-            (128, 16, 16, 32), 64, d0, nodes=100
-        ),
         "lm_step_8k": lambda: lm_step_case(8192, d0),
         "engine_cnn100": lambda: cnn(n_rounds=4),
         "engine_cnn100_obs_q8": lambda: cnn(telemetry=True, codec=q8),
         "engine_cnn100_scaffold": lambda: cnn(algorithm="scaffold"),
-        "engine_cnn100_pallas_conv": lambda: engine_case(
-            CNN(out_channels=10, conv_impl="pallas"), 100, (4, 128),
-            (32, 32, 3), devices,
-        ),
         "engine_resnet18x16": lambda: engine_case(
             ResNet18(out_channels=100), 16, (2, 128), (32, 32, 3), devices
         ),

@@ -24,8 +24,8 @@ scope over ``tpfl/``:
 Indirect dispatch (``fn(*args)``, attribute-held programs, donation
 decided at a different call depth) is out of static reach — the lint
 is best-effort on the engine/learner seams, and waivable
-(``donate:<file>::<scope>::<name>``). The dynamic complement is the
-engine_wire bench tier's donation inspection
+(``donate:<file>::<scope>::<name>``). The dynamic complement is
+``tests/test_engine_wire.py::test_donation_report_clean``
 (``tpfl.parallel.engine.donation_analysis``), which checks what the
 compiled executable really aliases.
 """

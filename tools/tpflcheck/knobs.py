@@ -3,7 +3,7 @@
 Four invariants over ``tpfl/settings.py``:
 
 1. **Existence** — every ``Settings.X`` attribute reference in code
-   (``tpfl/``, ``bench.py``, ``tools/``; AST-based, so docstring
+   (``tpfl/``, ``tools/``; AST-based, so docstring
    mentions don't count) names a declared knob. A typo'd knob
    silently reads as AttributeError at runtime, usually inside a
    rarely-exercised branch.
@@ -79,16 +79,12 @@ def _referenced_knobs(root: pathlib.Path) -> dict[str, list[tuple[str, int]]]:
     outside settings.py itself."""
     refs: dict[str, list[tuple[str, int]]] = {}
     files = py_files(root)
-    for extra in ("bench.py",):
-        p = root / extra
-        if p.exists():
-            files.append(p)
     tools_dir = root / "tools"
     if tools_dir.exists():
         files.extend(
             p
             for p in sorted(tools_dir.rglob("*.py"))
-            if "__pycache__" not in p.parts and "perf" not in p.parts
+            if "__pycache__" not in p.parts
         )
     for path in files:
         r = rel(root, path)
@@ -185,6 +181,6 @@ def check_knobs(
     for name in sorted(knobs - set(refs)):
         warnings.append(
             f"knob Settings.{name} is declared but never referenced in "
-            "tpfl/, bench.py, or tools/"
+            "tpfl/ or tools/"
         )
     return violations, warnings

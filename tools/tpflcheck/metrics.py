@@ -3,8 +3,8 @@ anywhere in ``tpfl/`` must appear in ``docs/observability.md``.
 
 The events lint's contract, extended to the registry plane: the metric
 taxonomy is DOCUMENTED DATA (the per-plane series tables in
-docs/observability.md — what scrapes, dashboards and the bench gates
-key on), and a new ``metrics.counter/gauge/observe`` site whose name
+docs/observability.md — what scrapes and dashboards key on), and a new
+``metrics.counter/gauge/observe`` site whose name
 never lands in the doc rots it silently. This pass closes the loop:
 
 - **emitted** names are collected by AST walk over ``tpfl/``: the

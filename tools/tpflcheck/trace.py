@@ -1,17 +1,15 @@
 """Timing/logging-path lint: spans and metrics are the only sanctioned
 timing path.
 
-Two invariants over ``tpfl/``, ``tools/`` and the root bench/dryrun
-scripts (the management layer is exempt — it IS the telemetry/
-profiling implementation and owns the wall-clock anchor; ``tools/perf``
-is exempt — superseded lab-notebook scratch scripts, see their
-README):
+Two invariants over ``tpfl/``, ``tools/`` and the root dry-run script
+(the management layer is exempt — it IS the telemetry/profiling
+implementation and owns the wall-clock anchor):
 
 1. **No ``time.time()``** — every duration, deadline, and stamp must
    come from ``time.monotonic()`` / ``time.perf_counter()`` (NTP-step
    immunity — the aggregator stall clock and round deadlines moved
-   first; this lint keeps the rest, INCLUDING new timing code in the
-   bench and the profiling subsystem's call sites, from regressing) or
+   first; this lint keeps the rest, INCLUDING the profiling
+   subsystem's call sites, from regressing) or
    flow through the spans in :mod:`tpfl.management.tracing` /
    :mod:`tpfl.management.profiling`, which timestamp monotonically and
    carry the process wall anchor for cross-process merges.
@@ -54,14 +52,10 @@ _LOGGING_CALLS = {
 }
 
 
-#: Lab-notebook scratch scripts (tools/perf/README.md): frozen
-#: measurement receipts, not maintained code — outside the lint.
-EXEMPT_PREFIXES = ("tools/perf/",)
-
-#: Root-level scripts with timing code the lint also covers (new
-#: timing in the bench must ride monotonic()/perf_counter() or the
-#: profiling API, same as the package).
-ROOT_SCRIPTS = ("bench.py", "__graft_entry__.py")
+#: Root-level scripts with timing code the lint also covers (their
+#: timing must ride monotonic()/perf_counter() or the profiling API,
+#: same as the package).
+ROOT_SCRIPTS = ("__graft_entry__.py",)
 
 
 def _lint_files(root: "pathlib.Path") -> "list[pathlib.Path]":
@@ -77,8 +71,6 @@ def check_trace(repo: "pathlib.Path | None" = None) -> list[Violation]:
     for path in _lint_files(root):
         r = rel(root, path)
         if r.startswith(ALLOWED_PREFIX) and r not in LINTED_MANAGEMENT:
-            continue
-        if any(r.startswith(p) for p in EXEMPT_PREFIXES):
             continue
         tree = core.parse(path)
         for node in ast.walk(tree):

@@ -45,7 +45,7 @@ its statistics, and was it flagged" — the update's network journey and
 its learning-plane verdict on one line.
 
 Pure functions (:func:`build_timeline`, :func:`hop_path`,
-:func:`ledger_report`) are the test/bench surface; the CLI is a thin
+:func:`ledger_report`) are the test surface; the CLI is a thin
 formatter over them.
 """
 

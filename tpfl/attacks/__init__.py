@@ -19,7 +19,7 @@ measures the effect on federation metrics. Here attacks are first-class:
   ramp/once/always), the adversarial mirror of
   :class:`~tpfl.communication.faults.FaultPlan`, composable with a
   fault plan into one chaos spec and carrying the ground-truth
-  ``adversary_map`` detection benchmarks score against.
+  ``adversary_map`` detection tests score against.
 
 See :mod:`tpfl.attacks.harness` for the seeded reproducibility harness
 (``exp_SAVE3.txt:282-332``).

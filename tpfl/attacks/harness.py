@@ -18,8 +18,8 @@ from tpfl.attacks.attacks import AttackFn, make_adversary
 
 #: Ground-truth adversary registry: ``exp_name -> {addr: attack name}``
 #: recorded by :func:`run_seeded_experiment` for every adversarial run.
-#: This is what detection benchmarks (bench.py's ledger tier) score the
-#: AnomalyScorer's flags against — the harness KNOWS who poisoned,
+#: This is what detection tests (``tests/test_ledger.py``,
+#: ``tests/test_quarantine.py``) score the AnomalyScorer's flags against — the harness KNOWS who poisoned,
 #: the ledger has to find them.
 _ADVERSARIES: dict[str, dict[str, str]] = {}
 
@@ -32,8 +32,8 @@ def adversary_map(exp_name: str) -> dict[str, str]:
 
 #: Final-model digests per experiment: ``exp_name -> {addr: sha256}``
 #: of every node's parameter leaves at finish — the byte-determinism
-#: receipt the async bench tier compares across same-seed runs (and
-#: across nodes within one serialized run).
+#: receipt ``tests/test_async_control.py`` compares across same-seed
+#: runs (and across nodes within one serialized run).
 _FINAL_DIGESTS: dict[str, dict[str, str]] = {}
 
 
@@ -170,7 +170,7 @@ def run_seeded_experiment(
         wait_convergence(nodes, n - 1, only_direct=False, wait=30)
         exp_name = nodes[0].set_start_learning(rounds=rounds, epochs=epochs)
         if adversaries or plan_truth:
-            # Ground truth for detection benchmarks: who actually
+            # Ground truth for detection tests: who actually
             # poisons this experiment, by node address — derived from
             # the plan when one is given.
             truth = dict(plan_truth)

@@ -14,10 +14,10 @@ guarantee that when an instance was shared).
 Composition: :func:`apply_chaos` installs an attack plan AND a fault
 plan on one federation in one call — malicious peers coexist with
 drops, crashes and partitions in a single chaos spec, the way
-pfl-research treats adversarial simulation as a benchmarked tier and
+pfl-research treats adversarial simulation as a first-class tier and
 BlazeFL demands the run stay deterministic. The plan is also the
 **ground truth**: :meth:`AttackPlan.adversary_map` is what detection /
-quarantine benchmarks score against (the plan KNOWS who poisons; the
+quarantine tests score against (the plan KNOWS who poisons; the
 defense has to find them).
 
 Schema (:meth:`AttackPlan.from_dict`)::
@@ -361,8 +361,8 @@ class SlowLearner(AdversarialLearner):
     then sleeps the :class:`tpfl.communication.faults.TrainerSpeedPlan`
     delay for this address — the fitted PARAMETERS are bit-identical
     to the undelayed learner's (the sleep follows the compute), only
-    the federation-visible finish time skews. This is how the bench's
-    async tier builds its 10x-skewed fleet reproducibly."""
+    the federation-visible finish time skews. This is how the async
+    tests build a skewed fleet reproducibly."""
 
     def __init__(self, inner: Any, delay: float) -> None:
         super().__init__(inner, attack=lambda p: p)

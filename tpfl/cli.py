@@ -64,8 +64,7 @@ def help_experiment(name: str) -> None:
     metavar="DIR",
     default=None,
     help="wrap the run in a jax.profiler trace written to DIR "
-    "(bench.py's opt-in, promoted to any experiment; view with "
-    "TensorBoard/xprof)",
+    "(view with TensorBoard/xprof)",
 )
 @click.argument("args", nargs=-1, type=click.UNPROCESSED)
 def run_experiment(

@@ -374,7 +374,7 @@ class ThreadedCommunicationProtocol(CommunicationProtocol):
 
     def _dispatch_send(self, nei: str, conn: Any, msg: Message) -> None:
         """One transport attempt, routed through the fault injector when
-        one is attached (chaos tests/bench; None in production)."""
+        one is attached (chaos tests; None in production)."""
         fi = self._fault_injector
         if fi is None:
             self._transport_send(nei, conn, msg)

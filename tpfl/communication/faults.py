@@ -12,7 +12,8 @@ stream** seeded from ``(seed, src, dst)``. Two runs with the same
 ``(seed, plan)`` therefore make identical per-link fault decisions
 regardless of cross-link thread interleaving, and the injector's
 counters (delivered / dropped / corrupted / blocked per link) come out
-identical — the property the bench chaos tier asserts.
+identical
+(``tests/test_communication.py::test_fault_injector_is_deterministic``).
 
 Injection points (wired in ``base.py``):
 
@@ -25,7 +26,7 @@ Injection points (wired in ``base.py``):
 - inbound: a crashed node's ``handle_message`` drops everything
   (:meth:`FaultInjector.is_down`).
 
-The injector is test/bench machinery: a production node simply never
+The injector is test machinery: a production node simply never
 attaches one (``protocol._fault_injector is None`` — zero overhead on
 the send path beyond the None check).
 """
@@ -334,8 +335,8 @@ class FaultInjector:
 class TrainerSpeedPlan:
     """Declarative seeded trainer-speed skew: ``addr -> fit delay``
     (seconds slept around every local fit — the chaos knob that makes
-    heterogeneous fleets reproducible). The bench's async tier builds
-    its 10x-skewed federation from one of these, and the SAME plan
+    heterogeneous fleets reproducible). The async tests build their
+    skewed federations from one of these, and the SAME plan
     seeds the :class:`AsyncSchedule` that serializes async arrival
     order — so the determinism discipline and the chaos it tames come
     from a single spec. Pure data: the learner wrapping lives in
@@ -391,8 +392,9 @@ class AsyncSchedule:
     contributions by ``(virtual time, seeded trainer rank)``. An
     aggregator holding out-of-order arrivals in a reorder buffer and
     folding strictly in this order folds an identical sequence at
-    every node and in every same-seed run — the property the bench's
-    async byte-determinism boolean asserts. Because the periods mirror
+    every node and in every same-seed run
+    (``tests/test_async_control.py::test_controller_serialized_same_seed_identical_trajectories``).
+    Because the periods mirror
     the real (injected) trainer speeds, actual arrival order tracks
     schedule order and the reorder buffer almost never waits.
 

@@ -27,9 +27,9 @@ threads involved, which is why every thread in tpfl carries a real
 Tracing is OFF by default: ``make_lock`` reads the setting at LOCK
 CREATION time (node construction), so enabling it for a test means
 setting ``Settings.LOCK_TRACING = True`` before building nodes. The
-overhead is one thread-local list append per acquire (<10% round
-throughput in bench.py's analysis tier), cheap enough for every chaos
-run but not free enough for the 1000-node profiles.
+overhead is one thread-local list append per acquire, cheap enough
+for every chaos run but not free enough for the 1000-node profiles
+(not measured on the chip).
 
 This module also hosts the TRACE-CONTRACT machinery
 (:func:`stamp_contract` / :func:`check_contract`,

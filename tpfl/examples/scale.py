@@ -19,8 +19,8 @@ to all N-1 peers (O(N^2) handler work at one node) and saturates around
 ~200 nodes; the default TREE topology (star-of-stars, ~sqrt(N) fully
 meshed hubs — tpfl.utils.topologies) splits the relay load across hubs
 and sustains 500+ protocol nodes (measured: see README). Beyond that,
-use the vmapped path directly (bench.py's config-4 tier:
-``VmapFederation`` with a participation mask — the whole round is one
+use the vmapped path directly
+(``VmapFederation`` with a participation mask — the whole round is one
 XLA program and the protocol overhead disappears) or the hierarchical
 ``FederationLearner`` tier.
 """

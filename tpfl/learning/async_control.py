@@ -5,7 +5,7 @@ rounds but left its two knobs STATIC: ``ASYNC_BUFFER_K`` and
 ``ASYNC_ROUND_DEADLINE`` are set once per profile, while the quantity
 they should track — how fast contributions actually arrive, and how
 stale they are when they do — drifts with fleet size, trainer skew and
-load. A K sized for a 10-node bench fleet is a barrier over the fast
+load. A K sized for a 10-node fleet is a barrier over the fast
 set of a 1000-node one; a deadline sized for quiet CPU rounds
 deadline-closes every round of a loaded host. This module closes the
 loop the ROADMAP names: a per-node :class:`AsyncController` that
@@ -21,7 +21,7 @@ Observation sources (the determinism discipline):
   ordinals when none is — never from the wall clock. Two same-seed
   runs therefore feed the controller identical observation multisets
   and its K/deadline trajectories are byte-identical at every node
-  (the bench async tier's receipt extends over the controller).
+  (``tests/test_async_control.py::test_controller_serialized_same_seed_identical_trajectories``).
 - **free-running mode**: stamps are ``time.monotonic()`` at intake —
   real cadence, no reproducibility claim (the PR-10 contract
   unchanged).
@@ -129,7 +129,7 @@ class AsyncController:
         # guarded-by: _lock
         self._deadline: "float | None" = None
         # Bounded per-round decision log — the deterministic trajectory
-        # receipt tests/bench compare across same-seed runs.
+        # receipt tests compare across same-seed runs.
         # guarded-by: _lock
         self._trajectory: "list[dict]" = []
         # The previous experiment's trajectory, archived by reset():

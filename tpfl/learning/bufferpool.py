@@ -145,7 +145,7 @@ class BufferPool:
             ):
                 self._free.append(buf)
 
-    # --- introspection (tests, bench) ---
+    # --- introspection (tests) ---
 
     def pooled_bytes_locked(self) -> int:
         return sum(len(b) for b in self._free)

@@ -11,7 +11,7 @@ translation-variant strokes a linear model cannot trivially separate but
 a small CNN/MLP learns to >90%.
 
 The ``TpflDataset.from_huggingface`` path stays the real-MNIST entry
-point when egress exists; every hermetic test/bench uses these.
+point when egress exists; every hermetic test uses these.
 
 Fonts come from matplotlib's bundled DejaVu TTFs (always present, no
 system font dependency). Rendering is deterministic per seed.

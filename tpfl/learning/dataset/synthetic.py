@@ -4,7 +4,7 @@ The reference pulls MNIST from the HF hub (``p2pfl/MNIST``,
 examples/mnist.py:173) — unavailable in an egress-free environment, and a
 poor benchmark dependency anyway. These generators produce seeded,
 learnable classification data with the same shapes (28×28 "MNIST",
-32×32×3 "CIFAR"), so every e2e test and bench is hermetic.
+32×32×3 "CIFAR"), so every e2e test is hermetic.
 
 Learnability: each class has a fixed random prototype vector; samples are
 prototype + Gaussian noise. A linear model separates them quickly, which

@@ -4,8 +4,8 @@ telemetry, and live SLO watchdogs — the fourth observability plane.
 Every plane built so far (registry/tracing, profiling, ledger, engine
 telemetry) is strictly process-local: a multi-host run has N disjoint
 ``/metrics`` endpoints, the million-client population tier emits no
-client-level health at all, and the only regression gate runs offline
-in bench. This module is the fleet-level closure over all of them,
+client-level health at all, and nothing watches a running federation
+for a regression. This module is the fleet-level closure over all of them,
 three coordinated pieces:
 
 1. **Cross-host metric federation** — :func:`snapshot` folds a
@@ -41,8 +41,8 @@ three coordinated pieces:
    gauge(name) / ratio(a, b)`` vs a threshold) over the live registry,
    EWMA-smoothed (``Settings.SLO_EWMA``); ``SLO_BREACH_WINDOWS``
    consecutive violations emit a ``slo_breach`` flight event and bump
-   ``tpfl_slo_breach_total`` — bench's offline baseline gate brought
-   into running federations, and the verdict behind
+   ``tpfl_slo_breach_total`` — a regression gate inside running
+   federations, and the verdict behind
    ``MetricsHTTPServer``'s ``/healthz``.
 
 Live-view gauges: :func:`register_view` / :func:`register_population`
@@ -604,8 +604,8 @@ class SLOWatchdog:
     (``Settings.SLO_EWMA``), and count consecutive violations;
     ``Settings.SLO_BREACH_WINDOWS`` of them fire ONE ``slo_breach``
     flight event + ``tpfl_slo_breach_total{target=...}`` bump, then
-    re-arm when the target recovers. ``now`` is injectable so bench/
-    tests drive deterministic windows; live callers omit it
+    re-arm when the target recovers. ``now`` is injectable so tests
+    drive deterministic windows; live callers omit it
     (monotonic clock). :meth:`start` runs evaluations on a named
     daemon thread for long-running federations; ``/healthz`` reads
     :meth:`healthy` / :meth:`verdicts`.

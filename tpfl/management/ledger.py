@@ -44,8 +44,9 @@ params, round-start reference), both of which are seed-deterministic,
 so :meth:`ContributionLedger.detections` — which dedups
 single-contributor entries by (peer, round) and scores them against a
 deduped global baseline — produces byte-identical flags across
-same-seed runs regardless of gossip arrival order (the bench ``ledger``
-tier asserts this). The per-observer flags recorded live at intake use
+same-seed runs regardless of gossip arrival order
+(``tests/test_ledger.py::test_detections_dedup_across_observers``).
+The per-observer flags recorded live at intake use
 the observer's own running window and are near-identical in practice
 but not guaranteed byte-stable; the deterministic view is the verdict
 surface.
@@ -53,7 +54,8 @@ surface.
 Gating (the PR-6 discipline): every entry point checks
 ``Settings.LEDGER_ENABLED`` first — disabled, the ledger is one
 attribute read per call site and adds ZERO device dispatches
-(the bench ledger tier's off/on A/B is the receipt). jax is imported
+(``tests/test_ledger.py::test_disabled_ledger_adds_zero_dispatches``).
+jax is imported
 lazily so the management layer stays backend-free.
 
 Concurrency: ring/state sit under one ``make_lock`` leaf lock; the
@@ -324,11 +326,10 @@ class ContributionLedger:
         # (params, round-start reference), and in-process federations
         # share numerically identical references — so the fused
         # reduction runs ONCE per (peer, round) process-wide and every
-        # other observer reuses the scalars. This is what keeps the
-        # defended intake inside the shared 5% rounds/sec budget (the
-        # bench byzantine tier's A/B): without it, N co-located
-        # observers each paid a mid-round dispatch+sync per
-        # contribution. Bounded FIFO (_score_keys).
+        # other observer reuses the scalars
+        # (tests/test_quarantine.py::test_repush_scores_once): without
+        # it, N co-located observers each paid a mid-round
+        # dispatch+sync per contribution. Bounded FIFO (_score_keys).
         # guarded-by: _lock
         self._score_cache: dict[tuple, dict] = {}
         # guarded-by: _lock
@@ -457,8 +458,7 @@ class ContributionLedger:
         needs the verdict BEFORE the aggregator folds, so the parked
         flush-at-close discipline of :meth:`record` does not apply
         here; the dispatch+sync tax mid-round is the defense's price,
-        measured inside the shared 5% budget by the bench byzantine
-        tier).
+        not measured on the chip).
 
         Deduped by (peer, round) per observer: gossip re-pushes of the
         same contribution return the already-scored entry without
@@ -900,8 +900,8 @@ class ContributionLedger:
              "flagged": {peer: {"rounds": [...], "reasons": [...]}},
              "peers": [every peer seen]}
 
-        Byte-identical across same-seed runs (bench ledger tier's
-        acceptance check).
+        Byte-identical for identical inputs
+        (``tests/test_ledger.py::test_detections_dedup_across_observers``).
         """
         self.flush()
         with self._lock:

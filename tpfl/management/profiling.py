@@ -1,14 +1,11 @@
 """Device-plane performance observatory.
 
-PR-5's flight recorder stitched together the HOST and NETWORK plane; the
-remaining perf questions (ROADMAP: MFU vs the shared-weight floor,
-dispatch RTT, pod-scale rounds) are DEVICE-plane questions, and until
-now the machinery to answer them lived as ad-hoc code inside
-``bench.py`` (``_flops_of``, empty-call RTT subtraction, device-side
-``fori_loop`` timing) and ``parallel/scaling.py``. This module makes
-that machinery a first-class, always-available subsystem feeding the
-PR-5 :class:`~tpfl.management.telemetry.MetricsRegistry` /
-:class:`~tpfl.management.telemetry.FlightRecorder`:
+PR-5's flight recorder stitched together the HOST and NETWORK plane; this
+module is the DEVICE-plane counterpart, feeding the PR-5
+:class:`~tpfl.management.telemetry.MetricsRegistry` /
+:class:`~tpfl.management.telemetry.FlightRecorder`. Rates, MFU and idle
+shares of a timed run come from the chip benchmark (``BENCHMARK.json``,
+``benchmark/``), not from here:
 
 - :class:`CompileObservatory` — wraps the jit/lower/compile seams
   (``jax_learner._shared_program``, ``VmapFederation._build_round*``,
@@ -23,33 +20,26 @@ PR-5 :class:`~tpfl.management.telemetry.MetricsRegistry` /
   wall-clock into ``train`` / ``dispatch`` / ``fold`` / ``gossip`` /
   ``host_other`` components (the instrumented sites live in the
   learner, the batched-fit chunk, the aggregator, and the round
-  stages), plus the REUSABLE device-side timing API generalized out of
-  bench.py: :func:`measure_dispatch_rtt` and :func:`timed_loop` — K
-  iterations inside ONE jitted ``fori_loop`` dispatch, scalar-reduced
-  sync, empty-call RTT subtracted (docs/perf_cnn.md is the methodology
-  anchor; proper ``block_until_ready`` discipline throughout).
-- :class:`CostModel` — ONE FLOPs-accounting path shared by bench.py
-  and ``parallel/scaling.py``: XLA ``cost_analysis`` flops (with the
+  stages). Beside it sit the two wall timers ``chip_smoke.py``'s
+  ``sync`` phase reads (:func:`measure_dispatch_rtt`, a scalar-synced
+  empty call) and :func:`best_of_wall_donated`.
+- :class:`CostModel` — ONE FLOPs-accounting path shared with
+  ``parallel/scaling.py``: XLA ``cost_analysis`` flops (with the
   scan-counted-once caveat in exactly one place), analytic model flops
   for the zoo architectures (2·M·K·N per layer, x3 fwd+bwd), peak
   FLOP/s lookup per device kind, and live per-round MFU gauges.
 - :class:`HbmTracker` — per-device HBM high-water-mark gauges lifted
   from ``node_monitor``'s ``memory_stats`` read into a peak-tracking
   registry collector.
-- a **perf regression gate** (:func:`compare_to_baseline`) — compares
-  a bench run's parsed metrics against a committed baseline with
-  per-metric tolerance thresholds and a machine-readable pass/fail
-  verdict; ``bench.py --check`` and the CI perf-smoke job are thin
-  shells over it.
-
 Gating: the metrics REGISTRY side (cache hit/miss counters, cache-size
 gauges, HBM gauges) always records — cheap dict updates, PR-5's rule.
 Everything that costs per-call work on a hot path (abstract-signature
 extraction in :meth:`CompileObservatory.wrap`, round spans, the
 ``block_until_ready`` splits in the learner) is gated by
 ``Settings.PROFILING_ENABLED`` and collapses to one attribute read
-when off — disabled profiling adds ZERO device dispatches and no
-measurable rounds/sec (bench.py's profiling tier A/B is the receipt).
+when off — disabled profiling adds ZERO device dispatches
+(``tests/test_profiling.py``: the disabled observatory and profiler
+record nothing).
 
 Concurrency: each tracker's shared state sits under its own
 ``make_lock`` leaf lock, never held while calling out of this module
@@ -71,7 +61,7 @@ from tpfl.management.telemetry import flight, metrics
 from tpfl.settings import Settings
 
 #: Peak dense bf16 FLOP/s per chip, keyed by the exact ``device_kind``
-#: JAX reports — the single copy (bench.py's ``_peak_flops`` reads it).
+#: JAX reports — the library's single copy (:func:`peak_flops` reads it).
 #: V5E MEASURED PATH ONLY: a row exists for a chip only once this repo
 #: has run on it (``chip_smoke.py``). Source: Google Cloud TPU v5e
 #: documentation, 197 TFLOP/s bf16. A kind missing here is ``None`` for
@@ -99,8 +89,7 @@ ROUND_BUCKETS: tuple[float, ...] = (
 #: node).
 PROFILING_RING = "_profiling"
 
-#: Round attribution component names (the five buckets the ISSUE and
-#: bench.py's profiling tier report). ``host_other`` is the residual:
+#: Round attribution component names. ``host_other`` is the residual:
 #: wall minus everything measured — attribution that cannot silently
 #: drop time.
 COMPONENTS = ("train", "dispatch", "fold", "gossip", "host_other")
@@ -225,8 +214,9 @@ class CompileObservatory:
             self._maybe_warn_storm(name, n_sigs)
             return out
 
-        # Keep the lowering escape hatch callers like bench's flops
-        # estimator use on raw jitted fns.
+        # Keep the lowering escape hatch static analysis uses on raw
+        # jitted fns (tests/test_profiling.py::
+        # test_observatory_wrap_preserves_lowering_handle).
         lower = getattr(fn, "lower", None)
         if lower is not None:
             observed.lower = lower  # type: ignore[attr-defined]
@@ -275,7 +265,7 @@ class CompileObservatory:
     def compile_span(self, name: str) -> Iterator[None]:
         """Time an explicit lower/compile block into the compile
         histogram (for callers that hold the seam open themselves,
-        e.g. ``.lower(...).compile()`` in scaling analysis/bench)."""
+        e.g. ``.lower(...).compile()`` in scaling analysis)."""
         t0 = time.perf_counter()
         try:
             yield
@@ -286,7 +276,8 @@ class CompileObservatory:
             )
 
     def signature_counts(self) -> dict[str, int]:
-        """fn name -> distinct abstract signatures seen (tests/bench)."""
+        """fn name -> distinct abstract signatures seen (the recompile
+        receipt of ``tests/test_elastic.py`` and ``chip_smoke.py``)."""
         with self._lock:
             return {k: len(v) for k, v in self._signatures.items()}
 
@@ -387,7 +378,7 @@ class RoundProfiler:
     attribution can never silently drop time), per-component seconds
     land in ``tpfl_round_attr_seconds{node,component}`` histograms and
     a ``round`` span in the node's flight ring, and the completed
-    record is retained for :meth:`attribution` (bench/tests).
+    record is retained for :meth:`attribution` (tests).
 
     Components may OVERLAP in wall time (an eager fold on a gRPC
     handler thread runs while the learning thread sits in the gossip
@@ -570,7 +561,7 @@ class RoundProfiler:
 
     def attribution(self, node: "str | None" = None) -> list[dict]:
         """Completed round records (optionally one node's), oldest
-        first — the bench profiling tier / test surface."""
+        first — the test surface."""
         with self._lock:
             records = list(self._done)
         if node is not None:
@@ -589,16 +580,15 @@ def round_(v: float, nd: int = 6) -> float:
     return round(v, nd)
 
 
-# --- device-side timing (the bench methodology, as an API) ---------------
+# --- wall timers ----------------------------------------------------------
 
 
 def measure_dispatch_rtt(best_of: int = 3) -> float:
     """Seconds for one dispatch+sync round trip of a trivially small
-    jitted program — the empty-call baseline :func:`timed_loop`
-    subtracts. When it is the same order as a federated round,
-    host-loop timing misattributes it to the device; what it is on the
-    current chip host is printed by ``chip_smoke.py``'s ``sync``
-    phase."""
+    jitted program (best of ``best_of`` after a discarded compile run).
+    When it is the same order as a federated round, host-loop timing
+    misattributes it to the device; what it is on the current chip host
+    is printed by ``chip_smoke.py``'s ``sync`` phase."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -607,12 +597,15 @@ def measure_dispatch_rtt(best_of: int = 3) -> float:
     def empty_call(x):
         return lax.fori_loop(0, 100, lambda i, a: a + x * (1 + i), jnp.float32(0))
 
-    rtt, _ = best_of_wall(empty_call, (jnp.float32(1),), best_of)
+    # Nothing is donated, so the "rebind" hands the same scalar back.
+    rtt, _ = best_of_wall_donated(
+        empty_call, (jnp.float32(1),), lambda out, args: args, best_of
+    )
     return rtt
 
 
 def _sync_scalar(out: Any) -> None:
-    """The one host sync both wall timers share: copy 4 bytes of the
+    """The one host sync the wall timers share: copy 4 bytes of the
     LAST output leaf (perf_cnn.md round-5 trap #1 — syncing by copying
     an array carry measures the device-to-host transfer, not the
     device)."""
@@ -622,40 +615,24 @@ def _sync_scalar(out: Any) -> None:
     float(np.asarray(jax.tree_util.tree_leaves(out)[-1]).ravel()[0])
 
 
-def best_of_wall(fn: Callable, args: tuple, n: int = 3) -> tuple[float, Any]:
-    """Best-of-n wall time of ``fn(*args)`` with a SCALAR host sync on
-    the last output leaf. Returns ``(best_seconds, last_outputs)``.
-    The first call is a discarded compile/warm run. ``fn`` must NOT
-    donate its inputs — every iteration re-feeds the same buffers; for
-    a donating program use :func:`best_of_wall_donated`."""
-    out = fn(*args)  # compile + warm
-    _sync_scalar(out)
-    best = float("inf")
-    for _ in range(max(1, n)):
-        t0 = time.perf_counter()
-        out = fn(*args)
-        _sync_scalar(out)
-        best = min(best, time.perf_counter() - t0)
-    return best, out
-
-
 def best_of_wall_donated(
     fn: Callable,
     args: tuple,
     rebind: Callable[[Any, tuple], tuple],
     n: int = 3,
 ) -> tuple[float, Any]:
-    """:func:`best_of_wall` for a program that DONATES input buffers:
-    each call consumes (part of) its arguments, so iterations cannot
-    re-feed ``args`` verbatim — ``rebind(last_outputs, prev_args) ->
-    args`` re-materializes the consumed inputs for the next iteration,
-    typically by threading the program's own outputs back in (the
-    production shape: window N+1 trains from window N's fold, e.g.
-    ``lambda out, a: (out[0], *a[1:])``). Rebinding and buffer
-    materialization happen OUTSIDE the timed region
-    (``block_until_ready`` before the clock starts), so the measured
-    wall is the donating program itself — the real engine path, not a
-    ``donate=False`` stand-in built just to be timeable."""
+    """Best-of-n wall time of ``fn(*args)`` (scalar host sync on the
+    last output leaf, first call a discarded compile/warm run) for a
+    program that DONATES input buffers: each call consumes (part of)
+    its arguments, so iterations cannot re-feed ``args`` verbatim —
+    ``rebind(last_outputs, prev_args) -> args`` re-materializes the
+    consumed inputs for the next iteration, typically by threading the
+    program's own outputs back in (the production shape: window N+1
+    trains from window N's fold, e.g. ``lambda out, a: (out[0],
+    *a[1:])``). Rebinding and buffer materialization happen OUTSIDE the
+    timed region (``block_until_ready`` before the clock starts), so
+    the measured wall is the donating program itself. Returns
+    ``(best_seconds, last_outputs)``."""
     import jax
 
     out = fn(*args)  # compile + warm (consumes the caller's buffers)
@@ -671,58 +648,13 @@ def best_of_wall_donated(
     return best, out
 
 
-def timed_loop(
-    step: Callable,
-    carry: Any,
-    data: tuple,
-    n_iters: int,
-    rtt: "float | None" = None,
-    best_of: int = 3,
-) -> tuple[float, Any]:
-    """Seconds per iteration of ``step(carry, *data) -> carry`` — the
-    canonical device-side methodology every bench tier shares, now a
-    reusable API (generalized out of ``bench.py``):
-
-    - ``n_iters`` iterations run inside ONE jitted ``fori_loop``
-      dispatch (host-loop timing misattributes the dispatch RTT to
-      the device);
-    - the program returns ONE f32 scalar reduced from every carry leaf
-      (observes all outputs — no dead-code elimination — while the
-      host sync copies 4 bytes, not an array carry);
-    - a measured empty-call RTT is subtracted (pass ``rtt`` to share
-      one measurement across tiers; None measures it here);
-    - best of ``best_of`` runs.
-
-    ``data`` rides as ARGUMENTS, not closure constants — closures embed
-    the arrays into the program as constants, bloating what is
-    compiled and cached. Size ``n_iters`` so the device work dwarfs the
-    RTT's run-to-run drift (perf_cnn.md round-5 trap #2). Returns
-    ``(seconds_per_iter, final_outputs)``."""
-    import jax
-    import jax.numpy as jnp
-    from jax import lax
-
-    if rtt is None:
-        rtt = measure_dispatch_rtt(best_of)
-
-    @jax.jit
-    def run(c, *d):
-        out = lax.fori_loop(0, n_iters, lambda i, cc: step(cc, *d), c)
-        leaves = jax.tree_util.tree_leaves(out)
-        return sum(x.ravel()[0].astype(jnp.float32) for x in leaves)
-
-    total, out = best_of_wall(run, (carry, *data), best_of)
-    return max(total - rtt, 1e-9) / n_iters, out
-
-
 # --- cost model -----------------------------------------------------------
 
 
 class CostModel:
     """Unified FLOPs / MFU accounting — the ONE ``cost_analysis()``
-    call path shared by ``bench.py`` and
-    ``parallel/scaling.py:analyze_compiled``, so static scaling
-    analysis and live MFU can never disagree."""
+    call path, shared with ``parallel/scaling.py:analyze_compiled``,
+    so static scaling analysis and live MFU can never disagree."""
 
     @staticmethod
     def cost_analysis(compiled: Any) -> dict:
@@ -839,9 +771,9 @@ class CostModel:
     ) -> "float | None":
         """Publish one round's live MFU: ``tpfl_mfu{program}`` /
         ``tpfl_round_flops{program}`` gauges plus the per-round seconds
-        histogram. Returns the MFU (None off-TPU). This is the gauge
-        bench.py's profiling tier cross-checks against the analytic
-        MFU column."""
+        histogram. Returns the MFU (None off-TPU). ``seconds`` is HOST
+        wall time (ROADMAP D7): the benchmark's ``mfu_device_pct``
+        divides by device time instead."""
         seconds = max(seconds, 1e-12)
         value = cls.mfu(flops / seconds, device=device, n_chips=n_chips)
         metrics.gauge(
@@ -975,7 +907,7 @@ def _hbm_collector(registry: Any) -> None:
     hbm.sample()
 
 
-# --- jax.profiler trace wrap (any run, not just bench) -------------------
+# --- jax.profiler trace wrap (any run) -----------------------------------
 
 _trace_lock = make_lock("profiling._trace_lock")
 _trace_dir: "list[str]" = []  # 0- or 1-element; guarded by _trace_lock
@@ -1031,100 +963,14 @@ def stop_trace() -> bool:
 @contextlib.contextmanager
 def maybe_trace(directory: "str | None") -> Iterator[None]:
     """Wrap a block in a jax profiler trace when ``directory`` is
-    set; a shared no-op otherwise (bench's ``--profile`` and the CLI's
-    ``experiment run --profile`` both ride this)."""
+    set; a shared no-op otherwise (the CLI's ``experiment run
+    --profile`` rides this)."""
     started = start_trace(directory) if directory else False
     try:
         yield
     finally:
         if started:
             stop_trace()
-
-
-# --- perf regression gate -------------------------------------------------
-
-#: Default per-metric relative tolerance for the regression gate.
-DEFAULT_TOLERANCE = 0.2
-
-
-def resolve_path(doc: Any, path: str) -> Any:
-    """Dotted-path lookup into a bench result document
-    (``"extra.mfu"`` → ``doc["extra"]["mfu"]``); None when missing."""
-    cur = doc
-    for part in path.split("."):
-        if not isinstance(cur, dict) or part not in cur:
-            return None
-        cur = cur[part]
-    return cur
-
-
-def compare_to_baseline(results: dict, baseline: dict) -> dict:
-    """The perf regression gate: compare a bench run's parsed metrics
-    against a committed baseline document.
-
-    Baseline schema (``BENCH_BASELINE*.json``)::
-
-        {"metrics": {
-            "<name>": {"path": "extra.mfu", "baseline": 0.105,
-                        "direction": "higher",     # or "lower"
-                        "tolerance": 0.2,          # relative, optional
-                        "required": false},        # missing => fail?
-         ...}}
-
-    A ``higher``-direction metric regresses when
-    ``value < baseline * (1 - tolerance)``; ``lower`` (bytes, seconds)
-    when ``value > baseline * (1 + tolerance)``. Booleans coerce to
-    1.0/0.0 so acceptance flags gate too. Metrics absent from the run
-    are SKIPPED unless ``required`` (CPU smoke runs don't produce the
-    TPU tiers). Returns the machine-readable verdict
-    ``{"pass": bool, "checked": [...], "skipped": [...]}`` that
-    ``bench.py --check`` prints and exits on."""
-    checked: list[dict] = []
-    skipped: list[dict] = []
-    ok_all = True
-    for name, spec in sorted(baseline.get("metrics", {}).items()):
-        path = spec.get("path", name)
-        base = spec.get("baseline")
-        value = resolve_path(results, path)
-        if isinstance(value, bool):
-            value = 1.0 if value else 0.0
-        if isinstance(base, bool):
-            base = 1.0 if base else 0.0
-        if value is None or not isinstance(value, (int, float)):
-            entry = {"metric": name, "path": path, "status": "missing"}
-            if spec.get("required", False):
-                entry["ok"] = False
-                checked.append(entry)
-                ok_all = False
-            else:
-                skipped.append(entry)
-            continue
-        if not isinstance(base, (int, float)) or base == 0:
-            skipped.append(
-                {"metric": name, "path": path, "status": "bad_baseline"}
-            )
-            continue
-        tolerance = float(spec.get("tolerance", DEFAULT_TOLERANCE))
-        direction = spec.get("direction", "higher")
-        ratio = float(value) / float(base)
-        if direction == "lower":
-            ok = ratio <= 1.0 + tolerance
-        else:
-            ok = ratio >= 1.0 - tolerance
-        checked.append(
-            {
-                "metric": name,
-                "path": path,
-                "value": value,
-                "baseline": base,
-                "ratio": round(ratio, 4),
-                "direction": direction,
-                "tolerance": tolerance,
-                "ok": ok,
-            }
-        )
-        ok_all = ok_all and ok
-    return {"pass": bool(ok_all), "checked": checked, "skipped": skipped}
 
 
 # The directory the persistent compilation cache is armed at (None until
@@ -1166,7 +1012,7 @@ def compile_cache_dir(directory: "str | None" = None) -> str:
 def ensure_compile_cache(directory: "str | None" = None) -> str:
     """Arm JAX's persistent compilation cache at
     :func:`compile_cache_dir` and return that directory. Called by
-    every entry point (``chip_smoke.py``, ``bench.py``, the examples)
+    every entry point (``chip_smoke.py``, the examples)
     and by the engine constructor when ``Settings.COMPILE_CACHE_DIR``
     is set. Idempotent per directory. A warm process then replays
     lowered programs from disk instead of recompiling — the

@@ -39,8 +39,9 @@ Determinism: the intake verdict is a pure function of (contribution
 params, round-start reference, prior rounds' clean norm window) — all
 seed-deterministic — so every observer that scores a given
 (peer, round) contribution reaches the same verdict, and honest
-senders' exclusion sets agree. The byte-stable *verdict surface* the
-bench ``byzantine`` tier gates is :func:`replay_decisions` over the
+senders' exclusion sets agree. The byte-stable *verdict surface*
+(``tests/test_quarantine.py::test_replay_decisions_matches_live_and_is_stable``)
+is :func:`replay_decisions` over the
 ledger's deduped :meth:`detections` view (the PR-7 discipline: live
 per-observer state is the enforcement, the deduped replay is the
 receipt).
@@ -366,7 +367,7 @@ def replay_decisions(
     arrival order or which observers happened to score which
     contribution (every contribution is scored at least at its own
     trainer's intake). Live engines enforce; this view is the receipt
-    the bench byzantine tier gates. Returns the ordered action list
+    tests compare across runs. Returns the ordered action list
     ``[{"peer", "round", "action", "reasons"}, ...]``.
     """
     if detections is None:

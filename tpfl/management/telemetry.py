@@ -409,7 +409,7 @@ class MetricsRegistry:
         return merged
 
     def reset(self) -> None:
-        """Drop all recorded series (tests / bench A-B runs). Shards
+        """Drop all recorded series (tests, A/B runs). Shards
         registered by live threads are emptied, not discarded — the
         thread-local pointers stay valid."""
         with self._meta_lock:

@@ -25,8 +25,9 @@ the per-node :class:`~tpfl.management.telemetry.FlightRecorder` ring.
 
 Trace ids are DETERMINISTIC for a fixed seed: id ``n`` minted by node
 ``a`` is ``sha256(SEED | a | n)[:16]`` — two runs of the same seeded
-federation mint the same id sequence per node (asserted by the
-bench.py telemetry tier), so timelines from repeated runs line up.
+federation mint the same id sequence per node
+(``tests/test_telemetry.py::test_trace_id_mint_deterministic_for_fixed_seed``),
+so timelines from repeated runs line up.
 """
 
 from __future__ import annotations
@@ -79,7 +80,7 @@ def mint(node: str) -> str:
 
 
 def reset() -> None:
-    """Restart the deterministic id sequences (tests / bench A-B)."""
+    """Restart the deterministic id sequences (tests, A/B runs)."""
     _minter.reset()
     _span_seq.reset()
 

@@ -1,8 +1,7 @@
 """Flax modules: MLP, CNN, ResNet-18, TransformerLM.
 
-TPU notes: every module takes ``compute_dtype`` (default bfloat16 on TPU
-via Settings.DEFAULT_DTYPE staying float32 for params) so the MXU sees
-bf16 matmuls/convs; logits are always returned float32 for a stable
+TPU notes: every module takes ``compute_dtype`` (default bfloat16; params
+stay float32) so the MXU sees bf16 matmuls/convs; logits are always returned float32 for a stable
 softmax. Shapes are static; no python control flow depends on data.
 """
 
@@ -14,6 +13,7 @@ from typing import Any, Callable, Optional, Sequence
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from tpfl.learning.model import TpflModel
 from tpfl.models.head_loss import head_cross_entropy
@@ -38,27 +38,72 @@ class MLP(nn.Module):
         return x.astype(jnp.float32)
 
 
+_CONV_DN = ("NHWC", "HWIO", "NHWC")
+
+
+def _conv_same(x, w):
+    return lax.conv_general_dilated(
+        x, w, (1, 1), "SAME", dimension_numbers=_CONV_DN
+    )
+
+
+@jax.custom_vjp
+def conv_fwd_style(x: jnp.ndarray, w: jnp.ndarray):
+    """Stride-1 SAME convolution (NHWC x HWIO) with BOTH backward
+    passes expressed as ordinary FORWARD convolutions at the XLA level:
+
+    - ``dx = conv_SAME(dout, rot180(w) io-swapped)`` — the standard
+      transposed-conv identity for stride 1 / SAME / odd kernels;
+    - ``dW = conv(x, dout)`` with dimension numbers ``CHWN/IHWO/HWNC``
+      (Cin as the conv batch, the real batch as the contraction
+      feature, dout as a big-window kernel).
+
+    Why: JAX's built-in conv transpose rules emit
+    ``batch_group_count``/grouped-transpose convolutions that, once
+    vmapped over a nodes axis, lower slower than forward-style grouped
+    convs on TPU (``docs/perf_cnn.md``: pre-PR-1 figures, not
+    re-measured). Gradients are numerically IDENTICAL to the autodiff
+    path (``tests/test_parallel.py::test_conv_fwd_style_grads_match_autodiff``).
+
+    Restrictions: stride 1, SAME padding, odd square kernel."""
+    return _conv_same(x, w)
+
+
+def _fs_fwd(x, w):
+    return _conv_same(x, w), (x, w)
+
+
+def _fs_bwd(res, g):
+    x, w = res
+    g = g.astype(x.dtype)
+    k = w.shape[0]
+    assert k == w.shape[1] and k % 2 == 1, "conv_fwd_style: odd square only"
+    r = k // 2
+    w_flip = jnp.flip(w, (0, 1)).swapaxes(2, 3)  # [k, k, Cout, Cin]
+    dx = lax.conv_general_dilated(
+        g, w_flip, (1, 1), "SAME", dimension_numbers=_CONV_DN
+    )
+    dw = lax.conv_general_dilated(
+        x, g, (1, 1), [(r, r), (r, r)],
+        dimension_numbers=("CHWN", "IHWO", "HWNC"),
+    ).astype(w.dtype)
+    return dx, dw
+
+
+conv_fwd_style.defvjp(_fs_fwd, _fs_bwd)
+
+
 class TpflConv(nn.Conv):
-    """``nn.Conv`` with a selectable gradient lowering — same forward
-    op, same param layout/init (pass ``name="Conv_i"`` for tree/RNG
-    parity with a plain ``nn.Conv`` stack).
-
-    ``impl="fwd_bwd"``: gradients via
-    :func:`tpfl.parallel.conv_kernel.conv_fwd_style` — both backward
-    convs expressed as forward-style convolutions, which vmap into
-    XLA's fast grouped lowering (the per-node federation path);
-    numerically identical to autodiff. ``impl="pallas"``: backward via
-    the Pallas im2col kernels (kept as the seam for future Mosaic
-    tuning; measured SLOWER than XLA's grouped path on v5e today).
-    Only the zoo-CNN case is supported: stride 1, SAME padding, odd
-    square kernel, no grouping."""
-
-    impl: str = "fwd_bwd"
+    """``nn.Conv`` whose gradients go through :func:`conv_fwd_style` —
+    same forward op, same param layout/init (pass ``name="Conv_i"`` for
+    tree/RNG parity with a plain ``nn.Conv`` stack): both backward
+    convs are expressed as forward-style convolutions, which vmap into
+    XLA's grouped lowering (the per-node federation path); numerically
+    identical to autodiff. Only the zoo-CNN case is supported: stride
+    1, SAME padding, odd square kernel, no grouping."""
 
     @nn.compact
     def __call__(self, inputs):
-        from tpfl.parallel.conv_kernel import conv_fwd_style, node_conv
-
         kh, kw = self.kernel_size
         if (
             (self.strides not in (1, (1, 1), None))
@@ -95,13 +140,13 @@ class TpflConv(nn.Conv):
         inputs, kernel, bias = _dtypes.promote_dtype(
             inputs, kernel, bias, dtype=self.dtype
         )
-        if self.impl == "pallas":
-            y = node_conv(inputs, kernel)
-        else:
-            y = conv_fwd_style(inputs, kernel)
+        y = conv_fwd_style(inputs, kernel)
         if bias is not None:
             y = y + bias
         return y
+
+
+_CONV_IMPLS = {"fwd_bwd": TpflConv, "xla": nn.Conv}
 
 
 class CNN(nn.Module):
@@ -109,13 +154,11 @@ class CNN(nn.Module):
 
     ``conv_impl``: "fwd_bwd" (default) uses :class:`TpflConv` —
     identical forward and params to ``nn.Conv``, with the backward
-    convs reformulated as forward-style convs (measured ~4% faster
-    100-node federated rounds on v5e, exact grads); "xla" uses plain
-    ``nn.Conv``; "pallas" routes the backward through the Pallas
-    im2col kernels (tested-correct, currently slower — see
-    tpfl.parallel.conv_kernel). The param tree is identical across
-    impls (explicit Conv_i names), so checkpoints and federations mix
-    freely."""
+    convs reformulated as forward-style convs (exact grads;
+    ``docs/perf_cnn.md`` has the pre-PR-1 figures, not re-measured);
+    "xla" uses plain ``nn.Conv``. Any other value raises ``ValueError``.
+    The param tree is identical across the two (explicit Conv_i names),
+    so checkpoints and federations mix freely."""
 
     channels: Sequence[int] = (32, 64)
     dense: int = 128
@@ -125,12 +168,12 @@ class CNN(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool = False):
-        impl = self.conv_impl
-        conv_cls = (
-            nn.Conv
-            if impl == "xla"
-            else partial(TpflConv, impl=impl)
-        )
+        if self.conv_impl not in _CONV_IMPLS:
+            raise ValueError(
+                f"CNN.conv_impl must be one of {sorted(_CONV_IMPLS)}, "
+                f"got {self.conv_impl!r}"
+            )
+        conv_cls = _CONV_IMPLS[self.conv_impl]
         if x.ndim == 3:  # grayscale [B, H, W] -> [B, H, W, 1]
             x = x[..., None]
         x = x.astype(self.compute_dtype)

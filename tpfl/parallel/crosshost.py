@@ -20,7 +20,7 @@ sides of that check:
   contract), applies the knob overrides from ``TPFL_CROSSHOST_CFG``,
   runs :func:`demo_run`, and writes its JSON result to
   ``<TPFL_CROSSHOST_OUT>.<process_id>.json``.
-- :func:`launch` — the orchestrator tests/bench call in-process: forks
+- :func:`launch` — the orchestrator tests call in-process: forks
   N workers with per-process env (``JAX_PLATFORMS=cpu`` and
   ``--xla_force_host_platform_device_count=K`` BEFORE the child
   imports jax — the reason this is a subprocess harness at all),
@@ -167,8 +167,8 @@ def demo_run(
     # The cross-host receipt: bytes the DCN leg ships per round under
     # the active codec — hosts × codec'd-model bytes, the exact
     # constant the telemetry carry's dcn_bytes row records
-    # (tests/test_crosshost.py pins carry == constant; the bench gates
-    # the dense/quant8 ratio on this).
+    # (tests/test_crosshost.py pins carry == constant and the
+    # dense/quant8 ratio of it).
     from tpfl.learning import compression
 
     # The fleet-observatory leg (ISSUE-20): every worker receipt

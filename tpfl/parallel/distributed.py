@@ -10,8 +10,7 @@ The CPU CI exercises this for real — ``jax_cpu_collectives_implementation
 = "gloo"`` gives the host platform TCP collectives, and
 ``--xla_force_host_platform_device_count=K`` gives each worker K virtual
 devices — so cross-host == single-process parity is machine-checked
-without TPU hardware (tests/test_crosshost.py, bench ``crosshost``
-tier). On real pods the same entry point picks up the TPU runtime's
+without TPU hardware (tests/test_crosshost.py). On real pods the same entry point picks up the TPU runtime's
 own coordinator (see docs/deployment.md).
 
 Environment contract (the subprocess harness and real launchers both

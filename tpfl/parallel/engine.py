@@ -84,7 +84,7 @@ Consumers: :class:`~tpfl.parallel.federation.VmapFederation` (all its
 round programs are built here), the batched-fit pool
 (:func:`build_batched_fit_program` / :func:`maybe_nodes_mesh`),
 :class:`~tpfl.parallel.federation_learner.FederationLearner` (round
-windows), and ``bench.py``'s ``multichip`` tier.
+windows), and the chip benchmark (``benchmark/harness.py``).
 """
 
 from __future__ import annotations
@@ -689,7 +689,7 @@ class FederationEngine:
         # from the checkpointed n_nodes on this mesh.
         self.padded_nodes = padded_node_count(self.n_nodes, self.mesh)
         # unguarded: single-owner — an engine is built and driven by one
-        # thread (a learner's fit thread or the bench); the caches below
+        # thread (a learner's fit thread or a driver script); the caches below
         # are only touched from that thread.
         # ephemeral: compiled-program cache — rebuilt per mesh/process
         # (the persistent XLA cache makes rebuilds warm, not a resume
@@ -1373,7 +1373,8 @@ class FederationEngine:
         is Python-level, so the carry is elided from the trace, not
         masked out of it.
 
-        ``a_ndim`` (the adversarial variant, bench/test machinery):
+        ``a_ndim`` (the adversarial variant; only tests pass it —
+        ``tests/test_engine_obs.py`` — kept until ROADMAP D3):
         appends an ``attack_scales`` argument ([n] or [n_rounds, n])
         multiplied into each node's TRAINED params before stats and
         fold — the in-program lowering of ``AttackPlan``'s sign-flip
@@ -1913,12 +1914,11 @@ class FederationEngine:
         mesh_hosts: int = 1, pop_size: int = 0,
     ) -> Callable:
         """Cached compiled program for ``(kind, epochs, n_rounds,
-        w_ndim)`` — the raw jitted callable (bench drives these from
-        inside its own timed loops). ``donate=False`` builds a
-        NON-donating variant (separate cache slot): repeated-call
-        benchmarking over FIXED buffers (``best_of_wall``) re-feeds
-        inputs a donating program would have consumed — the donating
-        path is timed by ``best_of_wall_donated``, which re-binds.
+        w_ndim)`` — the raw jitted callable. ``donate=False`` builds a
+        NON-donating variant (separate cache slot) for a caller that
+        re-feeds inputs a donating program would have consumed: the
+        tests' ``.lower()`` pins, ``benchmark/harness.py``'s check and
+        ``chip_smoke.py``'s ``sync`` phase pass it (ROADMAP D3).
         ``telemetry``/``a_ndim``/``codec`` select the ENGINE_TELEMETRY
         carry / attack-scale / ENGINE_WIRE_CODEC variants — every
         variant axis (donation mode included) is part of the cache
@@ -2275,13 +2275,13 @@ class FederationEngine:
         ``weights``: [n] per-node FedAvg weight (0 = not elected),
         or [n_rounds, n] for per-round participation; None = uniform
         full participation. Data is reused across the window's rounds
-        (the bench/simulation semantics; re-stack between windows for
+        (the simulation semantics; re-stack between windows for
         fresh data). ``donate`` defaults to ``Settings.ENGINE_DONATE``
         (True: the program consumes the state buffers it was handed —
         params/variates/aux alias the outputs in-place, no staging
         copy; verify with :meth:`donation_report`); ``donate=False``
-        keeps the input buffers alive (repeated-call benchmarking over
-        the same arrays — ``profiling.best_of_wall``'s contract).
+        keeps the input buffers alive (for a caller that re-feeds the
+        same arrays: see :meth:`program`).
 
         With ``Settings.ENGINE_WIRE_CODEC`` != "dense" the window runs
         the device-codec program variant: every node's contribution
@@ -2290,7 +2290,7 @@ class FederationEngine:
         ``wire_bytes`` row records the exchange's per-round payload
         bytes. "dense" compiles the byte-identical pre-codec program.
 
-        ``attack_scales`` ([n] or [n_rounds, n], bench/test machinery):
+        ``attack_scales`` ([n] or [n_rounds, n], test machinery):
         per-node multipliers applied to each node's TRAINED params
         before the fold — the in-program seeded adversary
         (``AttackPlan.engine_scales``); None (default) compiles no

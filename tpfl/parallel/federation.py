@@ -124,12 +124,13 @@ class VmapFederation:
         mesh (node axis sharded, padded to the device multiple)."""
         return self.engine.shard_data(xs, ys)
 
-    # --- raw round programs (bench drives these inside its own jitted
-    # loops, where the observatory's per-call probe would execute at
-    # trace time and record junk — so these stay unwrapped; they are
-    # jitted with the LEGACY signatures — positional-static epochs,
-    # legacy donation — so ``.lower(...)`` keeps working for the
-    # static scaling analysis and the bench flops estimate) ---
+    # --- raw round programs (unwrapped: a caller may drive them from
+    # inside its own jitted loop, where the observatory's per-call
+    # probe would execute at trace time and record junk; jitted with
+    # the LEGACY signatures — positional-static epochs, legacy
+    # donation — so ``.lower(...)`` keeps working for the static
+    # scaling analysis: ``tests/test_scaling_model.py`` and
+    # ``__graft_entry__.py`` are the callers left, ROADMAP D5) ---
 
     def _build_round(self) -> Callable:
         eng = self.engine
@@ -230,9 +231,9 @@ class VmapFederation:
                 )
             if self._round_scaffold_fn is None:
                 # Observatory wrap at the API seam (not inside the
-                # builders): bench drives the raw _build_round* fns
-                # from inside its own jitted loops, where a per-call
-                # probe would execute at trace time and record junk.
+                # builders): the raw _build_round* fns may run inside a
+                # caller's own jitted loop, where a per-call probe
+                # would execute at trace time and record junk.
                 self._round_scaffold_fn = profiling.observatory.wrap(
                     self._build_round_scaffold(),
                     f"vmap_round_scaffold:{profiling.module_tag(self.module)}",
@@ -279,10 +280,8 @@ class VmapFederation:
         :meth:`round`; ``n_rounds=1`` is the identical program.
         ``donate`` defaults to ``Settings.ENGINE_DONATE`` (the state
         buffers alias the outputs in place); ``donate=False`` keeps
-        input buffers alive (repeated-call benchmarking over fixed
-        arrays — ``profiling.best_of_wall``'s contract; the primary
-        tier times the DONATING program via
-        ``profiling.best_of_wall_donated``). ``schedule`` (a
+        input buffers alive (a caller that re-feeds the same arrays:
+        tests do). ``schedule`` (a
         :class:`~tpfl.parallel.engine.FedBuffSchedule`) runs the
         window ASYNC — per-round arrival masks with staleness-weighted
         folds, the FedBuff semantics of the gRPC tier moved on-device

@@ -431,11 +431,11 @@ def flash_attention(
     [B, S, H, D]. Backward is the recompute-based flash VJP (two Pallas
     kernels); gradients match the XLA blockwise path (tested).
 
-    ``block``: 1024 is the measured sweet spot on v5e for H=8, D=128 —
-    496k toks/s fwd+bwd at 8k tokens and 374k at 32k, vs 230k/132k at
-    the former 256 default (the [block, block] f32 score tile then
-    fills VMEM well; 2048 exceeds it and fails to compile). Shorter
-    sequences are clamped to ``min(block, S)``.
+    ``block``: 1024 on v5e for H=8, D=128 (the [block, block] f32
+    score tile then fills VMEM well; 2048 exceeds it and fails to
+    compile; its rates are pre-PR-1, not re-measured:
+    ``docs/perf_attention.md``). Shorter sequences are clamped to
+    ``min(block, S)``.
 
     Non-causal with a sequence that doesn't divide ``block`` falls back
     to the XLA blockwise path (pad keys would need extra masking; the

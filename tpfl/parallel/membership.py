@@ -19,8 +19,9 @@ padded slots, so every membership event the ops plane sees —
 (:meth:`weights`). The program's cache key, abstract shapes and
 compiled bytes are all functions of the tier, so churn inside a tier
 runs **zero recompiles** (the CompileObservatory's
-``signature_counts`` is the receipt; the bench ``elastic`` tier gates
-it). Only crossing a tier boundary (:meth:`maybe_resize`) re-lowers —
+``signature_counts`` is the receipt:
+``tests/test_elastic.py::test_churn_storm_zero_recompiles_at_fixed_tier``).
+Only crossing a tier boundary (:meth:`maybe_resize`) re-lowers —
 and demoting back to a previously-visited tier re-uses its cached
 program, so even tier oscillation compiles each tier once.
 
@@ -85,8 +86,9 @@ class MembershipView:
         # mask, never restack state).
         # guarded-by: _lock
         self._quarantined: set[str] = set()
-        # Bounded tier-change log ({"kind","capacity","live"}) — the
-        # bench gates recompile count == promotion count.
+        # Bounded tier-change log ({"kind","capacity","live"}):
+        # recompile count == promotion count
+        # (tests/test_elastic.py::test_tier_promotion_compiles_once_then_caches).
         # guarded-by: _lock
         self._tier_log: list[dict] = []
         # guarded-by: _lock — next never-used slot ordinal.
@@ -204,8 +206,8 @@ class MembershipView:
             return [dict(e) for e in self._tier_log]
 
     def promotions(self) -> int:
-        """Tier promotions so far — the bench's allowed-recompile
-        budget (recompile count == promotions, nothing else)."""
+        """Tier promotions so far — the allowed-recompile budget
+        (recompile count == promotions, nothing else)."""
         with self._lock:
             return sum(1 for e in self._tier_log if e["kind"] == "promote")
 

@@ -67,7 +67,7 @@ def device_report() -> dict[str, Any]:
 
 def require_chip(min_count: int = 1) -> dict[str, Any]:
     """:func:`device_report` for a path that only means something on the
-    accelerator (``chip_smoke.py``, ``bench.py``'s timed device tiers):
+    accelerator (``chip_smoke.py``, ``benchmark/run.py``):
     raises instead of letting JAX's silent CPU fallback produce numbers
     under a device metric's name. A TPU kind missing from the peaks
     table is an error too — utilisation against an unknown peak is not

@@ -108,8 +108,7 @@ def blockwise_attention(
     ``_blockwise_vjp_bwd``), a few key heads a step. Reverse-mode
     through the forward's scan would instead stash O(S·block) score
     residuals per step, which at 32k tokens produced a program the TPU
-    compiler could not build (the r3 bench's ``blockwise_fwdbwd_32k``
-    compile failure).
+    compiler could not build (pre-PR-1, not re-measured).
 
     The tile matmuls (``P V``, ``dO V^T``, ``dS K``, ``dS^T Q``,
     ``P^T dO``) read P and dS rounded to the inputs' dtype beside q, k,

@@ -72,10 +72,10 @@ def analyze_compiled(compiled: Any) -> dict[str, Any]:
     "collective_bytes": total}.
 
     FLOPs come from the shared :class:`~tpfl.management.profiling
-    .CostModel` — the ONE ``cost_analysis()`` call path (bench.py's
-    live MFU uses the same one, with the same scan-counted-once
-    caveat), so static scaling analysis and live MFU can never
-    disagree about what a program costs."""
+    .CostModel` — the ONE ``cost_analysis()`` call path (the live
+    ``tpfl_mfu`` gauge uses the same one, with the same
+    scan-counted-once caveat), so static scaling analysis and live MFU
+    can never disagree about what a program costs."""
     coll = collective_bytes(compiled.as_text())
     return {
         "flops": cost_model.xla_flops(compiled) or 0.0,

@@ -6,7 +6,7 @@ TPU-idiomatic seam: a jitted train step whose batch is sharded over a
 ``dp`` axis and (optionally) whose parameters/optimizer state are
 sharded FSDP-style; XLA inserts the gradient all-reduce / all-gather
 collectives over ICI. Plugs into a Learner via ``optimizer_factory`` /
-custom fit, or is used directly by benchmarks.
+custom fit, or is used directly (``__graft_entry__.py``'s dry run).
 """
 
 from __future__ import annotations

@@ -170,7 +170,7 @@ class WindowPipeline:
     def __init__(self, engine: FederationEngine) -> None:
         self.engine = engine
         # unguarded: written only by the run() thread; cross-thread
-        # readers (bench/tests) read after run() returns.
+        # readers (tests) read after run() returns.
         # ephemeral: per-run diagnostic — every run() resets it; the
         # durable cadence state rides the engine snapshot
         # (_materialize_snapshot -> engine.export_state).

@@ -163,8 +163,8 @@ class Settings:
     one entropy coder ("zlib", or "zstd" when the optional zstandard
     package is installed). E.g. "quant8+zlib". Validated at use time —
     unknown names raise ValueError. Lossy codecs are within FedAvg /
-    SCAFFOLD convergence noise on the digits/CIFAR paths (seeded A/B
-    in bench.py) at ≥4x fewer payload bytes."""
+    SCAFFOLD convergence noise on the digits path at ≥4x fewer payload
+    bytes (``tests/test_compression.py::test_wire_ab_codec_moves_4x_fewer_bytes_at_loss_parity``)."""
 
     WIRE_TOPK_FRAC: float = 0.05
     """Fraction of entries per leaf the "topk" codec keeps (by
@@ -286,7 +286,7 @@ class Settings:
     serialized deterministic order — schedule order when a seeded
     :class:`tpfl.communication.faults.AsyncSchedule` is attached to
     the aggregator (the reorder-buffer admission that makes same-seed
-    runs byte-identical, bench's async tier), else canonical
+    runs byte-identical, ``tests/test_async_control.py``), else canonical
     (contributor-sorted) order. False (scale profile): free-running —
     contributions fold eagerly in arrival order (AGG_STREAM_EAGER
     semantics), maximum throughput, no reproducibility guarantee."""
@@ -409,9 +409,11 @@ class Settings:
     recorder — reconstructable across nodes into a round timeline by
     ``tools/traceview.py``. Off by default: the metrics REGISTRY
     (``logger.metrics``) always records (cheap per-thread dict
-    updates), but span minting/recording is gated here — measured <5%
-    rounds/sec overhead when on (bench.py telemetry tier), zero when
-    off. Read at use time, so it can be toggled between experiments."""
+    updates), but span minting/recording is gated here — off, a span
+    site records nothing
+    (``tests/test_telemetry.py::test_span_gating_and_ring_bound``); the
+    cost when on is not measured on the chip. Read at use time, so it
+    can be toggled between experiments."""
 
     TELEMETRY_RING: int = 512
     """Flight-recorder capacity: the last N spans/events retained PER
@@ -432,7 +434,7 @@ class Settings:
     """Directory for flight-recorder crash dumps (JSON, one file per
     (node, reason)). Empty (default) disables file dumps — the ring
     still records and ``logger.metrics``/``FlightRecorder.snapshot``
-    stay queryable in-process. Set by the chaos harness / bench so
+    stay queryable in-process. Set by the chaos harness so
     every injected crash and quorum degradation is post-mortem-able."""
 
     METRIC_MAX_POINTS: int = 4096
@@ -475,8 +477,8 @@ class Settings:
     gauge(tpfl_engine_loss) <= 2.5"``. Signals are
     EWMA-smoothed (``SLO_EWMA``); ``SLO_BREACH_WINDOWS`` consecutive
     violating evaluations emit a ``slo_breach`` flight event and bump
-    ``tpfl_slo_breach_total`` — bench's offline baseline gate brought
-    into running federations. Empty (default) = watchdog idle."""
+    ``tpfl_slo_breach_total`` — a regression gate inside running
+    federations. Empty (default) = watchdog idle."""
 
     SLO_EWMA: float = 0.3
     """EWMA smoothing factor for SLO watchdog signals (weight of the
@@ -534,9 +536,10 @@ class Settings:
     attribution spans (RoundProfiler: train/dispatch/fold/gossip/
     host_other), and the block_until_ready dispatch/compute split in
     the learner. Off by default — disabled profiling is one attribute
-    read per instrumented site, adds ZERO device dispatches, and costs
-    no measurable rounds/sec (bench.py's profiling tier A/B); enabled
-    overhead is budgeted ≤5% like the telemetry tier. The always-cheap
+    read per instrumented site and adds ZERO device dispatches
+    (``tests/test_profiling.py``: the disabled observatory and profiler
+    record nothing); the cost when on is not measured on the chip. The
+    always-cheap
     registry side (compiled-cache hit/miss counters and size gauges,
     HBM gauges) records regardless, per the PR-5 rule. Read at use
     time, so it can be toggled between experiments."""
@@ -552,9 +555,8 @@ class Settings:
 
     PROFILING_TRACE_DIR: str = ""
     """When set, federation runs wrap the experiment (StartLearning →
-    experiment finish) in a ``jax.profiler`` trace written here —
-    bench.py's opt-in ``--profile``, promoted to ANY run: the CLI's
-    ``tpfl experiment run --profile DIR`` sets this via the
+    experiment finish) in a ``jax.profiler`` trace written here: the
+    CLI's ``tpfl experiment run --profile DIR`` sets this via the
     ``TPFL_PROFILING_TRACE_DIR`` environment override. One process-wide
     trace at a time (in-process federations share the profiler); view
     with TensorBoard/xprof. Empty (default) disables."""
@@ -570,9 +572,10 @@ class Settings:
     (global-model delta norm + loss-trajectory slope), and the
     AnomalyScorer's sign-flip / norm-outlier detection. Off by
     default — disabled, every tap is one attribute read and adds ZERO
-    device dispatches (bench.py's ledger tier off/on A/B is the
-    receipt); enabled overhead is budgeted <5% rounds/sec like
-    telemetry/profiling. Detection is observational: flags never
+    device dispatches
+    (``tests/test_ledger.py::test_disabled_ledger_adds_zero_dispatches``);
+    the cost when on is not measured on the chip. Detection is
+    observational: flags never
     change aggregation results. Read at use time."""
 
     LEDGER_RING: int = 1024
@@ -619,8 +622,9 @@ class Settings:
     enabling this activates the ledger's open-round/scoring taps even
     when LEDGER_ENABLED is off (the observational knob only gates the
     passive record path). Off by default — disabled, the intake is one
-    attribute read; enabled overhead is budgeted within the shared 5%
-    rounds/sec envelope (bench.py's byzantine tier off/on A/B). Unlike
+    attribute read
+    (``tests/test_quarantine.py::test_disabled_defense_is_inert``); the
+    cost when on is not measured on the chip. Unlike
     the ledger, quarantine is NOT observational: verdicts change what
     aggregates. Read at use time."""
 
@@ -784,8 +788,9 @@ class Settings:
     natively (~4x fewer exchange bytes for f32 under quant8) and the
     ENGINE_TELEMETRY carry's ``wire_bytes`` row records bytes/round
     device-side (``tpfl_engine_wire_bytes``). LOSSY like the host-side
-    WIRE_CODEC it mirrors (same kernels, same per-leaf policy — the
-    bench gates loss parity); "dense" compiles the byte-identical
+    WIRE_CODEC it mirrors (same kernels, same per-leaf policy; loss
+    parity: ``tests/test_engine_wire.py::test_quantized_gossip_loss_parity``);
+    "dense" compiles the byte-identical
     pre-codec program (separate program-cache slot, HLO-digest-stable
     across toggles). Entropy coders (zlib/zstd) and delta are host
     byte transforms and are rejected here at knob-read time. Read at
@@ -816,11 +821,11 @@ class Settings:
     XLA writes the fold's outputs INTO the input buffers, so a window
     costs no staging copy of the model state and peak HBM stays
     one-model-deep (verify with ``FederationEngine.donation_report``;
-    the engine_wire bench tier gates donation-clean HLO and
+    ``tests/test_engine_wire.py`` pins donation-clean HLO and
     byte-identical outputs vs the non-donating variant). The handed-in
-    buffers are CONSUMED — callers that re-feed the same arrays
-    (repeated-call benchmarking) pass ``donate=False`` explicitly or
-    rebind from the outputs (``profiling.best_of_wall_donated``).
+    buffers are CONSUMED — callers that re-feed the same arrays pass
+    ``donate=False`` explicitly or rebind from the outputs
+    (``profiling.best_of_wall_donated``).
     False: every dispatch allocates fresh outputs (debugging aid)."""
 
     ELASTIC_CAPACITY_MIN: int = 2
@@ -871,9 +876,9 @@ class Settings:
     ``copy_to_host_async`` host leg, landing while the next window's
     device work runs) and written as a checkpoint. 0 (default)
     disables cadence snapshots even when CHECKPOINT_DIR is set (the
-    SIGTERM path below can still emit a final checkpoint). The bench
-    ``elastic`` tier gates the cadence overhead inside a 5% budget.
-    Read per fit() call."""
+    SIGTERM path below can still emit a final checkpoint). What a
+    snapshot stalls a window by is not measured on the chip (ROADMAP
+    S7). Read per fit() call."""
 
     CHECKPOINT_ON_SIGTERM: bool = False
     """Preemption hardening: when on (and CHECKPOINT_DIR is set),
@@ -935,22 +940,13 @@ class Settings:
     asserts the graph is acyclic — a cycle is a latent deadlock, and
     the error carries the witness chain. Read at lock CREATION time, so
     it must be set before nodes are built. Off by default (one
-    thread-local append per acquire, measured <10% round-throughput
-    overhead in bench.py's analysis tier — fine for chaos/e2e runs,
-    not for 1000-node profiles). The static half of the same invariant
+    thread-local append per acquire — fine for chaos/e2e runs, not for
+    1000-node profiles). The static half of the same invariant
     runs in CI via ``python -m tools.tpflcheck`` (docs/concurrency.md)."""
 
     # --- determinism / TPU ---
     SEED: int | None = None
     """Global seed for reproducible experiments (fork feature)."""
-
-    DEFAULT_DTYPE: str = "float32"
-    """Parameter dtype; compute may run bfloat16 on TPU."""
-
-    EXACT_AGGREGATION: bool = True
-    """When all train-set nodes share one process/mesh, replace
-    gossip-until-converged with an exact on-device mean (see
-    tpfl.parallel). Cross-host gossip still applies between processes."""
 
     @classmethod
     def set_test_settings(cls) -> None:
@@ -1019,7 +1015,7 @@ class Settings:
         cls.BREAKER_PROBE_PERIOD = 1.0
         cls.ROUND_QUORUM = 1.0
         # Async rounds off by default (reference-parity sync lifecycle);
-        # async tests/bench toggle per-case. Serialized discipline ON
+        # async tests toggle per-case. Serialized discipline ON
         # for this profile: deferred canonical folds (schedule order
         # when one is attached) keep seeded async runs byte-identical.
         cls.ASYNC_ROUNDS = False
@@ -1053,15 +1049,15 @@ class Settings:
         cls.SLO_TARGETS = ""
         cls.SLO_EWMA = 0.3
         cls.SLO_BREACH_WINDOWS = 2
-        # Device-plane profiling off by default (profiling tests and
-        # the bench profiling tier toggle per-case); a low storm
+        # Device-plane profiling off by default (profiling tests
+        # toggle per-case); a low storm
         # threshold would misfire on tests that legitimately churn
         # shapes, so the class default rides.
         cls.PROFILING_ENABLED = False
         cls.PROFILING_RECOMPILE_WARN = 8
         cls.PROFILING_TRACE_DIR = ""
-        # Learning-plane ledger off by default (ledger tests and the
-        # bench ledger tier toggle per-case) — disabled taps add zero
+        # Learning-plane ledger off by default (ledger tests toggle
+        # per-case) — disabled taps add zero
         # device dispatches, keeping seeded runs bit-identical to
         # pre-ledger behavior.
         cls.LEDGER_ENABLED = False
@@ -1070,8 +1066,8 @@ class Settings:
         cls.LEDGER_ANOMALY_COS = 0.0
         cls.LEDGER_ANOMALY_MIN_N = 4
         cls.LEDGER_CONVERGENCE_WINDOW = 5
-        # Active defense off by default (quarantine/robust tests and the
-        # bench byzantine tier toggle per-case) — verdicts change what
+        # Active defense off by default (quarantine/robust tests
+        # toggle per-case) — verdicts change what
         # aggregates, so seeded reference-parity runs keep it off.
         cls.QUARANTINE_ENABLED = False
         cls.QUARANTINE_PROBATION_ROUNDS = 2
@@ -1092,8 +1088,8 @@ class Settings:
         cls.POPULATION_CLIENTS = 0
         cls.POPULATION_SAMPLE = 100
         cls.SHARD_ROUNDS_PER_DISPATCH = 1
-        # Engine-plane telemetry off by default (engine_obs tests and
-        # the bench engine_obs tier toggle per-case): the elided carry
+        # Engine-plane telemetry off by default (engine_obs tests
+        # toggle per-case): the elided carry
         # keeps the engine's round program byte-identical to the
         # reference path.
         cls.ENGINE_TELEMETRY = False
@@ -1321,7 +1317,8 @@ class Settings:
         cls.ROUND_WAIT_POLL = 2.0
         # The 1000-node runs are gossip-bound, not compute-bound:
         # quantize + DEFLATE the weight payloads (~4-5x fewer bytes at
-        # convergence within noise — bench.py's seeded A/B) and ship
+        # convergence within noise — tests/test_compression.py's
+        # seeded A/B) and ship
         # round results as residuals against the previous round's
         # aggregate wherever the peer acknowledged holding it.
         cls.WIRE_CODEC = "quant8+zlib"
@@ -1363,7 +1360,7 @@ class Settings:
         cls.ASYNC_ROUND_DEADLINE = 60.0
         cls.ASYNC_SERIALIZED = False
         # Free-running fleets are what the adaptive controller is FOR:
-        # the static K/deadline that fit a 10-node bench fleet starve
+        # the static K/deadline that fit a 10-node fleet starve
         # or barrier a 1000-node one, so when async is enabled at scale
         # the knobs tune themselves from the observed arrival cadence.
         # Untagged contributions fold at the maximum discount — at this
@@ -1430,7 +1427,7 @@ class Settings:
         # cross-window reproducibility the same way.
         cls.SHARD_NODES = True
         cls.SHARD_DEVICES = 0
-        # Model axis off by default even at scale: the zoo's bench
+        # Model axis off by default even at scale: the zoo's small
         # models fit one chip, and nodes-axis throughput is the
         # scale profile's first-order win. Raise SHARD_MODEL (a
         # divisor of the device count) to federate models bigger
@@ -1459,8 +1456,8 @@ class Settings:
         # The scale profile already ships quant8 on the host wire
         # (WIRE_CODEC above) — the in-program exchange follows suit:
         # cross-host/sharded gossip psums int8-round-tripped tensors
-        # natively (~4x fewer exchange bytes at the bench-gated loss
-        # parity). Donation on: O(1)-model HBM per window.
+        # natively (~4x fewer exchange bytes at loss parity,
+        # tests/test_engine_wire.py). Donation on: O(1)-model HBM per window.
         cls.ENGINE_WIRE_CODEC = "quant8"
         cls.ENGINE_DONATE = True
         # 8-round windows carry enough device work to hide the host

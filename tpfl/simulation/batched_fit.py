@@ -17,7 +17,7 @@ batch together exactly.
 
 The compiled program itself is built by the federation engine
 (``tpfl.parallel.engine.build_batched_fit_program`` — the one seam the
-vmapped federation, this pool, and the bench all ride), and when
+vmapped federation and this pool ride), and when
 ``Settings.SHARD_NODES`` is on with a multi-chip host the stacked node
 axis is placed over the ``nodes`` mesh
 (``engine.maybe_nodes_mesh``), so pool fits run SPMD across chips.

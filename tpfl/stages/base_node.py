@@ -65,7 +65,7 @@ class StartLearningStage(Stage):
         st.set_experiment(Experiment(node.exp_name, node.rounds))
         logger.experiment_started(node.addr, st.experiment)
         node.learner.set_epochs(node.epochs)
-        # Any run can produce a TPU trace, not just bench: when the
+        # Any run can produce a TPU trace: when the
         # experiment carries a profile dir (Settings.PROFILING_TRACE_DIR
         # / the CLI's --profile), wrap it in a process-wide
         # jax.profiler trace (idempotent — in-process peers share one
