@@ -44,10 +44,24 @@ def described():
 @pytest.mark.parametrize(
     "case, kernels, permutes",
     [
-        ("flash_8k", 3, 0),
-        ("flash_4k_h16_d128", 3, 0),
-        ("ring_flash_sp4", 6, 1),
+        # One forward kernel and ONE backward sweep a call (PR 30; the
+        # two-kernel backward before it made these 3, 3 and 6).
+        ("flash_8k", 2, 0),
+        ("flash_4k_h16_d128", 2, 0),
+        # The ring's causal step has two call sites a side (the diagonal
+        # pair masked, the pairs behind it not).
+        ("ring_flash_sp4", 4, 1),
         ("ssm_scan_8k_x2", 2, 0),
+        # blockwise_attention's TPU branch at the benchmark's shapes,
+        # under the engine's vmap: GPT-2 (two 64-wide heads a lane tile,
+        # two blocks) and SambaY (grouped rows, a value width of its own,
+        # sixteen blocks, 8 MB of float32 dq resident in VMEM).
+        ("block_attention_gpt2_x4", 2, 0),
+        ("block_attention_sambay_x2", 2, 0),
+        # The VMEM guard's edges (``flash_kernel.tiles``): the tallest
+        # tile with the longest resident dq, bf16 and float32 gradients.
+        ("flash_64k_d128", 2, 0),
+        ("flash_40k_d128_f32", 2, 0),
     ],
 )
 def test_kernel_compiles_for_described_v5e(described, case, kernels, permutes):
@@ -57,7 +71,55 @@ def test_kernel_compiles_for_described_v5e(described, case, kernels, permutes):
     assert report["collective_permute"] >= permutes, report
 
 
-def test_lm_head_owns_its_loss_in_the_compiled_window(described):
+@pytest.fixture(scope="module")
+def gpt2_window_text(described):
+    """The compiled text of a window of GPT-2 small's width, heads,
+    context and vocabulary (one block, two silos)."""
+    fn, args = rehearsal.cases(described)["engine_gpt2_head_x2"]()
+    return fn.lower(*args).compile().as_text()
+
+
+def test_attention_kernels_are_in_the_compiled_window(gpt2_window_text):
+    """On a TPU the zoo block's default attention is the Pallas kernels:
+    the compiled window calls one forward and one backward kernel by
+    name, and no ``while`` of the XLA block loop is left under the
+    scope."""
+    import re
+
+    calls = [
+        line for line in gpt2_window_text.splitlines()
+        if "tpu_custom_call" in line and " custom-call(" in line
+    ]
+    # A kernel's instruction carries the kernel's name: this is the name
+    # a device trace shows (``attention_kernel_share_pct`` reads it).
+    names = sorted(
+        re.match(r"\s*%?([A-Za-z_]+)", line).group(1).rstrip("_") for line in calls
+    )
+    assert names == ["block_attention_backward", "block_attention_forward"], calls
+    loops = [
+        line for line in gpt2_window_text.splitlines()
+        if " while(" in line and "block_attention" in line
+    ]
+    assert not loops, loops
+
+
+def test_attention_operands_are_not_copied_between_layouts(gpt2_window_text):
+    """The kernels read ``[.., heads * d]``. The zoo block splits the
+    joint projection FIRST and names the heads after, so the kernels'
+    reshape back folds away: no bf16 ``[.., 768]`` copy of q, k, v or
+    their gradients is materialised under the scope (a reshape between
+    ``[.., 12, 64]`` and ``[.., 768]`` is a copy on a TPU, whose tiles
+    span the last two dimensions: six a layer before, 4.3 ms a round of
+    GPT-2's cell, PERF.md PR 30)."""
+    copies = [
+        op for op in rehearsal.estimated_operations(gpt2_window_text)
+        if op["name"].startswith("copy") and "block_attention" in op["op_name_tail"]
+        and op["shape"].startswith("bf16[") and op["shape"].endswith(",768]")
+    ]
+    assert not copies, copies
+
+
+def test_lm_head_owns_its_loss_in_the_compiled_window(described, gpt2_window_text):
     """A window of GPT-2 small's width and vocabulary (one block, two
     silos) compiled for the described v5e: with the head owning its
     loss, no float32 array of the logits' full ``[.., seq, vocab]``
@@ -69,8 +131,7 @@ def test_lm_head_owns_its_loss_in_the_compiled_window(described):
 
     from tpfl.models.head_loss import _ONES_ROWS
 
-    fn, args = rehearsal.cases(described)["engine_gpt2_head_x2"]()
-    text = fn.lower(*args).compile().as_text()
+    text = gpt2_window_text
     ops = rehearsal.estimated_operations(text)  # what is materialised
     full_logits = re.compile(r"\[[\d,]*1024,50257\]")
     assert any(full_logits.search(op["shape"]) for op in ops)  # bf16 ones are

@@ -693,6 +693,173 @@ def test_flash_kernel_gradients_unaligned_causal():
         )
 
 
+@pytest.fixture
+def kernels_on_cpu(monkeypatch):
+    """``blockwise_attention``'s TPU branch on the CPU: the one seam the
+    selection goes through says "a TPU", and the kernels run in Pallas's
+    emulator."""
+    from tpfl.parallel import compat
+
+    monkeypatch.setattr(compat, "on_tpu", lambda: True)
+    monkeypatch.setattr(compat, "pallas_interpret", lambda interpret: True)
+
+
+def _attention_and_gradients(q, k, v, cot, silos: bool, **kw):
+    """(out, dq, dk, dv) of ``blockwise_attention`` as traced NOW (a fresh
+    function each call: jit would hand back the other path's program)."""
+    from tpfl.parallel.ring_attention import blockwise_attention
+
+    def both(q, k, v, cot):
+        def loss(q, k, v):
+            out = blockwise_attention(q, k, v, **kw)
+            return jnp.vdot(out.astype(jnp.float32), cot), out
+
+        grads, out = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+        return (out, *grads)
+
+    return jax.jit(jax.vmap(both) if silos else both)(q, k, v, cot)
+
+
+@pytest.mark.parametrize(
+    "hq, hkv, d, dv, s, block, causal, silos, dtype",
+    [
+        # GPT-2's call: equal heads of 64 (two a program instance), two
+        # blocks of 512 -> three visible pairs, one skipped.
+        (2, 2, 64, 64, 1024, 512, True, False, jnp.float32),
+        # SambaY's: two query heads a key head as rows, a value twice as
+        # wide as the keys (its own lane tiles, the keys' lanes masked).
+        (4, 2, 64, 128, 512, 256, True, False, jnp.float32),
+        # S not a multiple of the block: padded rows and keys, s_len.
+        (2, 2, 64, 64, 600, 256, True, False, jnp.float32),
+        # ... not causal: the last key block masks its padding.
+        (2, 2, 64, 64, 300, 128, False, False, jnp.float32),
+        # One 128-wide head a program instance; a scale (128 ** -0.5) that
+        # is no power of two multiplies the scores, not an operand.
+        (2, 1, 128, 128, 256, 128, True, False, jnp.float32),
+        # Heads no 128 lanes hold: every head in one instance.
+        (4, 2, 8, 16, 256, 128, True, False, jnp.float32),
+        # Under the engine's vmap over silos (one more grid dimension).
+        (4, 2, 64, 128, 256, 128, True, True, jnp.float32),
+        # bf16 as the cells run it.
+        (4, 2, 64, 128, 512, 256, True, True, jnp.bfloat16),
+    ],
+)
+def test_blockwise_attention_kernels_match_the_xla_loop(
+    monkeypatch, hq, hkv, d, dv, s, block, causal, silos, dtype
+):
+    """The Pallas kernels behind ``blockwise_attention`` on a TPU against
+    its XLA block loop: the output and all three gradients. float32 to
+    float32 tolerance (the same arithmetic, the matmuls' own summation
+    order apart). bf16 to 1% in norm: the LOOP rounds its scores to bf16
+    (``ring_attention._scores``: faster in XLA), the kernel reads the
+    float32 accumulator, so they differ by the rounding of one tile —
+    and the kernel is the closer of the two to the float32 result."""
+    from tpfl.parallel import compat
+
+    lead = (2,) if silos else ()
+    keys = jax.random.split(jax.random.PRNGKey(7), 4)
+    q32 = jax.random.normal(keys[0], (*lead, 2, s, hq, d))
+    k32 = jax.random.normal(keys[1], (*lead, 2, s, hkv, d))
+    v32 = jax.random.normal(keys[2], (*lead, 2, s, hkv, dv))
+    cot = jax.random.normal(keys[3], (*lead, 2, s, hq, dv))
+    q, k, v = (x.astype(dtype) for x in (q32, k32, v32))
+    kw = dict(causal=causal, block_size=block)
+    loop = _attention_and_gradients(q, k, v, cot, silos, **kw)
+    exact = _attention_and_gradients(q32, k32, v32, cot, silos, **kw)
+    monkeypatch.setattr(compat, "on_tpu", lambda: True)
+    monkeypatch.setattr(compat, "pallas_interpret", lambda interpret: True)
+    kernels = _attention_and_gradients(q, k, v, cot, silos, **kw)
+    # (a case whose blocks the selection refused would compare the loop
+    # with itself)
+    from tpfl.parallel.ring_attention import blockwise_attention
+
+    one = (x[0] if silos else x for x in (q, k, v))
+    assert "pallas_call" in str(jax.make_jaxpr(
+        lambda q, k, v: blockwise_attention(q, k, v, **kw)
+    )(*one))
+
+    def off(a, b):
+        a, b = (np.asarray(x, np.float32) for x in (a, b))
+        return np.linalg.norm(a - b) / np.linalg.norm(b)
+
+    for name, got, want, true in zip(("out", "dq", "dk", "dv"), kernels, loop, exact):
+        assert got.dtype == want.dtype and got.shape == want.shape, name
+        if dtype == jnp.float32:
+            np.testing.assert_allclose(got, want, atol=3e-5, err_msg=name)
+        else:
+            assert off(got, want) < 1e-2, (name, off(got, want))
+            assert off(got, true) < 1.1 * off(want, true), name
+
+
+def test_blockwise_attention_kernels_are_the_tpu_branch_without_a_band(kernels_on_cpu):
+    """What the code can observe selects the path: on a TPU the block
+    loop is two ``pallas_call`` s (forward; ONE backward sweep), with a
+    band (``window``) or at blocks the kernels cannot tile it stays the
+    XLA loop — and elsewhere (every other test of this file) too."""
+    from tpfl.parallel.ring_attention import blockwise_attention
+
+    def kernels(s, **kw):
+        x = jnp.zeros((1, s, 2, 64), jnp.bfloat16)
+        text = str(jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(
+            blockwise_attention(q, k, v, causal=True, **kw).astype(jnp.float32)
+        ), argnums=(0, 1, 2)))(x, x, x))
+        return text.count("pallas_call")
+
+    assert kernels(1024) == 2
+    assert kernels(1024, window=256) == 0
+    assert kernels(128) == 2  # one block as long as the sequence
+    # Only what was compiled for a TPU: a short sequence that is one
+    # block of its own, unaligned length stays with the loop, as before
+    # the kernels; so do blocks that are no lane tiles, or too tall.
+    assert kernels(100) == 0
+    assert kernels(120, block_size=40) == 0
+    assert kernels(4096, block_size=2048) == 0
+
+
+def test_ring_auto_takes_the_kernels_only_at_blocks_they_tile(kernels_on_cpu):
+    """``ring_attention(impl="auto")`` on a TPU: the flash inner where
+    the local block is whole lane tiles, the einsum inner where it is
+    not (a 100-token shard would be one 100-row block: never compiled
+    for a TPU)."""
+    from tpfl.parallel.ring_attention import make_ring_attention
+
+    ring = make_ring_attention(create_mesh({"sp": 8}), causal=True)
+
+    def kernels(s):
+        x = jnp.zeros((1, s, 2, 64), jnp.bfloat16)
+        return str(jax.make_jaxpr(ring)(x, x, x)).count("pallas_call")
+
+    assert kernels(8 * 128) > 0 and kernels(8 * 100) == 0
+
+
+def test_attention_kernels_take_the_fewest_heads_with_whole_lane_tiles():
+    from tpfl.parallel import flash_kernel
+
+    heads = flash_kernel._heads_per_instance
+    assert heads(12, 64, 64) == 2  # GPT-2: a head pair is one 128-lane tile
+    assert heads(20, 64, 128) == 2  # SambaY: the keys decide
+    assert heads(8, 128, 128) == 1
+    assert heads(3, 64, 64) == 3  # no pair divides three heads: all of them
+    assert heads(4, 8, 16) == 4
+    tiles = flash_kernel.tiles
+    assert tiles((1, 1024, 12, 64), 64, 512) and tiles((1, 128, 2, 64), 64, 128)
+    # One block of an unaligned length, 40-row blocks: no lane tiles.
+    assert not tiles((1, 100, 2, 64), 64, 100)
+    assert not tiles((1, 120, 2, 64), 64, 40)
+    # A pair's float32 tile: [1024, 1024] is the largest compiled.
+    assert tiles((1, 8192, 8, 128), 128, 1024)
+    assert not tiles((1, 8192, 8, 128), 128, 2048)
+    assert not tiles((1, 8192, 20, 64), 128, 1024, groups=2)
+    # The backward keeps a head block's dq for the whole sequence in
+    # VMEM, float32 scratch and double-buffered output block: 8 + 8 MB
+    # in the SambaY cell, too much at a million tokens, and float32
+    # gradients (the ring's; float32 inputs) reach the limit sooner.
+    assert tiles((1, 8192, 20, 64), 128, 512, groups=2)
+    assert not tiles((1, 1 << 20, 20, 64), 128, 512, groups=2)
+    assert tiles((1, 32768, 20, 64), 128, 512, groups=2)
+    assert not tiles((1, 32768, 20, 64), 128, 512, groups=2, grad_bytes=4)
+
+
 def test_transformer_lm_with_ring_attention_seam():
     """TransformerLM's attention_fn seam: the same model computes
     matching logits with default blockwise attention and with

@@ -368,6 +368,54 @@ def test_layer_kind_is_the_published_pattern():
             layer_kind(*bad)
 
 
+@pytest.mark.parametrize(
+    "layers, kernels",
+    [
+        # The benchmark's cut in little: Mamba, full attention (lends K / V),
+        # a GMU, cross-attention: both attention layers take the kernels ...
+        ((4, 5, 6, 7), 2 * 2 + 2),
+        # ... and a sliding-window layer stays the XLA loop (it has a band).
+        ((1,), 0),
+    ],
+)
+def test_sambay_attention_runs_the_kernels_on_the_tpu_branch(monkeypatch, layers, kernels):
+    """``DiffAttention`` through ``blockwise_attention``'s TPU branch
+    (Pallas's emulator here): grouped query rows, a value twice the
+    keys' width, borrowed keys and values — the loss and every
+    parameter's gradient agree with the XLA loop's, and the program
+    holds a forward kernel twice a layer (each block is recomputed) and
+    ONE backward kernel."""
+    from tpfl.parallel import compat
+
+    model = SambaYLM(window=4, layers=layers, compute_dtype=jnp.float32)
+    # 128 tokens: one block that is a whole lane tile (a shorter sequence
+    # is one block of its own length, which stays with the XLA loop).
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (1, 128), 0, 512)
+    params = model.init(jax.random.PRNGKey(1), tokens)
+
+    def loss_and_grads():
+        loss = lambda p: model.apply(p, tokens, train=True, targets=tokens)  # noqa: E731
+        text = str(jax.make_jaxpr(jax.grad(loss))(params))
+        # (a fresh function each call: jit cannot hand back the other path)
+        return (*jax.jit(jax.value_and_grad(loss))(params), text.count("pallas_call"))
+
+    loop_loss, loop_grads, none = loss_and_grads()
+    monkeypatch.setattr(compat, "on_tpu", lambda: True)
+    monkeypatch.setattr(compat, "pallas_interpret", lambda interpret: True)
+    loss, grads, calls = loss_and_grads()
+    # (the selective scan's kernels are in the program too, on this branch)
+    scans = 3 * sum(layer_kind(l, 8) == "mamba" for l in layers)
+    assert none == 0 and calls - scans == kernels
+    assert abs(loss - loop_loss) < 1e-5 * abs(loop_loss)
+    # (the key bias moves every score of a row alike, so its gradient is
+    # rounding noise around 0: compared on the scale of the weights')
+    worst = jax.tree.map(
+        lambda g, want: float(jnp.abs(g - want).max() / max(jnp.abs(want).max(), 1e-3)),
+        grads, loop_grads,
+    )
+    assert max(jax.tree.leaves(worst)) < 2e-4, worst
+
+
 def test_sambay_through_create_model_and_its_stage_rule():
     model = create_model("sambay_lm", (16,), window=4, compute_dtype=jnp.float32)
     assert sorted(model.get_parameters()) == (

@@ -185,6 +185,26 @@ def flash_case(shape, dtype, device) -> tuple:
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2))), (x, x, x)
 
 
+def block_attention_case(silos: int, q, k, v, device) -> tuple:
+    """(jitted fwd+bwd of ``blockwise_attention`` under the engine's vmap
+    over ``silos``, args) for bf16 causal ``q`` / ``k`` / ``v`` shapes: on
+    the TPU branch the block loop is two Pallas kernels."""
+    from tpfl.parallel.ring_attention import blockwise_attention
+
+    def loss(q, k, v):
+        out = jax.vmap(
+            lambda *x: blockwise_attention(*x, causal=True)
+        )(q, k, v)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    sharding = SingleDeviceSharding(device)
+    args = tuple(
+        jax.ShapeDtypeStruct((silos, *shape), jnp.bfloat16, sharding=sharding)
+        for shape in (q, k, v)
+    )
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))), args
+
+
 def scan_case(shape, states: int, silos: int, device) -> tuple:
     """(jitted fwd+bwd of the selective scan's Pallas kernels under the
     engine's vmap over ``silos``, args) for ``shape = (batch, S, D)``."""
@@ -350,7 +370,21 @@ def cases(devices) -> dict:
         "flash_32k": lambda: flash_case((1, 32768, 8, 64), bf16, d0),
         "flash_4k_h16_d128": lambda: flash_case((2, 4096, 16, 128), bf16, d0),
         "flash_2k_f32": lambda: flash_case((1, 2048, 8, 64), f32, d0),
+        # The edges of what ``flash_kernel.tiles`` admits: the largest
+        # tile ([1024, 1024]) with a head block's resident dq (float32
+        # scratch + double-buffered output block) at 64 and 63 MiB.
+        "flash_64k_d128": lambda: flash_case((1, 65536, 8, 128), bf16, d0),
+        "flash_40k_d128_f32": lambda: flash_case((1, 40960, 8, 128), f32, d0),
         "ring_flash_sp4": lambda: ring_case(devices),
+        # One attention call of the benchmark's LM cells: GPT-2's 4 x
+        # 1024 tokens a silo at four silos, SambaY's one 8192-token
+        # sequence at two (grouped heads, a value width of its own).
+        "block_attention_gpt2_x4": lambda: block_attention_case(
+            4, *[(4, 1024, 12, 64)] * 3, d0
+        ),
+        "block_attention_sambay_x2": lambda: block_attention_case(
+            2, (1, 8192, 40, 64), (1, 8192, 20, 64), (1, 8192, 20, 128), d0
+        ),
         # The Mamba layer of the benchmark's SambaY cell: one 8192-token
         # sequence a silo, 5120 channels x 16 states, two silos vmapped.
         "ssm_scan_8k_x2": lambda: scan_case((1, 8192, 5120), 16, 2, d0),

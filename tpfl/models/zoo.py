@@ -290,11 +290,12 @@ class TransformerBlock(nn.Module):
     """Pre-norm attention + MLP block. ``attention_fn(q, k, v, causal)``
     defaults to the differentiable flash-style
     :func:`~tpfl.parallel.ring_attention.blockwise_attention`
-    (O(block²) score memory); pass a
-    :func:`~tpfl.parallel.ring_attention.ring_attention` closure for
-    sequence-sharded training or
-    :func:`~tpfl.parallel.flash_kernel.flash_attention` for the Pallas
-    serving fast path."""
+    (O(block²) score memory; on a TPU its block loop is the Pallas
+    kernels of :mod:`tpfl.parallel.flash_kernel`, elsewhere an XLA
+    loop); pass a :func:`~tpfl.parallel.ring_attention.ring_attention`
+    closure for sequence-sharded training, or
+    :func:`~tpfl.parallel.flash_kernel.flash_attention` for the same
+    kernels at a block size of your own."""
 
     dim: int
     heads: int = 4
@@ -312,7 +313,12 @@ class TransformerBlock(nn.Module):
         h, d = self.heads, self.dim // self.heads
         y = nn.LayerNorm(dtype=self.compute_dtype)(x)
         qkv = nn.Dense(3 * self.dim, use_bias=False, dtype=self.compute_dtype)(y)
-        q, k, v = jnp.split(qkv.reshape(b, s, 3 * h, d), 3, axis=2)
+        # Split first, then name the heads: attention's kernels read
+        # [.., heads * d] (flash_kernel._lanes), and a reshape of a reshape
+        # folds away, where one of a [.., heads, d] slice is a copy on a TPU.
+        q, k, v = (
+            part.reshape(b, s, h, d) for part in jnp.split(qkv, 3, axis=-1)
+        )
         attn = attention(q, k, v, causal=self.causal)
         x = x + nn.Dense(self.dim, dtype=self.compute_dtype)(
             attn.reshape(b, s, self.dim)

@@ -1,358 +1,598 @@
-"""Flash attention as a Pallas TPU kernel — the hot-op fast path.
+"""Block attention as Pallas TPU kernels — a block pair's tiles never
+leave VMEM.
 
-The pure-XLA :func:`tpfl.parallel.ring_attention.blockwise_attention`
-is correct and fuses well; this kernel goes further: the online-softmax
-accumulators for one query block live in VMEM scratch across the whole
-K/V sweep (K/V stream through VMEM one block at a time — sequence
-length is bounded by HBM, not by the ~16 MB VMEM), and the score
-matmuls run on the MXU.
+The kernels behind :func:`tpfl.parallel.ring_attention.blockwise_attention`
+on a TPU (its XLA block loop is the path everywhere else, and what the
+kernels are tested against), behind :func:`flash_attention` and behind
+the flash ring's per-step inner. One family, static shapes differ:
+grouped query rows (``rows = groups * block`` query rows a key block, a
+key head's query heads side by side), a value width of its own, equal
+heads as the case ``groups = 1``.
 
-Grid: (batch·heads, query blocks, key blocks) — TPU executes the last
-grid dimension sequentially on the same core, so scratch carries the
-running (acc, max, denom) between key blocks; the first key block
-initializes them and the last one writes the output block. Causal
-programs above the diagonal skip all work via ``pl.when``.
+Layout. The kernels index ``[B, S', H, D]`` as ``[B, S', H * D]``, and
+a program instance takes the fewest heads whose lanes are whole
+128-lane tiles (two 64-wide heads; one 128-wide head): no operand is
+transposed, and D = 64 is not padded to 128. That reshape is free only
+in the LOGICAL layout: on a TPU a ``[.., 12, 64]`` array is tiled over
+its last two dimensions, so where XLA cannot fold the reshape away it
+is a copy. A caller that reshapes ``[.., H * D]`` to heads itself pays
+nothing (the two reshapes fold: the zoo block splits its joint
+projection first and names the heads after, PERF.md §6 PR 30); one that
+hands over slices of a ``[.., 3 * H, D]`` array paid 4.3 ms a round on
+GPT-2. Inside, a head whose lanes are not whole tiles is taken by
+ZEROING the other heads' lanes of the resident operand: the contraction then adds exact
+zeros, and a 64-wide head half-fills the 128 x 128 MXU either way.
+``jax.vmap`` (the engine's silos) becomes one more grid dimension, so
+no tile size depends on how many silos share the chip.
 
-Training: ``flash_attention`` carries a ``jax.custom_vjp`` with the
-standard recompute-based flash backward (Dao et al.): the forward
-additionally banks the per-query logsumexp L; the backward recomputes
-P = exp(S - L) tile by tile and runs two kernels — dQ (query-block
-grid, key sweep) and dK/dV (key-block grid, query sweep) — all matmuls
-on the MXU, no S-sized tensor ever materialized in HBM.
+Both kernels hold a pair's tile as ``[block, rows]``, keys on sublanes
+and queries on lanes: a query's scalars (max, denominator, lse, delta)
+are then ROWS, stored and read as ``[.., H, S']`` has them, and no
+tile-sized operand is ever transposed but ``dS`` for ``dQ``.
 
-``flash_attention`` interprets on CPU (tests) and compiles on TPU.
+- forward, grid ``(batch, head block, query block, key sweep)``: the
+  scores, P and the running ``acc^T`` / max / denominator of one query
+  block live in VMEM across the sweep (the last grid dimension runs in
+  order on one core); ``(P V)^T = V^T P^T`` transposes the small
+  operand, and the output block is transposed once, when it is written.
+- backward, grid ``(batch, head block, key block, query sweep)``: ONE
+  sweep (FlashAttention-2's): S, P = exp(S - lse), dP and dS of a pair
+  are computed once and feed dQ, dK and dV — 5 tile matmuls. dK / dV
+  accumulate in VMEM over the sweep; dQ accumulates in a float32 VMEM
+  buffer of the WHOLE sequence (one head block's: 8 MB at 8k tokens
+  with two query heads a key head), written once when the head block
+  is done — no float32 dq in HBM. (Measured against a dQ kernel over a
+  key sweep plus a dK / dV kernel over a query sweep, 7 matmuls: one
+  sweep is 16% faster a call at both cells' shapes, PERF.md §6 PR 30.)
+
+Causal pairs above the diagonal are neither computed nor fetched (the
+index maps clamp to the diagonal, so the block index does not change
+and nothing is copied; ``pl.when`` skips the body); the mask is built
+on pairs that touch the diagonal only. Numerics are the block loop's:
+P and dS are rounded to the inputs' dtype for their matmuls alone;
+accumulators, max, denominator, lse and delta are float32; float32
+inputs compute in float32 throughout (on a TPU at the MXU's default
+precision, like XLA's own float32 matmuls: 0.7% of the largest element
+against a dense float32 softmax on the v5e, 5e-7 on the CPU). The
+softmax scale is folded into the resident operand where that is exact
+(a power of two), and multiplies the float32 scores where it is not.
+
+Interprets on the CPU (tests), compiles on a TPU.
 """
 
 from __future__ import annotations
 
 import functools
+import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from tpfl.parallel import compat
 
-_NEG_INF = -1e30  # large-negative instead of -inf: exp() stays exact, no NaNs
+F32 = jnp.float32
+LANES = 128
+_NEG = -1e30  # large-negative instead of -inf: exp() stays exact, no NaNs
+_VMEM_LIMIT = 100 * 1024 * 1024
+#: What the backward's resident dq of a head block, the WHOLE sequence's,
+#: may take of it: its float32 scratch and its output block, which the
+#: pipeline double-buffers in the gradients' dtype. With bf16 gradients
+#: that is 64k grouped query rows of a 128-lane head block (a 32 MiB
+#: scratch), with float32 ones two thirds of that; the rest of the limit
+#: is the pair's operands and tiles.
+_DQ_BYTES = 64 * 1024 * 1024
+#: The largest float32 ``[block, rows]`` tile admitted: ``[1024, 1024]``,
+#: the largest compiled for the described v5e (``flash_8k``; the cells
+#: run ``[512, 512]`` and ``[512, 1024]``). A pair holds four such tiles.
+_TILE_BYTES = 4 * 1024 * 1024
+#: The kernels' names in a device trace (``attention_kernel_share_pct``).
+FORWARD, BACKWARD = "block_attention_forward", "block_attention_backward"
+
+_NT = ((1,), (1,))  # a @ b^T
+_NN = ((1,), (0,))  # a @ b
+_TN = ((0,), (0,))  # a^T @ b
 
 
-def _mm(a, b, dims):
-    """MXU matmul at the operands' NATIVE dtype with f32 accumulation.
-    bf16 inputs run the MXU at full rate; upcasting them to f32 first
-    (the r4 kernels did) runs every score/grad matmul at the f32 rate —
-    several times slower — for precision the f32 accumulator already
-    provides. f32 inputs (exactness tests) still compute fully in f32."""
-    if a.dtype != b.dtype:  # ring bwd: f32 cotangents, bf16 operands
-        wide = jnp.promote_types(a.dtype, b.dtype)
-        a, b = a.astype(wide), b.astype(wide)
-    return jax.lax.dot_general(
-        a, b, (dims, ((), ())), preferred_element_type=jnp.float32
+def _dot(a, b, dims):
+    """MXU matmul on the operands' own dtype with a float32 accumulator:
+    bf16 operands run the MXU at full rate, float32 operands (the
+    exactness tests) compute in float32."""
+    return lax.dot_general(
+        a, b, (dims, ((), ())), preferred_element_type=F32
     )
 
 
-def _lowp(ref):
-    """The dtype f32 intermediates must be cast back to before feeding
-    the next matmul: the ref's native dtype when it is low-precision
-    (bf16 path — the standard flash recipe rounds P/dS to bf16), f32
-    otherwise."""
-    return ref.dtype if ref.dtype == jnp.bfloat16 else jnp.float32
+class _Plan(NamedTuple):
+    """The static shapes of one call."""
+
+    heads: int  # heads a program instance
+    d: int
+    d_v: int
+    block: int
+    groups: int
+    n_blocks: int
+    causal: bool
+    s_len: int
+    scale: float
+    fold: bool  # the scale is a power of two: folded into an operand
+
+    @property
+    def rows(self) -> int:
+        return self.groups * self.block
+
+    @property
+    def masked_last(self) -> bool:
+        """Not causal, and the last key block holds padding."""
+        return not self.causal and self.n_blocks * self.block > self.s_len
+
+    @property
+    def pre(self) -> float:
+        """What the resident key-side operand (q forward, k backward) is
+        multiplied by: the scale where folding it is exact."""
+        return self.scale if self.fold else 1.0
+
+    def own_tiles(self, width: int) -> bool:
+        """A head ``width`` lanes wide is whole lane tiles of its own
+        within the instance's block (else it shares one with others)."""
+        return self.heads == 1 or width % LANES == 0
+
+    def operand_width(self, width: int) -> int:
+        """Lanes of one head's matmul operand: its own, or the block's."""
+        return width if self.own_tiles(width) else self.heads * width
 
 
-def _fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-    *, block: int, causal: bool, scale: float,
+def _heads_per_instance(h: int, d: int, d_v: int) -> int:
+    """The fewest heads, a divisor of ``h``, whose key and value lanes
+    are whole 128-lane tiles; all of them where none is (a block as wide
+    as the array is always legal)."""
+    for n in range(1, h + 1):
+        if h % n == 0 and n * d % LANES == 0 and n * d_v % LANES == 0:
+            return n
+    return h
+
+
+def _plan(k, v, causal, block, s_len, groups) -> _Plan:
+    _, sp, h, d = k.shape
+    scale = 1.0 / math.sqrt(d)
+    return _Plan(
+        heads=_heads_per_instance(h, d, v.shape[-1]), d=d, d_v=v.shape[-1],
+        block=block, groups=groups, n_blocks=sp // block, causal=causal,
+        s_len=s_len, scale=scale, fold=math.log2(scale).is_integer(),
+    )
+
+
+def tiles(
+    k_shape: tuple, d_v: int, block: int, groups: int = 1,
+    grad_bytes: int = 2,
+) -> bool:
+    """Whether the kernels take padded keys of ``k_shape`` on a TPU —
+    only what was compiled for one (``tests/test_chip_compile.py``): a
+    block that is whole 128-lane tiles (lse and delta are stored with
+    the sequence on lanes), a float32 score tile within ``_TILE_BYTES``,
+    and a head block's dq for the whole sequence — float32 scratch plus
+    the double-buffered output block, ``grad_bytes`` an element — within
+    ``_DQ_BYTES`` of VMEM. Everything else is the XLA loop's, as it was
+    before the kernels: a short or unaligned sequence that is one block
+    of its own length, blocks of thousands of rows, a million tokens."""
+    _, sp, h, d = k_shape
+    rows = groups * block
+    dq_elements = groups * sp * _heads_per_instance(h, d, d_v) * d
+    return (
+        block % LANES == 0
+        and 4 * block * rows <= _TILE_BYTES
+        and (4 + 2 * grad_bytes) * dq_elements <= _DQ_BYTES
+    )
+
+
+# --- a head's lanes within a block of several heads ------------------------
+
+
+def _lanes_of(h: int, width: int, shape: tuple):
+    lane = lax.broadcasted_iota(jnp.int32, shape, 1)
+    return (lane >= h * width) & (lane < (h + 1) * width)
+
+
+def _resident(plan: _Plan, x, h: int, width: int, pre: float = 1.0):
+    """Head ``h``'s operand from a block that stays for a whole sweep,
+    times ``pre``: its own lanes where they are whole tiles, else the
+    block with the other heads' lanes zeroed (module docstring)."""
+    if plan.own_tiles(width):
+        x = x[:, h * width:(h + 1) * width]
+        return x if pre == 1.0 else (x.astype(F32) * pre).astype(x.dtype)
+    wide = x.astype(F32) * pre
+    return jnp.where(_lanes_of(h, width, x.shape), wide, 0.0).astype(x.dtype)
+
+
+def _streamed(plan: _Plan, x, h: int, width: int):
+    """Head ``h``'s operand from a block that changes every step: its
+    own lanes where they are whole tiles, else the block as it is (the
+    resident operand it meets has the other heads zeroed)."""
+    if plan.own_tiles(width):
+        return x[:, h * width:(h + 1) * width]
+    return x
+
+
+def _add(plan: _Plan, ref, h: int, width: int, value) -> None:
+    """``ref[:, lanes of h] += value``. ``value`` is ``width`` wide where
+    a head has its own tiles, else as wide as the block with junk in the
+    other heads' lanes, which a select keeps out."""
+    if plan.own_tiles(width):
+        ref[:, h * width:(h + 1) * width] += value
+        return
+    old = ref[...]
+    ref[...] = jnp.where(_lanes_of(h, width, old.shape), old + value, old)
+
+
+def _visible(plan: _Plan, j):
+    """Which (key, query) of a masked pair's ``[block, rows]`` tile is
+    seen: on the diagonal of a causal call ``q >= k`` (a group's heads
+    are ``groups`` runs of the block's positions along the lanes), in
+    the last key block of a padded non-causal one ``k < s_len``."""
+    one = (plan.block, plan.block)
+    k_pos = lax.broadcasted_iota(jnp.int32, one, 0)
+    if plan.causal:
+        seen = lax.broadcasted_iota(jnp.int32, one, 1) >= k_pos
+    else:
+        seen = j * plan.block + k_pos < plan.s_len
+    # (tiled as int32: the compiler concatenates no masks)
+    return jnp.concatenate([seen.astype(jnp.int32)] * plan.groups, axis=1) > 0
+
+
+def _on_pairs(plan: _Plan, i, j, last, pair) -> None:
+    """Run ``pair(masked)`` on the visible pairs of query block ``i`` and
+    key block ``j``, with the mask where a pair needs one."""
+    if plan.causal:
+        pl.when(j < i)(functools.partial(pair, False))
+        pl.when(j == i)(functools.partial(pair, True))
+    elif plan.masked_last:
+        pl.when(j < last)(functools.partial(pair, False))
+        pl.when(j == last)(functools.partial(pair, True))
+    else:
+        pair(False)
+
+
+# --- kernels -----------------------------------------------------------------
+
+
+def _forward_kernel(
+    q_ref, k_ref, v_ref, o_ref, lse_ref, qh_scr, acc_scr, m_scr, l_scr,
+    *, plan: _Plan,
 ):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    i, j = pl.program_id(2), pl.program_id(3)
+    last = pl.num_programs(3) - 1
+    lowp = q_ref.dtype
+    d_v = plan.d_v
 
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+    @pl.when(j == 0)
+    def _():
+        q = q_ref[...]
+        for h in range(plan.heads):
+            qh_scr[h] = _resident(plan, q, h, plan.d, plan.pre)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        m_scr[...] = jnp.full_like(m_scr, _NEG)
+        l_scr[...] = jnp.zeros_like(l_scr)
 
-    run = (qi >= ki) if causal else (ki >= 0)
+    def pair(masked: bool):
+        k, v = k_ref[...], v_ref[...]
+        seen = _visible(plan, j) if masked else None
+        # [block, rows]: keys x queries, so a query's max and sum run down
+        # the sublanes and its scalars lie along the lanes. Every head's
+        # scores first: the next head's matmul then runs beside this
+        # head's vector work (measured, PERF.md PR 30: -7% a call).
+        scores = [
+            _dot(_streamed(plan, k, h, plan.d), qh_scr[h], _NT)
+            for h in range(plan.heads)
+        ]
+        for h, s in enumerate(scores):
+            if not plan.fold:
+                s = s * plan.scale
+            if masked:
+                s = jnp.where(seen, s, _NEG)
+            m_prev, l_prev = m_scr[h:h + 1, :], l_scr[h:h + 1, :]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_scr[h:h + 1, :] = l_prev * corr + jnp.sum(p, axis=0, keepdims=True)
+            m_scr[h:h + 1, :] = m_new
+            # (P V)^T = V^T P^T: the small operand is the transposed one,
+            # and a head's values are ROWS of the result.
+            pv = _dot(_streamed(plan, v, h, d_v), p.astype(lowp), _TN)
+            if not plan.own_tiles(d_v):
+                pv = pv[h * d_v:(h + 1) * d_v]
+            at = slice(h * d_v, (h + 1) * d_v)
+            acc_scr[at, :] = acc_scr[at, :] * corr + pv
 
-    @pl.when(run)
-    def _attend():
-        # Native-dtype operands on the MXU, f32 scores out (_mm); the
-        # scale folds into the f32 scores, not the (possibly bf16) q.
-        s = _mm(q_ref[0], k_ref[0], ((1,), (1,))) * scale  # [block, block]
-        if causal:
-            q_pos = qi * block + jax.lax.broadcasted_iota(
-                jnp.int32, (block, block), 0
+    _on_pairs(plan, i, j, last, pair)
+
+    @pl.when(j == last)
+    def _():
+        for h in range(plan.heads):
+            m, l = m_scr[h:h + 1, :], l_scr[h:h + 1, :]
+            at = slice(h * d_v, (h + 1) * d_v)
+            acc_scr[at, :] = acc_scr[at, :] / jnp.maximum(l, 1e-30)
+            # Rows with no visible key get +LARGE: the backward's
+            # exp(s - lse) is exactly 0 for them.
+            lse_ref[h:h + 1, :] = jnp.where(
+                l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), 1e30
             )
-            k_pos = ki * block + jax.lax.broadcasted_iota(
-                jnp.int32, (block, block), 1
-            )
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        m_prev = m_ref[:, :1]  # [block, 1]
-        l_prev = l_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        acc_ref[:] = acc_ref[:] * corr + _mm(
-            p.astype(_lowp(v_ref)), v_ref[0], ((1,), (0,))
-        )
-        m_ref[:, :1] = m_new
-        l_ref[:, :1] = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-
-    @pl.when(ki == nk - 1)
-    def _finalize():
-        denom = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0] = (acc_ref[:] / denom).astype(o_ref.dtype)
-        # Per-query logsumexp (the flash backward's softmax residual),
-        # broadcast across the 8-lane trailing dim — mosaic requires
-        # block dims (8k, 128m) or dims equal to the array's, so scalar
-        # rows are stored 8 lanes wide (see _flash_fwd_impl).
-        lse = m_ref[:, :1] + jnp.log(jnp.maximum(l_ref[:, :1], 1e-30))
-        lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
+        o_ref[...] = jnp.transpose(acc_scr[...]).astype(o_ref.dtype)
 
 
-def _dq_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dq_ref, dq_acc_ref,
-    *, block: int, causal: bool, scale: float,
+def _backward_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
+    kh_scr, vh_scr, dk_scr, dv_scr, dq_scr, *, plan: _Plan,
 ):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    j, i = pl.program_id(2), pl.program_id(3)
+    last_j, last_i = pl.num_programs(2) - 1, pl.num_programs(3) - 1
+    lowp = q_ref.dtype
 
-    @pl.when(ki == 0)
-    def _init():
-        dq_acc_ref[:] = jnp.zeros_like(dq_acc_ref)
+    @pl.when((i == 0) & (j == 0))
+    def _():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    run = (qi >= ki) if causal else (ki >= 0)
+    @pl.when(i == 0)
+    def _():
+        k, v = k_ref[...], v_ref[...]
+        for h in range(plan.heads):
+            kh_scr[h] = _resident(plan, k, h, plan.d, plan.pre)
+            vh_scr[h] = _resident(plan, v, h, plan.d_v)
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    @pl.when(run)
-    def _accumulate():
-        s = _mm(q_ref[0], k_ref[0], ((1,), (1,))) * scale
-        if causal:
-            q_pos = qi * block + jax.lax.broadcasted_iota(
-                jnp.int32, (block, block), 0
-            )
-            k_pos = ki * block + jax.lax.broadcasted_iota(
-                jnp.int32, (block, block), 1
-            )
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse_ref[0][:, :1])  # [blkq, blkk]
-        dp = _mm(do_ref[0], v_ref[0], ((1,), (1,)))
-        ds = p * (dp - dd_ref[0][:, :1])
-        dq_acc_ref[:] += _mm(
-            ds.astype(_lowp(k_ref)), k_ref[0], ((1,), (0,))
-        )
+    def pair(masked: bool):
+        q, do = q_ref[...], do_ref[...]
+        seen = _visible(plan, j) if masked else None
+        at = pl.ds(pl.multiple_of(i * plan.rows, plan.rows), plan.rows)
+        for h in range(plan.heads):
+            q_h = _streamed(plan, q, h, plan.d)
+            do_h = _streamed(plan, do, h, plan.d_v)
+            k_h = kh_scr[h]
+            s = _dot(k_h, q_h, _NT)  # [block, rows]: keys x queries
+            if not plan.fold:
+                s = s * plan.scale
+            p = jnp.exp(s - lse_ref[h:h + 1, :])
+            if masked:
+                p = jnp.where(seen, p, 0.0)
+            dp = _dot(vh_scr[h], do_h, _NT)
+            ds = (p * (dp - delta_ref[h:h + 1, :])).astype(lowp)
+            _add(plan, dv_scr, h, plan.d_v, _dot(p.astype(lowp), do_h, _NN))
+            _add(plan, dk_scr, h, plan.d, _dot(ds, q_h, _NN))
+            # dS^T K: the other heads' lanes of k_h are zero, so are dq's.
+            dq = _dot(ds, k_h, _TN)
+            if plan.own_tiles(plan.d):
+                dq_scr[at, h * plan.d:(h + 1) * plan.d] += dq
+            else:
+                dq_scr[at, :] += dq
 
-    @pl.when(ki == nk - 1)
-    def _finalize():
-        dq_ref[0] = (dq_acc_ref[:] * scale).astype(dq_ref.dtype)
+    _on_pairs(plan, i, j, last_j, pair)
+
+    @pl.when(i == last_i)
+    def _():
+        dk_ref[...] = (dk_scr[...] * plan.scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
+
+    @pl.when((i == last_i) & (j == last_j))
+    def _():
+        post = 1.0 if plan.fold else plan.scale  # k_h carried it
+        dq_ref[...] = (dq_scr[...] * post).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, dd_ref, dk_ref, dv_ref,
-    dk_acc_ref, dv_acc_ref,
-    *, block: int, causal: bool, scale: float,
+# --- calls -------------------------------------------------------------------
+
+
+def _lanes(x):
+    """``[B, S, H, D] -> [B, S, H * D]``. Free in the logical layout;
+    on a TPU an array whose last two dimensions are ``[12, 64]`` is
+    tiled over them, so this is a COPY unless it folds with the
+    caller's own reshape to heads (module docstring)."""
+    return x.reshape(*x.shape[:2], -1)
+
+
+def _params(*semantics: str):
+    return pltpu.CompilerParams(
+        dimension_semantics=semantics, vmem_limit_bytes=_VMEM_LIMIT
+    )
+
+
+def attention_forward(
+    q, k, v, *, causal: bool, block: int, s_len: int, groups: int = 1,
+    interpret: bool = False, out_dtype=None,
 ):
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+    """Padded k ``[B, nb * block, H, D]``, v ``[.., Dv]`` and q ``[B, nb *
+    rows, H, D]`` with ``rows = groups * block`` (the layout of
+    ``ring_attention._blockwise_fwd_core``) -> ``(out [B, nb * rows, H,
+    Dv], lse [B, H, nb * rows] float32)``."""
+    plan = _plan(k, v, causal, block, s_len, groups)
+    b, _, h, _ = k.shape
+    n, rows, heads = plan.n_blocks, plan.rows, plan.heads
+    w, w_v = heads * plan.d, heads * plan.d_v
 
-    @pl.when(qi == 0)
-    def _init():
-        dk_acc_ref[:] = jnp.zeros_like(dk_acc_ref)
-        dv_acc_ref[:] = jnp.zeros_like(dv_acc_ref)
+    def key_block(bi, hi, i, j):
+        return bi, jnp.minimum(i, j) if causal else j, hi
 
-    run = (qi >= ki) if causal else (qi >= 0)
-
-    @pl.when(run)
-    def _accumulate():
-        s = _mm(q_ref[0], k_ref[0], ((1,), (1,))) * scale
-        if causal:
-            q_pos = qi * block + jax.lax.broadcasted_iota(
-                jnp.int32, (block, block), 0
-            )
-            k_pos = ki * block + jax.lax.broadcasted_iota(
-                jnp.int32, (block, block), 1
-            )
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
-        p = jnp.exp(s - lse_ref[0][:, :1])  # [blkq, blkk]
-        # dV_j += P^T @ dO
-        pl_ = p.astype(_lowp(do_ref))
-        dv_acc_ref[:] += _mm(pl_, do_ref[0], ((0,), (0,)))
-        dp = _mm(do_ref[0], v_ref[0], ((1,), (1,)))
-        ds = p * (dp - dd_ref[0][:, :1])
-        # dK_j += scale · dS^T @ Q — scale applied at finalize (the
-        # f32 accumulator), not to the native-dtype q operand.
-        dk_acc_ref[:] += _mm(
-            ds.astype(_lowp(q_ref)), q_ref[0], ((0,), (0,))
-        )
-
-    @pl.when(qi == nq - 1)
-    def _finalize():
-        dk_ref[0] = (dk_acc_ref[:] * scale).astype(dk_ref.dtype)
-        dv_ref[0] = dv_acc_ref[:].astype(dv_ref.dtype)
-
-
-def _prep(x, b, h, s, d, s_pad, d_pad):
-    x = jnp.moveaxis(x, 2, 1).reshape(b * h, s, d)  # [BH, S, D]
-    return jnp.pad(x, ((0, 0), (0, s_pad - s), (0, d_pad - d)))
-
-
-def _unprep(x, b, h, s, d):
-    x = x[:, :s, :d].reshape(b, h, s, d)
-    return jnp.moveaxis(x, 1, 2)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash(q, k, v, causal: bool, block: int, interpret: bool):
-    out, _ = _flash_fwd_impl(q, k, v, causal, block, interpret)
-    return out
-
-
-def _flash_fwd_impl(q, k, v, causal, block, interpret, out_dtype=None):
-    b, s, h, d = q.shape
-    blk = min(block, s)
-    s_pad = -(-s // blk) * blk
-    d_pad = -(-d // 128) * 128
-    qp = _prep(q, b, h, s, d, s_pad, d_pad)
-    kp = _prep(k, b, h, s, d, s_pad, d_pad)
-    vp = _prep(v, b, h, s, d, s_pad, d_pad)
-    nblk = s_pad // blk
     out, lse = pl.pallas_call(
-        functools.partial(
-            _fwd_kernel, block=blk, causal=causal, scale=1.0 / (d**0.5)
-        ),
+        functools.partial(_forward_kernel, plan=plan),
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, s_pad, d_pad), out_dtype or q.dtype),
-            # lse rows are stored 8 lanes wide (col 0 meaningful): a
-            # (1, blk) block of a 2-D array violates mosaic's (8, 128)
-            # tiling rule on real TPUs.
-            jax.ShapeDtypeStruct((b * h, s_pad, 8), jnp.float32),
+            jax.ShapeDtypeStruct((b, n * rows, h * plan.d_v), out_dtype or q.dtype),
+            jax.ShapeDtypeStruct((b, h // heads, heads, n * rows), F32),
         ],
-        grid=(b * h, nblk, nblk),
+        grid=(b, h // heads, n, n),
         in_specs=[
-            pl.BlockSpec((1, blk, d_pad), lambda bhi, qi, ki: (bhi, qi, 0)),
-            pl.BlockSpec((1, blk, d_pad), lambda bhi, qi, ki: (bhi, ki, 0)),
-            pl.BlockSpec((1, blk, d_pad), lambda bhi, qi, ki: (bhi, ki, 0)),
+            pl.BlockSpec((None, rows, w), lambda bi, hi, i, j: (bi, i, hi)),
+            pl.BlockSpec((None, block, w), key_block),
+            pl.BlockSpec((None, block, w_v), key_block),
         ],
         out_specs=[
-            pl.BlockSpec((1, blk, d_pad), lambda bhi, qi, ki: (bhi, qi, 0)),
-            pl.BlockSpec((1, blk, 8), lambda bhi, qi, ki: (bhi, qi, 0)),
+            pl.BlockSpec((None, rows, w_v), lambda bi, hi, i, j: (bi, i, hi)),
+            pl.BlockSpec(
+                (None, None, heads, rows), lambda bi, hi, i, j: (bi, hi, 0, i)
+            ),
         ],
         scratch_shapes=[
-            pltpu.VMEM((blk, d_pad), jnp.float32),  # acc
-            pltpu.VMEM((blk, 128), jnp.float32),  # running max (col 0)
-            pltpu.VMEM((blk, 128), jnp.float32),  # running denom (col 0)
+            pltpu.VMEM((heads, rows, plan.operand_width(plan.d)), q.dtype),
+            pltpu.VMEM((w_v, rows), F32),  # acc^T
+            pltpu.VMEM((heads, rows), F32),  # running max
+            pltpu.VMEM((heads, rows), F32),  # denominator
         ],
+        compiler_params=_params("parallel", "parallel", "parallel", "arbitrary"),
         interpret=interpret,
-    )(qp, kp, vp)
-    return _unprep(out, b, h, s, d), lse
+        name=FORWARD,
+    )(_lanes(q), _lanes(k), _lanes(v))
+    return out.reshape(b, n * rows, h, plan.d_v), lse.reshape(b, h, n * rows)
 
 
-def _flash_fwd(q, k, v, causal, block, interpret):
-    out, lse = _flash_fwd_impl(q, k, v, causal, block, interpret)
+def attention_backward(
+    q, k, v, do, lse, delta, *, causal: bool, block: int, s_len: int,
+    groups: int = 1, interpret: bool = False, grad_dtype=None,
+):
+    """dq, dk, dv of :func:`attention_forward`'s operands from its
+    ``lse``, the cotangent ``do`` and ``delta = rowsum(do * out)`` ``[B,
+    H, nb * rows]`` float32 (both may cover MORE keys than this call
+    sees: the ring's are the whole row's)."""
+    plan = _plan(k, v, causal, block, s_len, groups)
+    b, _, h, _ = k.shape
+    n, rows, heads = plan.n_blocks, plan.rows, plan.heads
+    w, w_v = heads * plan.d, heads * plan.d_v
+
+    def fetched(j, i):
+        """The query block of step (j, i): clamped to the diagonal."""
+        return jnp.maximum(i, j) if causal else i
+
+    def query_block(bi, hi, j, i):
+        return bi, fetched(j, i), hi
+
+    def query_rows(bi, hi, j, i):
+        return bi, hi, 0, fetched(j, i)
+
+    def key_block(bi, hi, j, i):
+        return bi, j, hi
+
+    per_row = pl.BlockSpec((None, None, heads, rows), query_rows)
+    stats = lambda x: x.astype(F32).reshape(b, h // heads, heads, n * rows)  # noqa: E731
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_backward_kernel, plan=plan),
+        out_shape=[
+            jax.ShapeDtypeStruct((b, n * rows, h * plan.d), grad_dtype or q.dtype),
+            jax.ShapeDtypeStruct((b, n * block, h * plan.d), grad_dtype or k.dtype),
+            jax.ShapeDtypeStruct((b, n * block, h * plan.d_v), grad_dtype or v.dtype),
+        ],
+        grid=(b, h // heads, n, n),
+        in_specs=[
+            pl.BlockSpec((None, rows, w), query_block),
+            pl.BlockSpec((None, block, w), key_block),
+            pl.BlockSpec((None, block, w_v), key_block),
+            pl.BlockSpec((None, rows, w_v), query_block),
+            per_row, per_row,
+        ],
+        out_specs=[
+            # One head block's dq for the whole sequence stays in VMEM.
+            pl.BlockSpec((None, n * rows, w), lambda bi, hi, j, i: (bi, 0, hi)),
+            pl.BlockSpec((None, block, w), key_block),
+            pl.BlockSpec((None, block, w_v), key_block),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((heads, block, plan.operand_width(plan.d)), k.dtype),
+            pltpu.VMEM((heads, block, plan.operand_width(plan.d_v)), v.dtype),
+            pltpu.VMEM((block, w), F32),
+            pltpu.VMEM((block, w_v), F32),
+            pltpu.VMEM((n * rows, w), F32),
+        ],
+        compiler_params=_params("parallel", "parallel", "arbitrary", "arbitrary"),
+        interpret=interpret,
+        name=BACKWARD,
+    )(_lanes(q), _lanes(k), _lanes(v), _lanes(do).astype(v.dtype),
+      stats(lse), stats(delta))
+    return dq.reshape(q.shape), dk.reshape(k.shape), dv.reshape(v.shape)
+
+
+# --- entry points ------------------------------------------------------------
+
+
+def _delta(do, out):
+    """``rowsum(dO * O)`` as ``[B, H, S]`` float32: the softmax
+    derivative's correction term."""
+    return jnp.moveaxis(
+        jnp.sum(do.astype(F32) * out.astype(F32), axis=-1), 1, 2
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash(q, k, v, causal: bool, block: int, s_len: int, interpret: bool):
+    return _flash_fwd(q, k, v, causal, block, s_len, interpret)[0]
+
+
+def _flash_fwd(q, k, v, causal, block, s_len, interpret):
+    out, lse = attention_forward(
+        q, k, v, causal=causal, block=block, s_len=s_len, interpret=interpret
+    )
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd_kernels(qp, kp, vp, dop, lse, dd, causal, blk, d_pad,
-                       interpret, dtypes):
-    """The two flash backward pallas calls over PREPPED operands
-    ([BH, S_pad, D_pad]; lse/dd 8-lane wide [BH, S_pad, 8] f32).
-    Shared by the standalone VJP and the ring backward (which supplies
-    a GLOBAL lse/delta covering all ring steps)."""
-    bh, s_pad, _ = qp.shape
-    nblk = s_pad // blk
-    d = dtypes["d"]
-    scale = 1.0 / (d**0.5)
-
-    qkv_spec = pl.BlockSpec((1, blk, d_pad), lambda bhi, i, j: (bhi, i, 0))
-    kv_of_j = pl.BlockSpec((1, blk, d_pad), lambda bhi, i, j: (bhi, j, 0))
-    row_of_i = pl.BlockSpec((1, blk, 8), lambda bhi, i, j: (bhi, i, 0))
-    row_of_j = pl.BlockSpec((1, blk, 8), lambda bhi, i, j: (bhi, j, 0))
-
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, block=blk, causal=causal, scale=scale),
-        out_shape=jax.ShapeDtypeStruct((bh, s_pad, d_pad), dtypes["q"]),
-        grid=(bh, nblk, nblk),  # (BH, query block, key sweep)
-        in_specs=[qkv_spec, kv_of_j, kv_of_j, qkv_spec, row_of_i, row_of_i],
-        out_specs=qkv_spec,
-        scratch_shapes=[pltpu.VMEM((blk, d_pad), jnp.float32)],
-        interpret=interpret,
-    )(qp, kp, vp, dop, lse, dd)
-
-    q_of_j = pl.BlockSpec((1, blk, d_pad), lambda bhi, i, j: (bhi, j, 0))
-    kv_of_i = pl.BlockSpec((1, blk, d_pad), lambda bhi, i, j: (bhi, i, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, block=blk, causal=causal, scale=scale),
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, s_pad, d_pad), dtypes["k"]),
-            jax.ShapeDtypeStruct((bh, s_pad, d_pad), dtypes["v"]),
-        ],
-        grid=(bh, nblk, nblk),  # (BH, key block, query sweep)
-        in_specs=[q_of_j, kv_of_i, kv_of_i, q_of_j, row_of_j, row_of_j],
-        out_specs=[kv_of_i, kv_of_i],
-        scratch_shapes=[
-            pltpu.VMEM((blk, d_pad), jnp.float32),
-            pltpu.VMEM((blk, d_pad), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qp, kp, vp, dop, lse, dd)
-    return dq, dk, dv
-
-
-def _flash_bwd(causal, block, interpret, res, dout):
+def _flash_bwd(causal, block, s_len, interpret, res, do):
     q, k, v, out, lse = res
-    b, s, h, d = q.shape
-    blk = min(block, s)
-    s_pad = -(-s // blk) * blk
-    d_pad = -(-d // 128) * 128
-
-    qp = _prep(q, b, h, s, d, s_pad, d_pad)
-    kp = _prep(k, b, h, s, d, s_pad, d_pad)
-    vp = _prep(v, b, h, s, d, s_pad, d_pad)
-    dop = _prep(dout, b, h, s, d, s_pad, d_pad)
-    op = _prep(out, b, h, s, d, s_pad, d_pad)
-    # D_i = rowsum(dO * O) — the softmax-derivative correction term.
-    # Stored 8 lanes wide like lse (mosaic tiling rule).
-    dd = jnp.sum(dop.astype(jnp.float32) * op.astype(jnp.float32), axis=-1)
-    dd = jnp.broadcast_to(dd[..., None], (*dd.shape, 8))
-    # lse pad rows: 0 is safe — their dO rows are zero, so every term
-    # they touch (p * 0, ds * 0) vanishes before it reaches real rows.
-
-    dq, dk, dv = _flash_bwd_kernels(
-        qp, kp, vp, dop, lse, dd, causal, blk, d_pad, interpret,
-        {"q": q.dtype, "k": k.dtype, "v": v.dtype, "d": d},
-    )
-    return (
-        _unprep(dq, b, h, s, d),
-        _unprep(dk, b, h, s, d),
-        _unprep(dv, b, h, s, d),
+    return attention_backward(
+        q, k, v, do, lse, _delta(do, out), causal=causal, block=block,
+        s_len=s_len, interpret=interpret,
     )
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+def flash_attention(
+    q: jnp.ndarray,
+    k: jnp.ndarray,
+    v: jnp.ndarray,
+    causal: bool = False,
+    block: int = 1024,
+    interpret: bool | None = None,
+) -> jnp.ndarray:
+    """The kernels by name, differentiable: q/k/v ``[B, S, H, D]`` ->
+    ``[B, S, H, D]``, on any backend (``interpret=None``: compiled on a
+    TPU, the emulator elsewhere). What
+    :func:`~tpfl.parallel.ring_attention.blockwise_attention` runs on a
+    TPU by itself, with the block chosen here.
+
+    ``block``: the key block and, with equal heads, the query block; a
+    shorter sequence is one block, a longer one is padded to a multiple
+    (pad keys are masked, pad rows dropped). 1024 suits H = 8, D = 128
+    at 8k and more (pre-PR-1 rates, not re-measured:
+    ``docs/perf_attention.md``); the benchmark's cells run 512 through
+    ``blockwise_attention``."""
+    interpret = compat.pallas_interpret(interpret)
+    s = q.shape[1]
+    blk = min(block, s)
+    pad = -s % blk
+    fits = tiles(
+        (0, s + pad, *k.shape[2:]), v.shape[-1], blk,
+        grad_bytes=q.dtype.itemsize,
+    )
+    if not (interpret or fits):
+        from tpfl.parallel.ring_attention import blockwise_attention
+
+        return blockwise_attention(q, k, v, causal=causal, block_size=blk)
+    if pad:
+        q, k, v = (
+            jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0))) for x in (q, k, v)
+        )
+    return _flash(q, k, v, causal, blk, s, interpret)[:, :s]
+
+
 def ring_block_size(s: int, block: int) -> int:
-    """Largest kernel block ≤ ``block`` that tiles ``s`` exactly — ring
-    steps need s_pad == s (an off-diagonal ring step is FULL attention;
-    unmasked pad keys would corrupt it). Multiples of 8 keep mosaic's
-    (8, 128) tiling rule; if none divides, a single s-sized block
-    (block dims equal to array dims) is always legal."""
+    """Largest kernel block <= ``block`` that tiles ``s`` exactly — ring
+    steps need no padding (an off-diagonal ring step is FULL attention
+    over the step's keys). Multiples of 128 keep the kernels' tiling; if
+    none divides, one s-sized block is always legal."""
     if s <= block:
         return s
-    blk = (min(block, s) // 8) * 8
-    while blk >= 8 and s % blk:
-        blk -= 8
-    return blk if blk >= 8 and s % blk == 0 else s
-
-
-def _rows_to_lanes(x: jnp.ndarray) -> jnp.ndarray:
-    """[B, H, S] f32 per-row scalars -> the kernels' 8-lane-wide
-    [BH, S, 8] layout (mosaic tiling rule, see _fwd_kernel)."""
-    b, h, s = x.shape
-    x = x.reshape(b * h, s).astype(jnp.float32)
-    return jnp.broadcast_to(x[..., None], (b * h, s, 8))
+    blk = (block // LANES) * LANES
+    while blk >= LANES and s % blk:
+        blk -= LANES
+    return blk if blk >= LANES and s % blk == 0 else s
 
 
 def flash_block_fwd(
@@ -363,21 +603,16 @@ def flash_block_fwd(
     block: int = 1024,
     interpret: bool | None = None,
 ):
-    """One flash forward over a (q-block, kv-block) pair, returning
-    ``(out, lse)`` with lse as [B, H, S] f32 — the building block of
-    ring attention's per-step inner (the ring merges steps by
-    logsumexp, so it needs the softmax residual, not just the output).
-    Not differentiable on its own: the ring defines its own VJP."""
-    interpret = compat.pallas_interpret(interpret)
-    b, s, h, d = q.shape
-    blk = ring_block_size(s, block)
-    # f32 out: the ring merges steps at f32 — a per-step downcast to
-    # q.dtype would round every block before the logsumexp rescale.
-    out, lse8 = _flash_fwd_impl(
-        q, k, v, causal, blk, interpret, out_dtype=jnp.float32
+    """One forward over a (q-block, kv-block) pair of the ring,
+    returning ``(out float32, lse [B, H, S] float32)`` — the ring merges
+    steps by logsumexp at float32, so it needs the softmax residual and
+    an output no step has rounded. Not differentiable on its own: the
+    ring defines its own VJP."""
+    s = q.shape[1]
+    return attention_forward(
+        q, k, v, causal=causal, block=ring_block_size(s, block), s_len=s,
+        interpret=compat.pallas_interpret(interpret), out_dtype=F32,
     )
-    lse = lse8[:, :s, 0].reshape(b, h, s)
-    return out, lse
 
 
 def flash_block_bwd(
@@ -391,61 +626,15 @@ def flash_block_bwd(
     block: int = 1024,
     interpret: bool | None = None,
 ):
-    """Flash backward for one (q-block, kv-block) pair with EXTERNAL
-    softmax residuals: ``lse``/``delta`` are [B, H, S] f32 computed
-    over the FULL attention row (all ring steps), so per-step
-    contributions recomputed here sum exactly to the global gradient.
-    Returns (dq, dk, dv) in the operands' dtypes."""
-    interpret = compat.pallas_interpret(interpret)
-    b, s, h, d = q.shape
-    blk = ring_block_size(s, block)
-    d_pad = -(-d // 128) * 128
-    qp = _prep(q, b, h, s, d, s, d_pad)
-    kp = _prep(k, b, h, s, d, s, d_pad)
-    vp = _prep(v, b, h, s, d, s, d_pad)
-    dop = _prep(do, b, h, s, d, s, d_pad)
-    # f32 grads out: per-step contributions sum in the ring's f32
-    # accumulators; rounding each to the operand dtype first would
-    # compound across steps.
-    dq, dk, dv = _flash_bwd_kernels(
-        qp, kp, vp, dop, _rows_to_lanes(lse), _rows_to_lanes(delta),
-        causal, blk, d_pad, interpret,
-        {"q": jnp.float32, "k": jnp.float32, "v": jnp.float32, "d": d},
+    """The backward for one (q-block, kv-block) pair of the ring with
+    EXTERNAL softmax residuals: ``lse`` / ``delta`` are ``[B, H, S]``
+    float32 over the FULL attention row (all ring steps), so the
+    per-step contributions recomputed here sum exactly to the global
+    gradient. Returns float32 (dq, dk, dv): they sum in the ring's
+    float32 accumulators, and rounding each step first would compound."""
+    s = q.shape[1]
+    return attention_backward(
+        q, k, v, do, lse, delta, causal=causal,
+        block=ring_block_size(s, block), s_len=s,
+        interpret=compat.pallas_interpret(interpret), grad_dtype=F32,
     )
-    return (
-        _unprep(dq, b, h, s, d),
-        _unprep(dk, b, h, s, d),
-        _unprep(dv, b, h, s, d),
-    )
-
-
-def flash_attention(
-    q: jnp.ndarray,
-    k: jnp.ndarray,
-    v: jnp.ndarray,
-    causal: bool = False,
-    block: int = 1024,
-    interpret: bool | None = None,
-) -> jnp.ndarray:
-    """Pallas flash attention, differentiable. q/k/v: [B, S, H, D] ->
-    [B, S, H, D]. Backward is the recompute-based flash VJP (two Pallas
-    kernels); gradients match the XLA blockwise path (tested).
-
-    ``block``: 1024 on v5e for H=8, D=128 (the [block, block] f32
-    score tile then fills VMEM well; 2048 exceeds it and fails to
-    compile; its rates are pre-PR-1, not re-measured:
-    ``docs/perf_attention.md``). Shorter sequences are clamped to
-    ``min(block, S)``.
-
-    Non-causal with a sequence that doesn't divide ``block`` falls back
-    to the XLA blockwise path (pad keys would need extra masking; the
-    causal mask already excludes the high-position pad keys)."""
-    interpret = compat.pallas_interpret(interpret)
-    b, s, h, d = q.shape
-    blk = min(block, s)
-    s_pad = -(-s // blk) * blk
-    if not causal and s_pad != s:
-        from tpfl.parallel.ring_attention import blockwise_attention
-
-        return blockwise_attention(q, k, v, causal=False, block_size=blk)
-    return _flash(q, k, v, causal, block, interpret)

@@ -32,7 +32,7 @@ from tpfl.parallel.compat import shard_map
 
 def _mm(spec: str, a, b):
     """A tile matmul on its operands in the dtype they arrive in, with a
-    float32 accumulator (``flash_kernel._mm``'s rule): float32 copies of
+    float32 accumulator (``flash_kernel._dot``'s rule): float32 copies of
     bf16 operands buy no precision — at default precision the MXU
     multiplies float32 operands in bf16 anyway. float32 operands (the
     exactness tests) compute in float32 throughout."""
@@ -104,11 +104,24 @@ def blockwise_attention(
     forward banks only the output and per-row logsumexp; the backward
     re-derives P = exp(S - lse) block by block in ONE sweep that visits
     each visible block pair once and feeds dq, dk and dv from the same
-    P and dS (FlashAttention-2's VJP at the XLA level,
-    ``_blockwise_vjp_bwd``), a few key heads a step. Reverse-mode
-    through the forward's scan would instead stash O(S·block) score
-    residuals per step, which at 32k tokens produced a program the TPU
-    compiler could not build (pre-PR-1, not re-measured).
+    P and dS (FlashAttention-2's VJP). Reverse-mode through the
+    forward's scan would instead stash O(S·block) score residuals per
+    step, which at 32k tokens produced a program the TPU compiler could
+    not build (pre-PR-1, not re-measured).
+
+    **Two forms of the one block loop**, chosen by what can be observed
+    (``_kernels``): on a TPU, without a band, at blocks that are whole
+    128-lane tiles (``flash_kernel.tiles``: only what was compiled for a
+    TPU), it runs as the Pallas kernels of
+    :mod:`tpfl.parallel.flash_kernel` — a pair's score tile, P, dP, dS
+    and the running accumulators stay in VMEM; no operand is transposed
+    or padded. Everywhere else — a short sequence that is one block of
+    its own unaligned length too — and with ``window`` set, it is the
+    XLA loop below (``_blockwise_fwd_core`` /
+    ``_blockwise_vjp_bwd``, a few key heads a backward step), which is
+    also what the kernels are tested against. Both run under the named
+    scope ``block_attention``; padding, the grouped-row layout and what
+    the forward banks are the same.
 
     The tile matmuls (``P V``, ``dO V^T``, ``dS K``, ``dS^T Q``,
     ``P^T dO``) read P and dS rounded to the inputs' dtype beside q, k,
@@ -175,6 +188,19 @@ def _query_rows(local_idx, groups: int):
     return jnp.tile(local_idx, groups) if groups > 1 else local_idx
 
 
+def _kernels(k, v, block: int, groups: int, window) -> bool:
+    """Whether the block loop runs as the Pallas kernels of
+    :mod:`tpfl.parallel.flash_kernel`: on a TPU, without a band (the
+    kernels have none), at the shapes they were compiled for."""
+    if window is not None or not compat.on_tpu():
+        return False
+    from tpfl.parallel import flash_kernel
+
+    return flash_kernel.tiles(
+        k.shape, v.shape[-1], block, groups, k.dtype.itemsize
+    )
+
+
 @jax.named_scope("block_attention")
 def _blockwise_fwd_core(
     q, k, v, causal: bool, block: int, s_len: int, groups: int = 1,
@@ -188,6 +214,13 @@ def _blockwise_fwd_core(
     b, sp, h, d = k.shape
     d_v = v.shape[-1]
     n_blocks = sp // block
+    if _kernels(k, v, block, groups, window):
+        from tpfl.parallel import flash_kernel
+
+        return flash_kernel.attention_forward(
+            q, k, v, causal=causal, block=block, s_len=s_len, groups=groups,
+            interpret=compat.pallas_interpret(None),
+        )
     rows = groups * block
     qb = q.reshape(b, n_blocks, rows, h, d)
     kb = k.reshape(b, n_blocks, block, h, d)
@@ -296,13 +329,20 @@ def _blockwise_vjp_bwd(causal, block, s_len, groups, window, res, g):
     d_v = v.shape[-1]
     n_blocks = sp // block
     rows = groups * block
-    heads = _heads_per_step(b, h, rows, block)
-    chunks = h // heads
-    scale = 1.0 / jnp.sqrt(d)
     delta = jnp.moveaxis(
         jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1),
         1, 2,
     )  # [B, H, S']
+    if _kernels(k, v, block, groups, window):
+        from tpfl.parallel import flash_kernel
+
+        return flash_kernel.attention_backward(
+            q, k, v, g, lse, delta, causal=causal, block=block, s_len=s_len,
+            groups=groups, interpret=compat.pallas_interpret(None),
+        )
+    heads = _heads_per_step(b, h, rows, block)
+    chunks = h // heads
+    scale = 1.0 / jnp.sqrt(d)
     qb = q.reshape(b, n_blocks, rows, h, d)
     kb = k.reshape(b, n_blocks, block, h, d)
     vb = v.reshape(b, n_blocks, block, h, d_v)
@@ -434,16 +474,16 @@ def _ring_xla(
 # --- flash ring: Pallas flash kernel per ring step, recompute VJP ---
 #
 # Forward: each ring step attends the local Q block to the rotating
-# K/V block with the Pallas flash kernel (flash_kernel.flash_block_fwd
-# — MXU score matmuls, VMEM-resident online softmax, the block=1024
-# win), and steps are merged by logsumexp:
+# K/V block with the Pallas forward kernel (flash_kernel.flash_block_fwd:
+# the kernels blockwise_attention runs on a TPU, with a float32 output),
+# and steps are merged by logsumexp:
 #   lse' = logaddexp(lse, lse_t);  o' = o·e^{lse-lse'} + o_t·e^{lse_t-lse'}
 # which is exactly the online-softmax accumulation at block
 # granularity. Only (out, lse) carry across steps — no O(lq²) score
 # memory at the XLA level.
 #
 # Backward (jax.custom_vjp): banks just (q, k, v, out, lse); recomputes
-# per-step gradients with the flash backward kernels fed the GLOBAL
+# per-step gradients with the one-sweep backward kernel fed the GLOBAL
 # lse/delta (flash_kernel.flash_block_bwd), re-rotating K/V around the
 # ring. dK/dV contributions accumulate in buffers that rotate WITH
 # their K/V block, so after the full circle each block's gradient
@@ -605,10 +645,11 @@ def ring_attention(
 
     ``impl="auto"`` (default) picks the Pallas flash kernel per ring
     step with a ring-level recompute VJP (see module notes above) on
-    TPU, and the plain einsum inner elsewhere — Pallas interpret mode
-    is an emulator, orders of magnitude slower than XLA at real
-    sequence lengths, so non-TPU backends must not land on it by
-    default. ``impl="flash"`` forces the kernel (interpret-mode off
+    TPU at local blocks the kernels take (``flash_kernel.tiles``: whole
+    128-lane tiles), and the plain einsum inner elsewhere — Pallas
+    interpret mode is an emulator, orders of magnitude slower than XLA
+    at real sequence lengths, so non-TPU backends must not land on it
+    by default. ``impl="flash"`` forces the kernel (interpret-mode off
     TPU — for exactness tests); ``impl="xla"`` forces the einsum inner
     (identical math)."""
     if impl not in ("auto", "flash", "xla"):
@@ -620,7 +661,14 @@ def ring_attention(
             f"got {impl!r}"
         )
     if impl == "auto":
-        impl = "flash" if compat.on_tpu() else "xla"
+        from tpfl.parallel import flash_kernel
+
+        # A ring step's gradients leave the kernel in float32.
+        fits = flash_kernel.tiles(
+            k.shape, v.shape[-1],
+            flash_kernel.ring_block_size(k.shape[1], block), grad_bytes=4,
+        )
+        impl = "flash" if compat.on_tpu() and fits else "xla"
     if impl == "xla":
         return _ring_xla(q, k, v, axis_name, causal)
     return _ring_flash(
