@@ -58,6 +58,11 @@ def described():
         # sixteen blocks, 8 MB of float32 dq resident in VMEM).
         ("block_attention_gpt2_x4", 2, 0),
         ("block_attention_sambay_x2", 2, 0),
+        # Mellum 2's full layer (8 query heads a key head, block 256: a
+        # [256, 2048] tile, 64 MiB of dq) takes the kernels; its experts'
+        # six grouped products a step are the megablox kernels.
+        ("block_attention_mellum_x2", 2, 0),
+        ("grouped_products_mellum_x2", 6, 0),
         # The VMEM guard's edges (``flash_kernel.tiles``): the tallest
         # tile with the longest resident dq, bf16 and float32 gradients.
         ("flash_64k_d128", 2, 0),

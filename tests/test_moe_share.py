@@ -1,0 +1,218 @@
+"""The held-experts layer (``tpfl.parallel.moe.held_experts_moe``): the
+shares of the chips that hold the experts add up to the uncut layer, it
+drops nothing under any imbalance, it batches under ``jax.vmap`` as two
+separate calls, its Pallas form equals its XLA form, the router decides
+in float32, and no product over all held experts is ever formed."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from tpfl.parallel import compat, moe
+
+T, D, F, E, K = 64, 32, 48, 16, 4
+
+
+def _weights(seed=0, silos=None):
+    lead = () if silos is None else (silos,)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (
+        jax.random.normal(ks[0], (*lead, T, D)),
+        jax.random.normal(ks[1], (*lead, D, E)),
+        jax.random.normal(ks[2], (*lead, E, D, 2 * F)) / np.sqrt(D),
+        jax.random.normal(ks[3], (*lead, E, F, D)) / np.sqrt(F),
+    )
+
+
+def _share(x, router, w_in, w_out, first, count, bias=0.0):
+    """The part of the layer experts ``first .. first + count - 1`` give."""
+    gate, expert, _ = moe.route_top_k(x @ router + bias, K)
+    held = slice(first, first + count)
+    return moe.held_experts_moe(x, gate, expert, w_in[held], w_out[held], first)
+
+
+def _uncut(x, router, w_in, w_out, bias=0.0, only=None):
+    """The whole layer, every expert on every token, as the published
+    equations state it (``only``: the experts counted)."""
+    probs = jax.nn.softmax(x @ router + bias, axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, K)
+    gate = top_p / top_p.sum(-1, keepdims=True)
+    weight = jnp.sum(gate[..., None] * (top_e[..., None] == jnp.arange(E)), 1)
+    if only is not None:
+        weight = weight * ((jnp.arange(E) >= only[0]) & (jnp.arange(E) < only[1]))
+    g, u = jnp.split(jnp.einsum("td,edf->tef", x, w_in), 2, axis=-1)
+    each = jnp.einsum("tef,efd->ted", jax.nn.silu(g) * u, w_out)
+    return jnp.einsum("ted,te->td", each, weight)
+
+
+def _close(a, b, tol=2e-5):
+    for u, v in zip(jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)):
+        scale = float(jnp.abs(v).max()) + 1e-30
+        assert float(jnp.abs(u - v).max()) / scale < tol
+
+
+def test_the_four_shares_add_up_to_the_uncut_layer():
+    args = _weights()
+    with jax.default_matmul_precision("highest"):
+        shares = [_share(*args, first, 4) for first in (0, 4, 8, 12)]
+        whole = _uncut(*args)
+    _close(sum(shares), whole)
+    # No share is the whole, and each is its own experts' part.
+    assert float(jnp.abs(shares[0] - whole).max()) > 1e-2
+    with jax.default_matmul_precision("highest"):
+        _close(shares[2], _uncut(*args, only=(8, 12)))
+
+
+@pytest.mark.parametrize(
+    "bias_on, zero",
+    [(range(4, 8), False), (range(5, 9), False), (range(8, 16), True)],
+    ids=["all_choices_held", "three_of_four_held", "none_held"],
+)
+def test_dropless_under_forced_imbalance(bias_on, zero):
+    """Every token forced onto the SAME experts: all its choices held
+    (four rows a token, the row buffer's worst case, on four experts),
+    one expert's worth outside, or none held — then exactly zero."""
+    args = _weights(1)
+    bias = jnp.zeros(E).at[jnp.asarray(list(bias_on))].set(50.0)
+    with jax.default_matmul_precision("highest"):
+        out = _share(*args, 4, 4, bias=bias)
+        want = _uncut(*args, bias=bias, only=(4, 8))
+    if zero:
+        assert float(jnp.abs(out).max()) == 0.0
+    else:
+        _close(out, want)
+    # One held expert takes every token.
+    one = jnp.zeros(E).at[6].set(80.0)
+    with jax.default_matmul_precision("highest"):
+        _close(_share(*args, 4, 4, bias=one), _uncut(*args, bias=one, only=(4, 8)))
+
+
+def _loss(x, router, w_in, w_out, bias=0.0):
+    return jnp.sum(_share(x, router, w_in, w_out, 4, 8, bias=bias) ** 2)
+
+
+@pytest.mark.parametrize("held_bias", [0.0, 50.0, -50.0], ids=["balanced", "all", "none"])
+def test_buffer_past_its_head_is_entered_when_it_is_live(monkeypatch, held_bias):
+    """The layer always works on the head of the row buffer (three
+    eighths of its slots) and enters the rest only when a live row lies
+    there: with every choice of every token held it does, with none held
+    (or few) it does not — value and gradients equal the uncut layer's
+    either way, under vmap (2 x 256 slots, a head of 192)."""
+    monkeypatch.setattr(moe, "_TILE_ROWS", 8)
+    assert moe._head_rows(2 * T * K) == 192
+    args = _weights(5, silos=2)
+    bias = jnp.where((jnp.arange(E) >= 4) & (jnp.arange(E) < 12), held_bias, 0.0)
+    gate, expert, _ = moe.route_top_k(args[0][0] @ args[1][0] + bias, K)
+    live = int(((expert >= 4) & (expert < 12)).sum())
+    assert {0.0: 64 < live < 192, 50.0: live == 256, -50.0: live == 0}[held_bias]
+    grads = (0, 1, 2, 3)
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(jax.vmap(
+            jax.value_and_grad(lambda *a: _loss(*a, bias=bias), argnums=grads)
+        ))(*args)
+        want = jax.vmap(jax.value_and_grad(
+            lambda *a: jnp.sum(_uncut(*a, bias=bias, only=(4, 12)) ** 2),
+            argnums=grads,
+        ))(*args)
+    if held_bias < 0:
+        assert all(float(jnp.abs(g).max()) == 0.0 for g in jax.tree_util.tree_leaves(got))
+    else:
+        _close(got, want, 1e-4)
+
+
+def test_vmap_over_two_silos_equals_two_separate_calls():
+    args = _weights(2, silos=2)
+    grad = jax.value_and_grad(_loss, argnums=(0, 1, 2, 3))
+    with jax.default_matmul_precision("highest"):
+        batched = jax.jit(jax.vmap(grad))(*args)
+        apart = [jax.jit(grad)(*(a[s] for a in args)) for s in range(2)]
+        dense = jax.vmap(jax.value_and_grad(
+            lambda *a: jnp.sum(_uncut(*a, only=(4, 12)) ** 2), argnums=(0, 1, 2, 3)
+        ))(*args)
+    stacked = jax.tree_util.tree_map(lambda a, b: jnp.stack([a, b]), *apart)
+    _close(batched, stacked, 1e-5)
+    _close(batched, dense, 1e-4)  # and both are the published layer's
+    # Experts that are not held get no gradient; the router does.
+    assert float(jnp.abs(batched[1][2][:, :4]).max()) == 0.0
+    assert float(jnp.abs(batched[1][1]).max()) > 0.0
+
+
+def test_pallas_form_equals_the_xla_form(monkeypatch):
+    """The TPU branch (the Pallas grouped-matmul kernels, here in the
+    emulator) against the XLA branch, value and gradients, under vmap:
+    2 silos x 64 tokens x 4 choices = 512 rows, four row tiles."""
+    args = _weights(3, silos=2)
+    grad = jax.vmap(jax.value_and_grad(_loss, argnums=(0, 1, 2, 3)))
+    with jax.default_matmul_precision("highest"):
+        xla = jax.jit(grad)(*args)
+        monkeypatch.setattr(compat, "on_tpu", lambda: True)
+        monkeypatch.setattr(compat, "pallas_interpret", lambda _: True)
+        monkeypatch.setattr(moe, "_TILE_ROWS", 128)
+        assert moe._pallas(2 * T * K)
+        pallas = jax.jit(lambda *a: grad(*a))(*args)
+    _close(pallas, xla, 1e-5)
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+def test_no_product_over_all_held_experts_is_formed():
+    """The layer's products are grouped ones over the row buffer — two
+    forward (gate and up side by side as one, down), five backward (the
+    first made again, two input and two weight gradients); no array carries a token
+    axis AND a held-expert axis, as a dense evaluation of every held
+    expert on every token would."""
+    args = _weights(4)
+    jaxpr = jax.make_jaxpr(jax.grad(_loss, argnums=(0, 2, 3)))(*args).jaxpr
+    eqns = list(_eqns(jaxpr))
+    grouped = [e for e in eqns if e.primitive.name == "ragged_dot_general"]
+    assert len(grouped) == 2 + 5
+    rows = {tuple(v.aval.shape) for e in grouped for v in e.invars[:1]}
+    assert {shape[0] for shape in rows} == {T * K}
+    for eqn in eqns:
+        for var in eqn.outvars:
+            shape = tuple(getattr(var.aval, "shape", ()))
+            assert not (T in shape and 8 in shape and len(shape) >= 3), eqn
+    # Rows move by gather both ways: no scatter-add of hidden-width rows
+    # (top_k's own gradient scatters [T, E] probabilities: not rows).
+    assert not [
+        e for e in eqns if e.primitive.name == "scatter-add"
+        and e.outvars[0].aval.shape[-1] in (D, F, 2 * F)
+    ]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_router_is_float32_whatever_the_compute_dtype(dtype):
+    gate, expert, load = moe.route_top_k(
+        jax.random.normal(jax.random.PRNGKey(0), (T, E)).astype(dtype), K
+    )
+    assert gate.dtype == load.dtype == jnp.float32
+    assert float(jnp.abs(gate.sum(-1) - 1).max()) < 1e-6
+    assert float(load.sum()) == pytest.approx(1.0) and load.shape == (E,)
+    # In the zoo model the router's product has float32 operands at the
+    # highest precision, under a bf16 compute dtype too.
+    from tpfl.models import MellumLM
+
+    model = MellumLM(
+        vocab=32, dim=16, heads=2, kv_heads=1, head_dim=8, n_layers=1,
+        period=1, n_experts=E, top_k=K, expert_dim=8, held_experts=4,
+        compute_dtype=dtype,
+    )
+    tokens = jnp.zeros((1, 8), jnp.int32)
+    variables = model.init(jax.random.PRNGKey(0), tokens)
+    jaxpr = jax.make_jaxpr(lambda v: model.apply(v, tokens))(variables).jaxpr
+    routers = [
+        e for e in _eqns(jaxpr) if e.primitive.name == "dot_general"
+        and tuple(e.outvars[0].aval.shape) == (8, E)
+    ]
+    assert len(routers) == 1
+    assert {v.aval.dtype for v in routers[0].invars} == {jnp.dtype("float32")}
+    assert "HIGHEST" in str(routers[0].params["precision"])

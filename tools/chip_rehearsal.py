@@ -185,7 +185,7 @@ def flash_case(shape, dtype, device) -> tuple:
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2))), (x, x, x)
 
 
-def block_attention_case(silos: int, q, k, v, device) -> tuple:
+def block_attention_case(silos: int, q, k, v, device, block_size=None) -> tuple:
     """(jitted fwd+bwd of ``blockwise_attention`` under the engine's vmap
     over ``silos``, args) for bf16 causal ``q`` / ``k`` / ``v`` shapes: on
     the TPU branch the block loop is two Pallas kernels."""
@@ -193,7 +193,7 @@ def block_attention_case(silos: int, q, k, v, device) -> tuple:
 
     def loss(q, k, v):
         out = jax.vmap(
-            lambda *x: blockwise_attention(*x, causal=True)
+            lambda *x: blockwise_attention(*x, causal=True, block_size=block_size)
         )(q, k, v)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
@@ -203,6 +203,33 @@ def block_attention_case(silos: int, q, k, v, device) -> tuple:
         for shape in (q, k, v)
     )
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2))), args
+
+
+def grouped_products_case(rows: int, groups: int, d: int, f: int, device) -> tuple:
+    """(the six grouped products of one step of the held-experts layer
+    — ``tpfl.parallel.moe``: gate-and-up and down forward, their two
+    input and two weight gradients — jitted, args) on a row buffer of
+    ``rows`` slots split over ``groups`` experts: on the TPU branch six
+    Pallas grouped-matmul kernels."""
+    from tpfl.parallel import moe
+
+    def products(x, hidden, dy, d_gu, w_in, w_out, sizes):
+        return (
+            moe._gmm(x, w_in, sizes), moe._gmm(hidden, w_out, sizes),
+            moe._gmm_nt(dy, w_out, sizes), moe._gmm_nt(d_gu, w_in, sizes),
+            moe._gmm_tn(x, d_gu, sizes), moe._gmm_tn(hidden, dy, sizes),
+        )
+
+    sharding = SingleDeviceSharding(device)
+    sds = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=sharding
+    )
+    args = (
+        sds((rows, d)), sds((rows, f)), sds((rows, d)), sds((rows, 2 * f)),
+        sds((groups, d, 2 * f)), sds((groups, f, d)),
+        sds((groups,), jnp.int32),
+    )
+    return jax.jit(products), args
 
 
 def scan_case(shape, states: int, silos: int, device) -> tuple:
@@ -384,6 +411,19 @@ def cases(devices) -> dict:
         ),
         "block_attention_sambay_x2": lambda: block_attention_case(
             2, (1, 8192, 40, 64), (1, 8192, 20, 64), (1, 8192, 20, 128), d0
+        ),
+        # Mellum 2's full-attention layer in its cell: two 8192-token
+        # sequences a silo, 32 query heads on 4 key heads of 128 — a key
+        # head's block is 8 x 256 rows tall, the resident dq exactly
+        # ``_DQ_BYTES`` — and the experts' grouped products on the head
+        # of the row buffer (three eighths of 2 silos x 16384 tokens x 8
+        # choices), 2 x 16 experts.
+        "block_attention_mellum_x2": lambda: block_attention_case(
+            2, (2, 8192, 32, 128), (2, 8192, 4, 128), (2, 8192, 4, 128), d0,
+            block_size=256,
+        ),
+        "grouped_products_mellum_x2": lambda: grouped_products_case(
+            98304, 32, 2304, 896, d0
         ),
         # The Mamba layer of the benchmark's SambaY cell: one 8192-token
         # sequence a silo, 5120 channels x 16 states, two silos vmapped.

@@ -9,9 +9,10 @@ benchmark ladder (MNIST MLP → CIFAR CNN → ResNet-18), with a
 stay float32.
 """
 
+from tpfl.models.mellum import MellumLM
 from tpfl.models.sambay import SambaYLM
 from tpfl.models.zoo import (CNN, MLP, ResNet18, TransformerBlock,
                              TransformerLM, create_model)
 
 __all__ = ["MLP", "CNN", "ResNet18", "TransformerBlock",
-           "TransformerLM", "SambaYLM", "create_model"]
+           "TransformerLM", "SambaYLM", "MellumLM", "create_model"]

@@ -17,6 +17,7 @@ from jax import lax
 
 from tpfl.learning.model import TpflModel
 from tpfl.models.head_loss import head_cross_entropy
+from tpfl.models.mellum import MellumLM
 from tpfl.models.sambay import SambaYLM
 
 
@@ -262,8 +263,8 @@ def create_model(
     """Initialize a flax module into a :class:`TpflModel`.
 
     ``module`` may be a module instance or a zoo name ("mlp", "cnn",
-    "resnet18", "transformer_lm", "sambay_lm"). ``input_shape`` excludes
-    the batch dimension.
+    "resnet18", "transformer_lm", "sambay_lm", "mellum_lm").
+    ``input_shape`` excludes the batch dimension.
     """
     if isinstance(module, str):
         zoo: dict[str, Callable[..., nn.Module]] = {
@@ -272,6 +273,7 @@ def create_model(
             "resnet18": ResNet18,
             "transformer_lm": TransformerLM,
             "sambay_lm": SambaYLM,
+            "mellum_lm": MellumLM,
         }
         if module not in zoo:
             raise KeyError(f"Unknown model {module!r}; have {sorted(zoo)}")

@@ -1,24 +1,38 @@
-"""Expert parallelism — MoE dispatch with all_to_all over an ``ep``
-mesh axis: top-1 serving dispatch (``make_moe_layer``) and a trainable
-differentiable top-k layer (``make_moe_train_layer``).
+"""Sparse experts: the layer on the engine's path, and the two
+``ep``-axis originals.
 
-Completes the parallelism inventory (dp/FSDP, sp ring attention, pp
-pipeline, federated nodes — and now ep). One expert per device: each
-device routes its local tokens, packs up to ``capacity`` tokens
-per destination expert into a static [n, C, D] dispatch buffer,
-``all_to_all`` swaps buffers so every device receives its expert's
-tokens from all peers, the local expert MLP runs, and a second
-``all_to_all`` returns results to the owning device, which scatters
-them back into token order. Over-capacity tokens pass through on the
-residual path (standard Switch-style dropping).
+**On the engine's path** — :func:`held_experts_moe` (with
+:func:`route_top_k`): a DROPLESS top-k SwiGLU layer that is told which
+experts it holds. The router keeps its published width (all ``E``
+experts) and its ``k`` experts a token; this chip computes the part of
+the result that its ``n_held`` experts give — the (token, choice) pairs
+whose expert is held are sorted by expert, their tokens gathered into a
+row buffer whose static size is the worst case (every choice of every
+token held: ``k`` slots a token), and the SwiGLU products (gate and up
+side by side as one, then down) run as grouped matmuls over the rows
+actually routed here, whatever their split over the experts: nothing is
+dropped and nothing is computed for an expert a token did not choose.
+What the absent experts would add is left out (the model-configs guide's
+cut: the chip's share of an expert-parallel layer, run without its
+exchange). Under the engine's ``jax.vmap`` over silos the batch FOLDS
+into the groups (one row buffer, ``silos x n_held`` groups: no batched
+kernel and no loop over silos); it runs inside ``nn.remat``; its
+backward recomputes its row buffer, so nothing of the buffer's size is
+banked. ``tpfl.models.MellumLM`` is its user; the benchmark cell
+``mellum2_silo_8k`` runs it.
 
-Training (``make_moe_train_layer``): a learnable softmax router picks
-top-k experts; the combine is weighted by renormalized router
-probabilities so the router gets gradients, and a Switch-Transformer
-auxiliary load-balance loss keeps expert traffic even.
+**The ``ep``-axis originals, which no cell and no zoo model runs** —
+``make_moe_layer`` (top-1 serving dispatch) and ``make_moe_train_layer``
+(a trainable top-k layer with a Switch-style load-balance loss): ONE
+expert per device of an ``ep`` mesh axis, tokens packed into a static
+``[n, capacity, D]`` buffer and swapped by ``all_to_all``;
+over-capacity tokens pass through on the residual path (they DROP).
+They stay as the exchange an expert rule on a mesh axis would start
+from (ROADMAP R1).
 
 Static shapes throughout — routing is data-dependent but expressed as
-argsort/segment ops, never shape-changing, so the whole layer jits.
+sorts, gathers and group sizes, never shape-changing, so every layer
+jits.
 """
 
 from __future__ import annotations
@@ -27,10 +41,422 @@ from functools import partial
 from typing import Any, Callable
 
 import jax
-
-from tpfl.parallel.compat import shard_map
 import jax.numpy as jnp
+from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+from tpfl.parallel import compat
+from tpfl.parallel.compat import shard_map
+
+# --- the held-experts layer (the engine's path) -------------------------------
+
+
+def route_top_k(logits: jnp.ndarray, k: int) -> tuple:
+    """``logits [T, E]`` -> ``(gate [T, k], expert [T, k], load [E])``:
+    softmax over ALL ``E`` experts in float32, the ``k`` largest, their
+    probabilities normalised over the ``k`` (``norm_topk_prob``), and the
+    share of the ``T k`` token-choices each expert received."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    top_p, top_e = lax.top_k(probs, k)
+    gate = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    chosen = top_e[..., None] == jnp.arange(logits.shape[-1])
+    load = jnp.sum(chosen, axis=(0, 1), dtype=jnp.float32) / top_e.size
+    return gate, top_e, load
+
+
+# The three grouped products. ``sizes [G]`` splits the leading rows of a
+# row buffer into G consecutive groups; rows past their sum belong to no
+# group: they are not computed, and what a result holds there is
+# UNSPECIFIED (callers mask with ``where``, never by multiplying).
+#
+# Two forms, chosen by what can be observed (``compat.on_tpu``), as the
+# attention block loop's are: on a TPU the Pallas grouped-matmul kernels
+# of ``jax.experimental.pallas.ops.tpu.megablox`` (their grid is the
+# row tiles the groups cover, not the buffer's; and they carry the
+# caller's named scope into a device trace, which XLA's own lowering of
+# ``ragged_dot`` does not: PERF.md §6, PR 32), elsewhere
+# ``lax.ragged_dot``. Neither takes a batch dimension on a TPU: the
+# layer folds a ``vmap`` into the groups instead (``_fold_silos``).
+
+#: Rows a tile of the Pallas kernels, at most (a buffer that is no
+#: multiple takes the largest power of two that divides it, down to a
+#: sublane tile); the other two tile sizes are ``_tile`` of the widths
+#: (2304 -> 768, 896 -> 896, 1792 -> 896: whole divisors, within the
+#: 16 MB of VMEM a kernel gets by default; 1024 rows do not fit it).
+_TILE_ROWS = 512
+
+
+def _tile(width: int) -> int:
+    """The largest multiple of 128 up to 1024 that divides ``width``;
+    ``width`` itself where none does (a block as wide as the array is
+    always legal)."""
+    fits = [t for t in range(128, 1024 + 1, 128) if width % t == 0]
+    return max(fits) if fits else width
+
+
+def _row_tile(rows: int) -> int:
+    """Rows a tile for a buffer of ``rows``: ``_TILE_ROWS`` halved until
+    it divides (0 where not even 8 rows do)."""
+    tile = _TILE_ROWS
+    while tile >= 8 and rows % tile:
+        tile //= 2
+    return tile if tile >= 8 else 0
+
+
+def _pallas(rows: int) -> bool:
+    """Whether the grouped products run as the Pallas kernels: on a TPU,
+    on a row buffer of whole row tiles."""
+    return compat.on_tpu() and _row_tile(rows) > 0
+
+
+def _megablox():
+    """The kernels' module (its package exports a function under the
+    same name)."""
+    import importlib
+
+    return importlib.import_module(
+        "jax.experimental.pallas.ops.tpu.megablox.gmm"
+    )
+
+
+def _gmm(rows, w, sizes):
+    """``rows [M, K]`` x ``w [G, K, N]`` -> ``[M, N]``: every row by its
+    group's matrix, in the rows' dtype (float32 accumulator)."""
+    if _pallas(rows.shape[0]):
+        return _megablox().gmm(
+            rows, w, sizes, rows.dtype,
+            (_row_tile(rows.shape[0]), _tile(w.shape[1]), _tile(w.shape[2])),
+            interpret=compat.pallas_interpret(None),
+        )
+    return lax.ragged_dot(rows, w, sizes, preferred_element_type=rows.dtype)
+
+
+def _gmm_nt(rows, w, sizes):
+    """``rows [M, N]`` x ``w [G, K, N]`` transposed -> ``[M, K]``."""
+    if _pallas(rows.shape[0]):
+        return _megablox().gmm(
+            rows, w, sizes, rows.dtype,
+            (_row_tile(rows.shape[0]), _tile(w.shape[2]), _tile(w.shape[1])),
+            transpose_rhs=True, interpret=compat.pallas_interpret(None),
+        )
+    return lax.ragged_dot_general(
+        rows, w, sizes,
+        lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(((1,), (2,)), ((), ())),
+            lhs_ragged_dimensions=[0], rhs_group_dimensions=[0],
+        ),
+        preferred_element_type=rows.dtype,
+    )
+
+
+def _gmm_tn(rows, grads, sizes):
+    """``rows [M, K]`` transposed x ``grads [M, N]``, group by group ->
+    ``[G, K, N]`` float32: each group's weight gradient."""
+    if _pallas(rows.shape[0]):
+        return _megablox().tgmm(
+            rows.T, grads, sizes, jnp.float32,
+            (_row_tile(rows.shape[0]), _tile(rows.shape[1]), _tile(grads.shape[1])),
+            interpret=compat.pallas_interpret(None),
+        )
+    return lax.ragged_dot_general(
+        rows, grads, sizes,
+        lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(((0,), (0,)), ((), ())),
+            lhs_ragged_dimensions=[0], rhs_group_dimensions=[],
+        ),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _fold_silos(fn: Callable) -> Callable:
+    """``fn(x [T, d], gate [T, k], key [T, k], w_in [G, ..], w_out [G,
+    ..], *token_arrays) -> (token arrays.., group arrays..)`` made
+    batchable by FOLDING the batch into the groups: under ``vmap`` over
+    S silos it is one call on ``S T`` tokens and ``S G`` groups (silo
+    ``s``'s group ``g`` is group ``s G + g``; key ``G``, "not held",
+    becomes ``S G``), so the live rows of all silos lie side by side at
+    the head of ONE row buffer and the grouped products see no batch
+    dimension. A result whose leading size is the tokens' unfolds as a
+    token array, one whose leading size is the groups' as a group
+    array."""
+    folded = jax.custom_batching.custom_vmap(fn)
+
+    @folded.def_vmap
+    def rule(axis_size, in_batched, x, gate, key, w_in, w_out, *more):
+        args = [
+            a if batched else jnp.broadcast_to(a, (axis_size, *a.shape))
+            for a, batched in zip((x, gate, key, w_in, w_out, *more), in_batched)
+        ]
+        x, gate, key, w_in, w_out, *more = args
+        groups = w_in.shape[1]
+        silo = jnp.arange(axis_size, dtype=key.dtype)[:, None, None]
+        key = jnp.where(key < groups, key + silo * groups, axis_size * groups)
+        flat = lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:])  # noqa: E731
+        outs = folded(*map(flat, (x, gate, key, w_in, w_out, *more)))
+        unfolded = tuple(
+            o.reshape(axis_size, o.shape[0] // axis_size, *o.shape[1:])
+            for o in outs
+        )
+        return unfolded, tuple(True for _ in outs)
+
+    return folded
+
+
+#: Positions a block of the counting sort's prefix sums (a triangular
+#: matmul a block, exact in float32).
+_RANK_BLOCK = 512
+
+
+def _plan(key, groups: int) -> tuple:
+    """``key [T, k]`` (a pair's group; ``groups`` = not held) -> (row ->
+    pair ``order``, pair -> row ``pos``, rows a group ``sizes``): the
+    held pairs sorted by group at the head of the row buffer, in token
+    order within a group, the others behind them. ONE sort (``order``);
+    ``pos`` is a counting sort's arithmetic — a pair's row is its
+    group's first row plus the number of earlier pairs of that group, a
+    prefix sum taken block by block as a triangular matmul — where a
+    second sort cost as much as the first."""
+    flat = key.reshape(-1).astype(jnp.int32)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    pairs = flat.shape[0]
+    blocks = -(-pairs // _RANK_BLOCK)
+    onehot = (flat[:, None] == jnp.arange(groups + 1)).astype(jnp.float32)
+    onehot = jnp.pad(onehot, ((0, blocks * _RANK_BLOCK - pairs), (0, 0)))
+    onehot = onehot.reshape(blocks, _RANK_BLOCK, groups + 1)
+    lower = jnp.tril(jnp.ones((_RANK_BLOCK, _RANK_BLOCK), jnp.float32))
+    within = jnp.einsum(
+        "ij,bjg->big", lower, onehot, precision=lax.Precision.HIGHEST
+    )  # inclusive count inside the block
+    totals = within[:, -1, :]
+    before = jnp.cumsum(totals, axis=0) - totals  # pairs in earlier blocks
+    counts = jnp.sum(totals, axis=0)
+    first_row = jnp.cumsum(counts) - counts
+    row = jnp.sum(
+        onehot * (within + before[:, None, :] + first_row - 1.0), axis=-1
+    )
+    pos = row.reshape(-1)[:pairs].astype(jnp.int32)
+    return order, pos, counts[:groups].astype(jnp.int32)
+
+
+def _head_rows(slots: int) -> int:
+    """Slots of the row buffer's HEAD, the part the layer always works
+    on. The held pairs lie sorted at the front of ``slots`` = ``k T``
+    slots, the worst case; everything that is no grouped product
+    (gathers, the gates, their gradients) costs by the SLOT. So the
+    layer splits the buffer in two: a head of three eighths of the
+    slots — one and a half times the load of balanced routing when a
+    quarter of the experts is held — and the rest, which it enters only
+    when a live row lies there (``lax.cond``): as a rule never, always
+    when every choice of every token is held. A small buffer is all
+    head."""
+    if slots <= 8 * _TILE_ROWS:
+        return slots
+    return -(-(3 * slots // 8) // _TILE_ROWS) * _TILE_ROWS
+
+
+def _parts(order, pos, sizes, held) -> tuple:
+    """(whether a live row lies past the head, the buffer's parts): a
+    part is (its slice of ``order``, the rows of each group inside it,
+    and for the way back each pair's row within it with whether the
+    pair is held AND there)."""
+    slots = order.shape[0]
+    head = _head_rows(slots)
+    ends = jnp.cumsum(sizes)
+
+    def part(lo: int, hi: int) -> tuple:
+        inside = lambda edge: jnp.clip(edge, lo, hi)  # noqa: E731
+        at = pos - lo
+        return (
+            order[lo:hi], inside(ends) - inside(ends - sizes),
+            jnp.clip(at, 0, hi - lo - 1), held & (at >= 0) & (at < hi - lo),
+        )
+
+    parts = [part(0, head)] + ([part(head, slots)] if head < slots else [])
+    return ends[-1] > head, parts
+
+
+def _over_parts(overflows, parts: list, work: Callable):
+    """``work(part)`` summed over the buffer's parts, the part past the
+    head entered only when it holds a live row."""
+    total = work(parts[0])
+    if len(parts) == 1:
+        return total
+    rest = lax.cond(
+        overflows, lambda: work(parts[1]),
+        lambda: jax.tree_util.tree_map(jnp.zeros_like, total),
+    )
+    return jax.tree_util.tree_map(jnp.add, total, rest)
+
+
+def _rows_to_tokens(rows, at, here):
+    """``out[t] = sum over a token's k choices of rows[at[t, c]]`` where
+    ``here``: a gather by the pair's row and a masked float32 sum. Both
+    directions of the layer move rows by GATHER (by a row's token one
+    way, by a pair's row the other): a scatter-add of ``k T`` rows,
+    which autodiff of a gather brings, a TPU runs row by row."""
+    t, k = here.shape
+    picked = jnp.take(rows, at.reshape(-1), axis=0).reshape(t, k, rows.shape[-1])
+    return jnp.sum(
+        jnp.where(here[..., None], picked.astype(jnp.float32), 0.0), axis=1
+    )
+
+
+def _expert_rows(x, gate, order, sizes, w_in, k: int):
+    """A part's rows and the experts' hidden rows: (rows [n, d], the
+    router's weight of each row, gate and up products [n, f] each)."""
+    rows = jnp.take(x, order // k, axis=0)
+    row_gate = jnp.take(gate.reshape(-1), order)
+    return rows, row_gate, jnp.split(_gmm(rows, w_in, sizes), 2, axis=-1)
+
+
+@_fold_silos
+def _moe_forward(x, gate, key, w_in, w_out):
+    """(y [T, d], the plan: order and pos [T k])."""
+    groups, k = w_in.shape[0], key.shape[1]
+    f32, dtype = jnp.float32, x.dtype
+    held = key < groups
+    with jax.named_scope("moe_dispatch"):
+        order, pos, sizes = _plan(key, groups)
+        overflows, parts = _parts(order, pos.reshape(held.shape), sizes, held)
+    with jax.named_scope("moe_experts"):
+        w_in, w_out = w_in.astype(dtype), w_out.astype(dtype)
+
+    def work(part):
+        part_order, part_sizes, at, here = part
+        with jax.named_scope("moe_experts"):
+            _, row_gate, (g, u) = _expert_rows(x, gate, part_order, part_sizes, w_in, k)
+            # The router's weight on the rows of the NARROW product: the
+            # same sum (the down projection is linear), f / d of the work.
+            hidden = (
+                jax.nn.silu(g.astype(f32)) * u.astype(f32) * row_gate[:, None]
+            ).astype(dtype)
+            out_rows = _gmm(hidden, w_out, part_sizes)
+        with jax.named_scope("moe_combine"):
+            return _rows_to_tokens(out_rows, at, here)
+
+    y = _over_parts(overflows, parts, work)
+    with jax.named_scope("moe_combine"):
+        return y.astype(dtype), order, pos
+
+
+@_fold_silos
+def _moe_backward(x, gate, key, w_in, w_out, dy, order, pos):
+    """(dx, dgate, dw_in, dw_out) from the layer's inputs, the forward's
+    plan and ``dy``: a RECOMPUTE backward — a part's rows and its first
+    product are made again, so the forward banks nothing of the buffer's
+    size."""
+    groups, k = w_in.shape[0], key.shape[1]
+    f32, dtype = jnp.float32, x.dtype
+    held = key < groups
+    with jax.named_scope("moe_dispatch"):
+        sizes = jnp.sum(
+            key.reshape(-1, 1) == jnp.arange(groups), axis=0, dtype=jnp.int32
+        )
+        overflows, parts = _parts(order, pos.reshape(held.shape), sizes, held)
+    with jax.named_scope("moe_experts"):
+        w_in_c, w_out_c = w_in.astype(dtype), w_out.astype(dtype)
+
+    def work(part):
+        part_order, part_sizes, at, here = part
+        with jax.named_scope("moe_combine"):
+            # The transpose of the combine: a row's gradient is its
+            # token's. (Rows past the groups carry their pairs' tokens'
+            # too: finite, and never counted.)
+            dy_rows = jnp.take(dy, part_order // k, axis=0)
+        with jax.named_scope("moe_experts"):
+            rows, row_gate, (g, u) = _expert_rows(
+                x, gate, part_order, part_sizes, w_in_c, k
+            )
+            g, u = g.astype(f32), u.astype(f32)
+            sig = jax.nn.sigmoid(g)
+            act = g * sig * u  # silu(g) * u
+            hidden = (act * row_gate[:, None]).astype(dtype)
+            d_hidden = _gmm_nt(dy_rows, w_out_c, part_sizes).astype(f32)
+            d_w_out = _gmm_tn(hidden, dy_rows, part_sizes)
+            d_row_gate = jnp.sum(d_hidden * act, axis=-1)
+            d_act = d_hidden * row_gate[:, None]
+            d_gu = jnp.concatenate(
+                [d_act * u * sig * (1.0 + g * (1.0 - sig)), d_act * g * sig],
+                axis=-1,
+            ).astype(dtype)
+            d_rows = _gmm_nt(d_gu, w_in_c, part_sizes)
+            d_w_in = _gmm_tn(rows, d_gu, part_sizes)
+        with jax.named_scope("moe_dispatch"):
+            # The transpose of the dispatch. A pair that is not held has
+            # its row past the groups, where gradients are unspecified:
+            # masked by ``where``, never by a product.
+            dx = _rows_to_tokens(d_rows, at, here)
+            d_gate = jnp.where(
+                here, jnp.take(d_row_gate, at.reshape(-1)).reshape(here.shape), 0.0
+            )
+        return dx, d_gate, d_w_in, d_w_out
+
+    dx, d_gate, d_w_in, d_w_out = _over_parts(overflows, parts, work)
+    return (
+        dx.astype(dtype), d_gate.astype(gate.dtype),
+        d_w_in.astype(w_in.dtype), d_w_out.astype(w_out.dtype),
+    )
+
+
+@jax.custom_vjp
+def _held_experts(x, gate, key, w_in, w_out):
+    return _moe_forward(x, gate, key, w_in, w_out)[0]
+
+
+def _held_experts_fwd(x, gate, key, w_in, w_out):
+    y, order, pos = _moe_forward(x, gate, key, w_in, w_out)
+    return y, (x, gate, key, w_in, w_out, order, pos)
+
+
+def _held_experts_bwd(res, dy):
+    x, gate, key, w_in, w_out, order, pos = res
+    dx, d_gate, d_w_in, d_w_out = _moe_backward(
+        x, gate, key, w_in, w_out, dy, order, pos
+    )
+    return dx, d_gate, None, d_w_in, d_w_out
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
+def held_experts_moe(
+    x: jnp.ndarray, gate: jnp.ndarray, expert: jnp.ndarray,
+    w_in: jnp.ndarray, w_out: jnp.ndarray, first: int = 0,
+) -> jnp.ndarray:
+    """The held experts' part of a top-k SwiGLU expert layer.
+
+    ``x [T, d]`` tokens (the compute dtype); ``gate [T, k]`` float32 and
+    ``expert [T, k]`` int32 from :func:`route_top_k` over ALL experts;
+    ``w_in [n_held, d, 2 f]`` (gate and up projections side by side) and
+    ``w_out [n_held, f, d]`` the weights of experts ``first .. first +
+    n_held - 1``, the ones this chip holds (any float dtype: multiplied
+    in ``x``'s dtype, accumulated in float32; their gradients leave the
+    float32 accumulators unrounded, as ``head_cross_entropy``'s).
+    Returns ``sum over a token's choices e that are held of gate_e *
+    down_e(silu(gate_e x) * up_e x)`` as ``[T, d]`` in ``x``'s dtype —
+    ``gate`` stays normalised over all ``k`` choices, so the shares of
+    the chips that hold the other experts add up to the whole layer.
+
+    Dropless: the (token, choice) pairs whose expert is held are sorted
+    by expert, the others behind them, and their tokens gathered into a
+    row buffer of ``k T`` rows — the worst case, every choice held; the
+    grouped products visit the rows routed here only, however they split
+    over the experts. Differentiable in ``x``, ``gate`` and the weights
+    by a recompute backward (nothing of the buffer's size is banked).
+    Under ``jax.vmap`` the batch folds into the groups (one row buffer,
+    ``S n_held`` groups): no batched kernel, no loop over silos.
+
+    Named scopes ``moe_dispatch`` (sort, plan, and in the backward the
+    gather of the tokens' gradients), ``moe_experts`` (the row gather,
+    the grouped products and the gates) and ``moe_combine`` (the gather
+    back to tokens), forward and backward."""
+    n_held = w_in.shape[0]
+    local = expert - first
+    key = jnp.where((local >= 0) & (local < n_held), local, n_held)
+    return _held_experts(x, gate, key.astype(jnp.int32), w_in, w_out)
+
+
+# --- the ep-axis originals -----------------------------------------------------
 
 
 def _dispatch(
