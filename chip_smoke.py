@@ -19,7 +19,10 @@ chip and runs five phases:
 - ``kernel`` — ``TransformerLM`` (dim 512, 8 heads, 4 layers) with the
   Pallas ``flash_attention`` at S=8192 bf16, 3 SGD steps; the kernels'
   outputs and q/k/v gradients (by name, and as ``blockwise_attention``
-  runs them on a TPU) against a dense float32 softmax on the chip.
+  runs them on a TPU) against a dense float32 softmax on the chip —
+  and ``blockwise_attention`` with a band (window 1024, 8 query heads
+  a key head of 128, 8192 tokens) against the same under the band's
+  mask.
 - ``sync`` — INFORMATION for the benchmark PR: one window timed with
   ``jax.block_until_ready`` and with the scalar fetch, plus the
   dispatch round trip.
@@ -63,6 +66,8 @@ from typing import Any, Callable, Optional
 #: kernels as in XLA's own matmuls (on the CPU float32 agrees to 5e-7).
 #: 2e-2 is under three times the reading; the values are printed.
 FLASH_PARITY_TOL = 2e-2
+#: The band of the banded comparison: Mellum 2's published window.
+MELLUM_WINDOW = 1024
 
 #: Mesh vs one device, mean last-round loss, relative: 2%, measured
 #: 1e-5 to 2e-5 on the chip (ROADMAP S1, PR 21). Reduction order differs
@@ -97,6 +102,7 @@ class Sizes:
     lm_seq: int = 8192
     lm_steps: int = 3
     parity_seq: int = 2048
+    band_seq: int = 8192
     mesh_lm_seq: int = 2048
     mesh_lm_batch: int = 8
 
@@ -503,18 +509,22 @@ def phase_kernel(ph: Phase, sz: Sizes, seed: int) -> None:
         jnp.asarray(rng.normal(size=shape), jnp.float32) for _ in range(4)
     )
 
-    def dense(q, k, v, causal):
-        """One S x S float32 softmax, XLA's autodiff for its gradients."""
+    def dense(q, k, v, causal, window=None):
+        """One S x S float32 softmax, XLA's autodiff for its gradients;
+        grouped key heads repeated, a band as its mask."""
         assert causal
         q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+        k, v = (jnp.repeat(x, q.shape[2] // k.shape[2], axis=2) for x in (k, v))
         scores = jnp.einsum(
             "bqhd,bkhd->bhqk", q, k, precision="highest"
         ) / np.sqrt(q.shape[-1])
         seen = jnp.tril(jnp.ones(scores.shape[-2:], bool))
+        if window is not None:
+            seen &= ~jnp.tril(seen, -window)
         p = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
         return jnp.einsum("bhqk,bkhd->bqhd", p, v, precision="highest")
 
-    def out_and_grads(fn, q, k, v):
+    def out_and_grads(fn, q, k, v, cot=cot):
         def loss(q, k, v):
             return jnp.sum(fn(q, k, v, causal=True).astype(jnp.float32) * cot)
 
@@ -547,12 +557,48 @@ def phase_kernel(ph: Phase, sz: Sizes, seed: int) -> None:
     default_kernels = str(
         jax.make_jaxpr(partial(blockwise_attention, causal=True))(*qkv)
     ).count("pallas_call")
+
+    # The band inside the kernels (PR 33) at Mellum 2's banded call: 8
+    # query heads on a key head of 128, window 1024, bf16, the block left
+    # to ``blockwise_attention`` — ONE key head, so that the witness's
+    # [8, S, S] float32 scores and their gradients fit beside it.
+    band_shapes = [(1, sz.band_seq, heads, 128) for heads in (8, 1, 1, 8)]
+    band_qkv = [
+        jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+        for shape in band_shapes[:3]
+    ]
+    band_cot = jnp.asarray(rng.normal(size=band_shapes[3]), jnp.float32)
+    banded = partial(blockwise_attention, window=MELLUM_WINDOW)
+    errs["blockwise_attention.band.bfloat16"] = worst = {
+        part: rel_err(got, ref)
+        for part, got, ref in zip(
+            ("out", "dq", "dk", "dv"),
+            out_and_grads(banded, *band_qkv, cot=band_cot),
+            out_and_grads(
+                partial(dense, window=MELLUM_WINDOW), *band_qkv, cot=band_cot
+            ),
+        )
+    }
+    ph.check(
+        max(worst.values()) <= FLASH_PARITY_TOL,
+        f"blockwise_attention with window {MELLUM_WINDOW} ~ dense float32 "
+        f"softmax under the band's mask, bfloat16 inputs, 8 query heads a "
+        f"key head: out, dq, dk, dv within {FLASH_PARITY_TOL} "
+        f"(worst {max(worst.values()):.3g})",
+    )
+    band_kernels = str(
+        jax.make_jaxpr(partial(banded, causal=True))(*band_qkv)
+    ).count("pallas_call")
     # Last, so that the CPU walk-through (tests/test_chip_smoke.py) runs
     # everything above before the checks a CPU cannot hold.
     ph.check(calls > 0, f"compiled LM step holds tpu_custom_call ({calls})")
     ph.check(
         default_kernels > 0,
         "blockwise_attention, the zoo's default, runs the kernels here",
+    )
+    ph.check(
+        band_kernels > 0,
+        "blockwise_attention with a band runs the kernels here",
     )
     ph.facts.update(
         lm={"dim": 512, "heads": 8, "layers": 4, "seq": seq, "dtype": "bf16"},
@@ -567,6 +613,7 @@ def phase_kernel(ph: Phase, sz: Sizes, seed: int) -> None:
         tpu_custom_calls=calls,
         losses=[round(x, 4) for x in losses],
         parity_shape=list(shape),
+        band_parity_shapes=[list(shape) for shape in band_shapes[:3]],
         parity_witness="dense S x S float32 softmax, XLA autodiff",
         parity_rel_err={
             who: {k: float(f"{e:.3g}") for k, e in worst.items()}

@@ -63,6 +63,12 @@ def described():
         # six grouped products a step are the megablox kernels.
         ("block_attention_mellum_x2", 2, 0),
         ("grouped_products_mellum_x2", 6, 0),
+        # The band inside the kernels (PR 33), the block left to
+        # ``blockwise_attention``: Mellum 2's window of 1024 at block 256
+        # (a sweep of five steps, dq resident as in the full layer) and
+        # SambaY's sliding window of 512 at block 512 (two steps).
+        ("block_attention_mellum_band_x2", 2, 0),
+        ("block_attention_sambay_band_x2", 2, 0),
         # The VMEM guard's edges (``flash_kernel.tiles``): the tallest
         # tile with the longest resident dq, bf16 and float32 gradients.
         ("flash_64k_d128", 2, 0),
@@ -74,6 +80,41 @@ def test_kernel_compiles_for_described_v5e(described, case, kernels, permutes):
     report = rehearsal.compile_report(case, fn, args)
     assert report["tpu_custom_call"] == kernels, report
     assert report["collective_permute"] >= permutes, report
+
+
+@pytest.mark.parametrize(
+    "q_heads, kv_shape, d_v, seq, block",
+    [
+        # Equal heads and pairs of heads keep 512 ...
+        pytest.param(12, (4, 1024, 12, 64), 64, 1024, 512, id="gpt2"),
+        pytest.param(40, (1, 8192, 20, 64), 128, 8192, 512, id="sambay"),
+        pytest.param(40, (1, 1024, 20, 64), 128, 1024, 512, id="sambay_check"),
+        # ... 8 query heads a key head of 128 get 256 (a 512-block's
+        # float32 tile would be 8 MB), in a banded layer as in a full one.
+        pytest.param(32, (2, 8192, 4, 128), 128, 8192, 256, id="mellum"),
+        pytest.param(32, (2, 2048, 4, 128), 128, 2048, 256, id="mellum_check"),
+        # A short sequence is one block; one the kernels cannot tile too.
+        pytest.param(12, (4, 256, 12, 64), 64, 256, 256, id="short"),
+        pytest.param(12, (4, 100, 12, 64), 64, 100, 100, id="unaligned"),
+    ],
+)
+def test_default_block_is_the_largest_the_kernels_admit(
+    q_heads, kv_shape, d_v, seq, block
+):
+    """``blockwise_attention``'s block when the caller names none: a
+    function of shapes alone (no backend, no model's name), checked at
+    the cells' calls — and the block it gives is one the kernels take."""
+    import jax.numpy as jnp
+
+    from tpfl.parallel import flash_kernel
+    from tpfl.parallel.ring_attention import _default_block
+
+    k = jax.ShapeDtypeStruct(kv_shape, jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((*kv_shape[:3], d_v), jnp.bfloat16)
+    groups = q_heads // kv_shape[2]
+    assert _default_block(seq, k, v, groups) == block
+    if seq % 128 == 0:
+        assert flash_kernel.tiles(kv_shape, d_v, block, groups)
 
 
 @pytest.fixture(scope="module")

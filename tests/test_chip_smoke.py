@@ -186,7 +186,7 @@ def test_one_chip_phases_walk_through_on_cpu(monkeypatch, tmp_path):
     monkeypatch.setattr(compat, "pallas_interpret", lambda interpret: True)
     sz = chip_smoke.Sizes(
         resnet_nodes=2, resnet_batches=1, cnn_nodes=4, cnn_batches=1,
-        batch=8, sync_rounds=1, lm_seq=256, parity_seq=256,
+        batch=8, sync_rounds=1, lm_seq=256, parity_seq=256, band_seq=1280,
     )
     cache_dir = profiling.ensure_compile_cache(str(tmp_path / "cache"))
     meter = chip_smoke.CompileMeter().install()

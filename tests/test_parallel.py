@@ -721,31 +721,50 @@ def _attention_and_gradients(q, k, v, cot, silos: bool, **kw):
 
 
 @pytest.mark.parametrize(
-    "hq, hkv, d, dv, s, block, causal, silos, dtype",
+    "hq, hkv, d, dv, s, block, causal, silos, dtype, window",
     [
         # GPT-2's call: equal heads of 64 (two a program instance), two
         # blocks of 512 -> three visible pairs, one skipped.
-        (2, 2, 64, 64, 1024, 512, True, False, jnp.float32),
+        (2, 2, 64, 64, 1024, 512, True, False, jnp.float32, None),
         # SambaY's: two query heads a key head as rows, a value twice as
         # wide as the keys (its own lane tiles, the keys' lanes masked).
-        (4, 2, 64, 128, 512, 256, True, False, jnp.float32),
+        (4, 2, 64, 128, 512, 256, True, False, jnp.float32, None),
         # S not a multiple of the block: padded rows and keys, s_len.
-        (2, 2, 64, 64, 600, 256, True, False, jnp.float32),
+        (2, 2, 64, 64, 600, 256, True, False, jnp.float32, None),
         # ... not causal: the last key block masks its padding.
-        (2, 2, 64, 64, 300, 128, False, False, jnp.float32),
+        (2, 2, 64, 64, 300, 128, False, False, jnp.float32, None),
         # One 128-wide head a program instance; a scale (128 ** -0.5) that
         # is no power of two multiplies the scores, not an operand.
-        (2, 1, 128, 128, 256, 128, True, False, jnp.float32),
+        (2, 1, 128, 128, 256, 128, True, False, jnp.float32, None),
         # Heads no 128 lanes hold: every head in one instance.
-        (4, 2, 8, 16, 256, 128, True, False, jnp.float32),
+        (4, 2, 8, 16, 256, 128, True, False, jnp.float32, None),
         # Under the engine's vmap over silos (one more grid dimension).
-        (4, 2, 64, 128, 256, 128, True, True, jnp.float32),
+        (4, 2, 64, 128, 256, 128, True, True, jnp.float32, None),
         # bf16 as the cells run it.
-        (4, 2, 64, 128, 512, 256, True, True, jnp.bfloat16),
+        (4, 2, 64, 128, 512, 256, True, True, jnp.bfloat16, None),
+        # --- a band (``window``): the sweep covers the blocks it reaches ---
+        # Mellum 2's banded call, scaled down: 8 query heads a key head
+        # of 128, a window of 4 blocks -> five pairs a query block, the
+        # diagonal and the far edge masked, the three between not.
+        (8, 1, 128, 128, 1024, 128, True, False, jnp.float32, 512),
+        # A window that is no multiple of the block: TWO far pairs masked.
+        (2, 2, 64, 64, 768, 128, True, False, jnp.float32, 300),
+        # A window shorter than one block: every pair masked, the
+        # diagonal by both edges.
+        (2, 2, 64, 64, 512, 128, True, False, jnp.float32, 50),
+        # A padded sequence: the sweep's last steps leave the sequence.
+        (2, 2, 64, 64, 600, 128, True, False, jnp.float32, 256),
+        # SambaY's sliding window: two query heads a key head, a value
+        # width of its own, a window of one block (both pairs masked).
+        (4, 2, 64, 128, 768, 256, True, False, jnp.float32, 256),
+        # Under the engine's vmap over silos.
+        (4, 2, 64, 128, 512, 128, True, True, jnp.float32, 200),
+        # bf16 as the cell runs it.
+        (8, 1, 128, 128, 1024, 128, True, True, jnp.bfloat16, 512),
     ],
 )
 def test_blockwise_attention_kernels_match_the_xla_loop(
-    monkeypatch, hq, hkv, d, dv, s, block, causal, silos, dtype
+    monkeypatch, hq, hkv, d, dv, s, block, causal, silos, dtype, window
 ):
     """The Pallas kernels behind ``blockwise_attention`` on a TPU against
     its XLA block loop: the output and all three gradients. float32 to
@@ -763,7 +782,7 @@ def test_blockwise_attention_kernels_match_the_xla_loop(
     v32 = jax.random.normal(keys[2], (*lead, 2, s, hkv, dv))
     cot = jax.random.normal(keys[3], (*lead, 2, s, hq, dv))
     q, k, v = (x.astype(dtype) for x in (q32, k32, v32))
-    kw = dict(causal=causal, block_size=block)
+    kw = dict(causal=causal, block_size=block, window=window)
     loop = _attention_and_gradients(q, k, v, cot, silos, **kw)
     exact = _attention_and_gradients(q32, k32, v32, cot, silos, **kw)
     monkeypatch.setattr(compat, "on_tpu", lambda: True)
@@ -791,11 +810,111 @@ def test_blockwise_attention_kernels_match_the_xla_loop(
             assert off(got, true) < 1.1 * off(want, true), name
 
 
-def test_blockwise_attention_kernels_are_the_tpu_branch_without_a_band(kernels_on_cpu):
+def _dense_band(q, k, v, window):
+    """Float32 softmax over the whole masked ``[S, S]`` score matrix."""
+    groups = q.shape[2] // k.shape[2]
+    k, v = (jnp.repeat(x, groups, axis=2) for x in (k, v))
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    apart = jnp.arange(q.shape[1])[:, None] - jnp.arange(q.shape[1])[None]
+    scores = jnp.where((apart >= 0) & (apart < window), scores, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v)
+
+
+def test_banded_kernels_match_a_dense_masked_softmax(kernels_on_cpu):
+    """The band inside the kernels against plain dense attention under
+    the mask ``0 <= q - k < window``, output and gradients: grouped
+    heads, a padded sequence, a window that cuts two far blocks."""
+    keys = jax.random.split(jax.random.PRNGKey(11), 4)
+    q = jax.random.normal(keys[0], (2, 700, 4, 64))
+    k = jax.random.normal(keys[1], (2, 700, 2, 64))
+    v = jax.random.normal(keys[2], (2, 700, 2, 128))
+    cot = jax.random.normal(keys[3], (2, 700, 4, 128))
+    got = _attention_and_gradients(
+        q, k, v, cot, False, causal=True, block_size=128, window=300
+    )
+
+    def loss(q, k, v):
+        out = _dense_band(q, k, v, 300)
+        return jnp.vdot(out, cot), out
+
+    grads, out = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, (out, *grads)):
+        np.testing.assert_allclose(a, b, atol=3e-5, err_msg=name)
+
+
+#: float64 sums of (out, dq, dk, dv) of the call below at the commit
+#: before the band went into the kernels (PR 32's tree, the emulator).
+PARENT_SUMS = [
+    -134.0277310088277, 69.49734580801123, 0.17355041205883026,
+    141.93218785896897,
+]
+
+
+@pytest.mark.parametrize("window", [None, 600, 4096])
+def test_a_band_as_long_as_the_sequence_is_the_causal_call(kernels_on_cpu, window):
+    """With ``window=None``, or a window that no query's band leaves
+    (>= the sequence), the kernels' results are the causal call's bit
+    for bit — and the causal call's are the parent's: its kernels are
+    pinned below to the values the commit before the band gave."""
+    keys = jax.random.split(jax.random.PRNGKey(3), 4)
+    q, k, v, cot = (
+        jax.random.normal(key, (1, 600, 2, 64)).astype(jnp.bfloat16)
+        for key in keys
+    )
+    kw = dict(causal=True, block_size=256)
+    causal = _attention_and_gradients(q, k, v, cot, False, **kw)
+    banded = _attention_and_gradients(q, k, v, cot, False, window=window, **kw)
+    for name, a, b in zip(("out", "dq", "dk", "dv"), banded, causal):
+        np.testing.assert_array_equal(
+            np.asarray(a, np.float32), np.asarray(b, np.float32), err_msg=name
+        )
+    sums = [float(np.asarray(x, np.float64).sum()) for x in causal]
+    assert sums == PARENT_SUMS
+
+
+@pytest.mark.parametrize(
+    "window, block, n_blocks, reach, edge",
+    [
+        # Mellum 2's band: pairs 0 and 4 blocks apart are masked, 1-3 not.
+        (1024, 256, 32, 4, 4),
+        (1024, 128, 64, 8, 8),
+        # No multiple of the block: the far edge cuts TWO pairs (2 and 3).
+        (600, 256, 32, 3, 2),
+        # Shorter than a block: the diagonal is cut by both edges.
+        (50, 128, 8, 1, 0),
+        (1, 128, 8, 0, 0),
+        # SambaY's: one block. A band longer than the sequence's blocks.
+        (512, 512, 16, 1, 1),
+        (4000, 256, 4, 3, 15),
+    ],
+)
+def test_band_plan_sweeps_the_blocks_the_band_reaches(
+    window, block, n_blocks, reach, edge
+):
+    """The kernels' static plan of a band: a sweep of ``reach + 1`` steps
+    (the XLA loop's ``_band_reach``, within the sequence), masks from
+    ``edge`` blocks apart — checked against the mask itself."""
+    from tpfl.parallel import flash_kernel
+    from tpfl.parallel.ring_attention import _band_reach
+
+    x = jax.ShapeDtypeStruct((1, n_blocks * block, 2, 64), jnp.float32)
+    plan = flash_kernel._plan(x, x, True, block, x.shape[1], 1, window)
+    assert (plan.reach, plan.edge, plan.sweep) == (reach, edge, reach + 1)
+    assert plan.reach == min(n_blocks - 1, _band_reach(block, window))
+    apart = np.arange(block)[:, None] - np.arange(block)[None]  # q - k
+    for blocks in range(n_blocks):
+        seen = (apart + blocks * block >= 0) & (apart + blocks * block < window)
+        assert seen.any() == (blocks <= _band_reach(block, window)), blocks
+        if blocks:
+            assert (not seen.all()) == (blocks >= edge), blocks
+    assert flash_kernel._plan(x, x, True, block, x.shape[1], 1).sweep == n_blocks
+
+
+def test_blockwise_attention_kernels_are_the_tpu_branch_with_a_band_too(kernels_on_cpu):
     """What the code can observe selects the path: on a TPU the block
     loop is two ``pallas_call`` s (forward; ONE backward sweep), with a
-    band (``window``) or at blocks the kernels cannot tile it stays the
-    XLA loop — and elsewhere (every other test of this file) too."""
+    band (``window``) too; at blocks the kernels cannot tile it stays
+    the XLA loop — and elsewhere (every other test of this file) too."""
     from tpfl.parallel.ring_attention import blockwise_attention
 
     def kernels(s, **kw):
@@ -806,7 +925,7 @@ def test_blockwise_attention_kernels_are_the_tpu_branch_without_a_band(kernels_o
         return text.count("pallas_call")
 
     assert kernels(1024) == 2
-    assert kernels(1024, window=256) == 0
+    assert kernels(1024, window=256) == 2
     assert kernels(128) == 2  # one block as long as the sequence
     # Only what was compiled for a TPU: a short sequence that is one
     # block of its own, unaligned length stays with the loop, as before

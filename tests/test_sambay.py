@@ -374,8 +374,8 @@ def test_layer_kind_is_the_published_pattern():
         # The benchmark's cut in little: Mamba, full attention (lends K / V),
         # a GMU, cross-attention: both attention layers take the kernels ...
         ((4, 5, 6, 7), 2 * 2 + 2),
-        # ... and a sliding-window layer stays the XLA loop (it has a band).
-        ((1,), 0),
+        # ... and so does a sliding-window layer: the band is in the kernels.
+        ((1,), 2 + 1),
     ],
 )
 def test_sambay_attention_runs_the_kernels_on_the_tpu_branch(monkeypatch, layers, kernels):

@@ -185,15 +185,20 @@ def flash_case(shape, dtype, device) -> tuple:
     return jax.jit(jax.grad(loss, argnums=(0, 1, 2))), (x, x, x)
 
 
-def block_attention_case(silos: int, q, k, v, device, block_size=None) -> tuple:
+def block_attention_case(
+    silos: int, q, k, v, device, block_size=None, window=None
+) -> tuple:
     """(jitted fwd+bwd of ``blockwise_attention`` under the engine's vmap
     over ``silos``, args) for bf16 causal ``q`` / ``k`` / ``v`` shapes: on
-    the TPU branch the block loop is two Pallas kernels."""
+    the TPU branch the block loop is two Pallas kernels, with a band
+    (``window``) too."""
     from tpfl.parallel.ring_attention import blockwise_attention
 
     def loss(q, k, v):
         out = jax.vmap(
-            lambda *x: blockwise_attention(*x, causal=True, block_size=block_size)
+            lambda *x: blockwise_attention(
+                *x, causal=True, block_size=block_size, window=window
+            )
         )(q, k, v)
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
@@ -421,6 +426,19 @@ def cases(devices) -> dict:
         "block_attention_mellum_x2": lambda: block_attention_case(
             2, (2, 8192, 32, 128), (2, 8192, 4, 128), (2, 8192, 4, 128), d0,
             block_size=256,
+        ),
+        # The banded layers of both models, the block left to
+        # ``blockwise_attention``'s own rule: Mellum 2's window of 1024
+        # (block 256: five pairs a query block, two of them masked) and
+        # SambaY's sliding window of 512 (block 512: two pairs, both
+        # masked; two query heads a key head, a value twice as wide).
+        "block_attention_mellum_band_x2": lambda: block_attention_case(
+            2, (2, 8192, 32, 128), (2, 8192, 4, 128), (2, 8192, 4, 128), d0,
+            window=1024,
+        ),
+        "block_attention_sambay_band_x2": lambda: block_attention_case(
+            2, (1, 8192, 40, 64), (1, 8192, 20, 64), (1, 8192, 20, 128), d0,
+            window=512,
         ),
         "grouped_products_mellum_x2": lambda: grouped_products_case(
             98304, 32, 2304, 896, d0

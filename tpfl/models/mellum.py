@@ -48,12 +48,6 @@ from tpfl.models.head_loss import head_cross_entropy
 from tpfl.parallel.moe import held_experts_moe, route_top_k
 from tpfl.parallel.ring_attention import blockwise_attention
 
-#: The block the full layer asks ``blockwise_attention`` for: with 8
-#: query heads a key head a key head's block is ``8 x block`` rows tall,
-#: and at 256 the Pallas kernels take it at 8192 tokens
-#: (``flash_kernel.tiles``; at the default 512 the score tile is 8 MB).
-FULL_ATTENTION_BLOCK = 256
-
 
 def rotary_frequencies(
     head_dim: int, theta: float, yarn: Optional[dict] = None
@@ -150,10 +144,6 @@ class MellumAttention(nn.Module):
         out = blockwise_attention(
             q, k, v.reshape(b, s, self.kv_heads, self.head_dim), causal=True,
             window=self.window,
-            block_size=(
-                None if self.window is not None
-                else min(s, FULL_ATTENTION_BLOCK)
-            ),
         )
         return dense(dim, "o_proj")(out.reshape(b, s, -1))
 

@@ -48,7 +48,15 @@ tile-sized operand is ever transposed but ``dS`` for ``dQ``.
 Causal pairs above the diagonal are neither computed nor fetched (the
 index maps clamp to the diagonal, so the block index does not change
 and nothing is copied; ``pl.when`` skips the body); the mask is built
-on pairs that touch the diagonal only. Numerics are the block loop's:
+on pairs that touch the diagonal only. With a band (``window``: ``0 <=
+q - k < window``, PR 33) a sweep is ``reach + 1`` steps long and offset
+from the diagonal — the forward's ends on it, the backward's starts on
+it — so pairs wholly behind the band are not even grid steps (at 32
+blocks and a reach of 4 an ``n x n`` grid would spend 85% of its steps
+on nothing); the mask is built on the diagonal and on the pairs the
+band's far edge cuts, the pairs between run unmasked. The backward
+still keeps the head block's dq for the WHOLE sequence in VMEM.
+Numerics are the block loop's:
 P and dS are rounded to the inputs' dtype for their matmuls alone;
 accumulators, max, denominator, lse and delta are float32; float32
 inputs compute in float32 throughout (on a TPU at the MXU's default
@@ -64,7 +72,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -119,10 +127,29 @@ class _Plan(NamedTuple):
     s_len: int
     scale: float
     fold: bool  # the scale is a power of two: folded into an operand
+    window: Optional[int] = None  # the causal band: 0 <= q - k < window
 
     @property
     def rows(self) -> int:
         return self.groups * self.block
+
+    @property
+    def reach(self) -> int:
+        """How many key blocks behind its own a query block's band
+        reaches (``ring_attention._band_reach``), within the sequence."""
+        return min(self.n_blocks - 1, -(-(self.window - 1) // self.block))
+
+    @property
+    def edge(self) -> int:
+        """From how many blocks apart the band's far edge cuts a pair:
+        the farthest rows of blocks i and j are ``(i - j + 1) * block -
+        1`` apart."""
+        return -(-(self.window + 1) // self.block) - 1
+
+    @property
+    def sweep(self) -> int:
+        """Steps of a sweep: every block, or those the band reaches."""
+        return self.n_blocks if self.window is None else self.reach + 1
 
     @property
     def masked_last(self) -> bool:
@@ -155,13 +182,14 @@ def _heads_per_instance(h: int, d: int, d_v: int) -> int:
     return h
 
 
-def _plan(k, v, causal, block, s_len, groups) -> _Plan:
+def _plan(k, v, causal, block, s_len, groups, window=None) -> _Plan:
     _, sp, h, d = k.shape
     scale = 1.0 / math.sqrt(d)
     return _Plan(
         heads=_heads_per_instance(h, d, v.shape[-1]), d=d, d_v=v.shape[-1],
         block=block, groups=groups, n_blocks=sp // block, causal=causal,
         s_len=s_len, scale=scale, fold=math.log2(scale).is_integer(),
+        window=window,
     )
 
 
@@ -227,14 +255,20 @@ def _add(plan: _Plan, ref, h: int, width: int, value) -> None:
     ref[...] = jnp.where(_lanes_of(h, width, old.shape), old + value, old)
 
 
-def _visible(plan: _Plan, j):
+def _visible(plan: _Plan, i, j):
     """Which (key, query) of a masked pair's ``[block, rows]`` tile is
     seen: on the diagonal of a causal call ``q >= k`` (a group's heads
-    are ``groups`` runs of the block's positions along the lanes), in
-    the last key block of a padded non-causal one ``k < s_len``."""
+    are ``groups`` runs of the block's positions along the lanes), with
+    a band ``0 <= q - k < window`` (the diagonal and the far edge), in
+    the last key block of a padded non-causal call ``k < s_len``."""
     one = (plan.block, plan.block)
     k_pos = lax.broadcasted_iota(jnp.int32, one, 0)
-    if plan.causal:
+    if plan.window is not None:
+        apart = (i - j) * plan.block + (
+            lax.broadcasted_iota(jnp.int32, one, 1) - k_pos
+        )
+        seen = (apart >= 0) & (apart < plan.window)
+    elif plan.causal:
         seen = lax.broadcasted_iota(jnp.int32, one, 1) >= k_pos
     else:
         seen = j * plan.block + k_pos < plan.s_len
@@ -244,8 +278,18 @@ def _visible(plan: _Plan, j):
 
 def _on_pairs(plan: _Plan, i, j, last, pair) -> None:
     """Run ``pair(masked)`` on the visible pairs of query block ``i`` and
-    key block ``j``, with the mask where a pair needs one."""
-    if plan.causal:
+    key block ``j``, with the mask where a pair needs one. A band's sweep
+    is offset from the diagonal, so a step may lie outside the sequence;
+    of the pairs inside, the diagonal and those the far edge cuts are
+    masked, the ones between are not."""
+    if plan.window is not None:
+        apart = i - j
+        inside = (j >= 0) & (i < plan.n_blocks)
+        masked = (apart == 0) | (apart >= plan.edge)
+        if plan.edge > 1:
+            pl.when(inside & ~masked)(functools.partial(pair, False))
+        pl.when(inside & masked)(functools.partial(pair, True))
+    elif plan.causal:
         pl.when(j < i)(functools.partial(pair, False))
         pl.when(j == i)(functools.partial(pair, True))
     elif plan.masked_last:
@@ -262,12 +306,14 @@ def _forward_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, qh_scr, acc_scr, m_scr, l_scr,
     *, plan: _Plan,
 ):
-    i, j = pl.program_id(2), pl.program_id(3)
+    i, t = pl.program_id(2), pl.program_id(3)
     last = pl.num_programs(3) - 1
+    # The key block of step t: with a band the sweep ends on the diagonal.
+    j = t if plan.window is None else i - plan.reach + t
     lowp = q_ref.dtype
     d_v = plan.d_v
 
-    @pl.when(j == 0)
+    @pl.when(t == 0)
     def _():
         q = q_ref[...]
         for h in range(plan.heads):
@@ -278,7 +324,7 @@ def _forward_kernel(
 
     def pair(masked: bool):
         k, v = k_ref[...], v_ref[...]
-        seen = _visible(plan, j) if masked else None
+        seen = _visible(plan, i, j) if masked else None
         # [block, rows]: keys x queries, so a query's max and sum run down
         # the sublanes and its scalars lie along the lanes. Every head's
         # scores first: the next head's matmul then runs beside this
@@ -308,7 +354,7 @@ def _forward_kernel(
 
     _on_pairs(plan, i, j, last, pair)
 
-    @pl.when(j == last)
+    @pl.when(t == last)
     def _():
         for h in range(plan.heads):
             m, l = m_scr[h:h + 1, :], l_scr[h:h + 1, :]
@@ -326,15 +372,17 @@ def _backward_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, dk_ref, dv_ref,
     kh_scr, vh_scr, dk_scr, dv_scr, dq_scr, *, plan: _Plan,
 ):
-    j, i = pl.program_id(2), pl.program_id(3)
-    last_j, last_i = pl.num_programs(2) - 1, pl.num_programs(3) - 1
+    j, t = pl.program_id(2), pl.program_id(3)
+    last_j, last_t = pl.num_programs(2) - 1, pl.num_programs(3) - 1
+    # The query block of step t: with a band the sweep starts on the diagonal.
+    i = t if plan.window is None else j + t
     lowp = q_ref.dtype
 
-    @pl.when((i == 0) & (j == 0))
+    @pl.when((t == 0) & (j == 0))
     def _():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    @pl.when(i == 0)
+    @pl.when(t == 0)
     def _():
         k, v = k_ref[...], v_ref[...]
         for h in range(plan.heads):
@@ -345,7 +393,7 @@ def _backward_kernel(
 
     def pair(masked: bool):
         q, do = q_ref[...], do_ref[...]
-        seen = _visible(plan, j) if masked else None
+        seen = _visible(plan, i, j) if masked else None
         at = pl.ds(pl.multiple_of(i * plan.rows, plan.rows), plan.rows)
         for h in range(plan.heads):
             q_h = _streamed(plan, q, h, plan.d)
@@ -370,12 +418,12 @@ def _backward_kernel(
 
     _on_pairs(plan, i, j, last_j, pair)
 
-    @pl.when(i == last_i)
+    @pl.when(t == last_t)
     def _():
         dk_ref[...] = (dk_scr[...] * plan.scale).astype(dk_ref.dtype)
         dv_ref[...] = dv_scr[...].astype(dv_ref.dtype)
 
-    @pl.when((i == last_i) & (j == last_j))
+    @pl.when((t == last_t) & (j == last_j))
     def _():
         post = 1.0 if plan.fold else plan.scale  # k_h carried it
         dq_ref[...] = (dq_scr[...] * post).astype(dq_ref.dtype)
@@ -400,19 +448,22 @@ def _params(*semantics: str):
 
 def attention_forward(
     q, k, v, *, causal: bool, block: int, s_len: int, groups: int = 1,
-    interpret: bool = False, out_dtype=None,
+    window: Optional[int] = None, interpret: bool = False, out_dtype=None,
 ):
     """Padded k ``[B, nb * block, H, D]``, v ``[.., Dv]`` and q ``[B, nb *
     rows, H, D]`` with ``rows = groups * block`` (the layout of
     ``ring_attention._blockwise_fwd_core``) -> ``(out [B, nb * rows, H,
-    Dv], lse [B, H, nb * rows] float32)``."""
-    plan = _plan(k, v, causal, block, s_len, groups)
+    Dv], lse [B, H, nb * rows] float32)``. ``window`` (causal only): the
+    band ``0 <= q - k < window``."""
+    plan = _plan(k, v, causal, block, s_len, groups, window)
     b, _, h, _ = k.shape
     n, rows, heads = plan.n_blocks, plan.rows, plan.heads
     w, w_v = heads * plan.d, heads * plan.d_v
 
-    def key_block(bi, hi, i, j):
-        return bi, jnp.minimum(i, j) if causal else j, hi
+    def key_block(bi, hi, i, t):
+        if window is not None:  # steps before the sequence refetch block 0
+            return bi, jnp.maximum(i - plan.reach + t, 0), hi
+        return bi, jnp.minimum(i, t) if causal else t, hi
 
     out, lse = pl.pallas_call(
         functools.partial(_forward_kernel, plan=plan),
@@ -420,7 +471,7 @@ def attention_forward(
             jax.ShapeDtypeStruct((b, n * rows, h * plan.d_v), out_dtype or q.dtype),
             jax.ShapeDtypeStruct((b, h // heads, heads, n * rows), F32),
         ],
-        grid=(b, h // heads, n, n),
+        grid=(b, h // heads, n, plan.sweep),
         in_specs=[
             pl.BlockSpec((None, rows, w), lambda bi, hi, i, j: (bi, i, hi)),
             pl.BlockSpec((None, block, w), key_block),
@@ -447,20 +498,24 @@ def attention_forward(
 
 def attention_backward(
     q, k, v, do, lse, delta, *, causal: bool, block: int, s_len: int,
-    groups: int = 1, interpret: bool = False, grad_dtype=None,
+    groups: int = 1, window: Optional[int] = None, interpret: bool = False,
+    grad_dtype=None,
 ):
     """dq, dk, dv of :func:`attention_forward`'s operands from its
     ``lse``, the cotangent ``do`` and ``delta = rowsum(do * out)`` ``[B,
     H, nb * rows]`` float32 (both may cover MORE keys than this call
     sees: the ring's are the whole row's)."""
-    plan = _plan(k, v, causal, block, s_len, groups)
+    plan = _plan(k, v, causal, block, s_len, groups, window)
     b, _, h, _ = k.shape
     n, rows, heads = plan.n_blocks, plan.rows, plan.heads
     w, w_v = heads * plan.d, heads * plan.d_v
 
-    def fetched(j, i):
-        """The query block of step (j, i): clamped to the diagonal."""
-        return jnp.maximum(i, j) if causal else i
+    def fetched(j, t):
+        """The query block of step (j, t): clamped to the diagonal, and
+        with a band to the sequence's last block."""
+        if window is not None:
+            return jnp.minimum(j + t, n - 1)
+        return jnp.maximum(t, j) if causal else t
 
     def query_block(bi, hi, j, i):
         return bi, fetched(j, i), hi
@@ -480,7 +535,7 @@ def attention_backward(
             jax.ShapeDtypeStruct((b, n * block, h * plan.d), grad_dtype or k.dtype),
             jax.ShapeDtypeStruct((b, n * block, h * plan.d_v), grad_dtype or v.dtype),
         ],
-        grid=(b, h // heads, n, n),
+        grid=(b, h // heads, n, plan.sweep),
         in_specs=[
             pl.BlockSpec((None, rows, w), query_block),
             pl.BlockSpec((None, block, w), key_block),

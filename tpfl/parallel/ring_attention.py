@@ -110,18 +110,22 @@ def blockwise_attention(
     not build (pre-PR-1, not re-measured).
 
     **Two forms of the one block loop**, chosen by what can be observed
-    (``_kernels``): on a TPU, without a band, at blocks that are whole
-    128-lane tiles (``flash_kernel.tiles``: only what was compiled for a
-    TPU), it runs as the Pallas kernels of
-    :mod:`tpfl.parallel.flash_kernel` — a pair's score tile, P, dP, dS
-    and the running accumulators stay in VMEM; no operand is transposed
-    or padded. Everywhere else — a short sequence that is one block of
-    its own unaligned length too — and with ``window`` set, it is the
-    XLA loop below (``_blockwise_fwd_core`` /
-    ``_blockwise_vjp_bwd``, a few key heads a backward step), which is
-    also what the kernels are tested against. Both run under the named
-    scope ``block_attention``; padding, the grouped-row layout and what
-    the forward banks are the same.
+    (``_kernels``): on a TPU, at blocks that are whole 128-lane tiles
+    (``flash_kernel.tiles``: only what was compiled for a TPU), it runs
+    as the Pallas kernels of :mod:`tpfl.parallel.flash_kernel`, with a
+    band too — a pair's score tile, P, dP, dS and the running
+    accumulators stay in VMEM; no operand is transposed or padded.
+    Everywhere else — a short sequence that is one block of its own
+    unaligned length too — it is the XLA loop below
+    (``_blockwise_fwd_core`` / ``_blockwise_vjp_bwd``, a few key heads a
+    backward step), which is also what the kernels are tested against.
+    Both run under the named scope ``block_attention``; padding, the
+    grouped-row layout and what the forward banks are the same.
+
+    **The block** a call that names none gets is the largest of 512 /
+    256 / 128 that the kernels admit for its shapes
+    (``_default_block``): a function of shapes alone, the same on every
+    backend.
 
     The tile matmuls (``P V``, ``dO V^T``, ``dS K``, ``dS^T Q``,
     ``P^T dO``) read P and dS rounded to the inputs' dtype beside q, k,
@@ -138,7 +142,9 @@ def blockwise_attention(
         )
     if window is not None and not causal:
         raise ValueError("a band (window) is causal: pass causal=True")
-    block = block_size or min(s, 512)
+    if window is not None and window >= s:
+        window = None  # every key behind a query is inside the band
+    block = block_size or _default_block(s, k, v, groups)
     n_blocks = -(-s // block)
     pad = n_blocks * block - s
     if pad:
@@ -188,17 +194,36 @@ def _query_rows(local_idx, groups: int):
     return jnp.tile(local_idx, groups) if groups > 1 else local_idx
 
 
-def _kernels(k, v, block: int, groups: int, window) -> bool:
+def _kernels(k, v, block: int, groups: int) -> bool:
     """Whether the block loop runs as the Pallas kernels of
-    :mod:`tpfl.parallel.flash_kernel`: on a TPU, without a band (the
-    kernels have none), at the shapes they were compiled for."""
-    if window is not None or not compat.on_tpu():
+    :mod:`tpfl.parallel.flash_kernel`: on a TPU, at the shapes they were
+    compiled for."""
+    if not compat.on_tpu():
         return False
     from tpfl.parallel import flash_kernel
 
     return flash_kernel.tiles(
         k.shape, v.shape[-1], block, groups, k.dtype.itemsize
     )
+
+
+def _default_block(s: int, k, v, groups: int) -> int:
+    """The block of a call that names none: the largest of 512 / 256 /
+    128 (a shorter sequence is one block) that the kernels admit for the
+    call's shapes (``flash_kernel.tiles``), 512 where they admit none. A
+    function of shapes alone, so the same on every backend: with 8 query
+    heads a key head of 128 a 512-block's float32 tile is 8 MB and the
+    block is 256; equal heads and pairs of heads keep 512."""
+    from tpfl.parallel import flash_kernel
+
+    for block in (512, 256, 128):
+        block = min(s, block)
+        padded = (k.shape[0], -(-s // block) * block, *k.shape[2:])
+        if flash_kernel.tiles(
+            padded, v.shape[-1], block, groups, k.dtype.itemsize
+        ):
+            return block
+    return min(s, 512)
 
 
 @jax.named_scope("block_attention")
@@ -214,12 +239,12 @@ def _blockwise_fwd_core(
     b, sp, h, d = k.shape
     d_v = v.shape[-1]
     n_blocks = sp // block
-    if _kernels(k, v, block, groups, window):
+    if _kernels(k, v, block, groups):
         from tpfl.parallel import flash_kernel
 
         return flash_kernel.attention_forward(
             q, k, v, causal=causal, block=block, s_len=s_len, groups=groups,
-            interpret=compat.pallas_interpret(None),
+            window=window, interpret=compat.pallas_interpret(None),
         )
     rows = groups * block
     qb = q.reshape(b, n_blocks, rows, h, d)
@@ -333,12 +358,13 @@ def _blockwise_vjp_bwd(causal, block, s_len, groups, window, res, g):
         jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1),
         1, 2,
     )  # [B, H, S']
-    if _kernels(k, v, block, groups, window):
+    if _kernels(k, v, block, groups):
         from tpfl.parallel import flash_kernel
 
         return flash_kernel.attention_backward(
             q, k, v, g, lse, delta, causal=causal, block=block, s_len=s_len,
-            groups=groups, interpret=compat.pallas_interpret(None),
+            groups=groups, window=window,
+            interpret=compat.pallas_interpret(None),
         )
     heads = _heads_per_step(b, h, rows, block)
     chunks = h // heads
