@@ -48,9 +48,14 @@ def pytest_collection_modifyitems(items):
     test_cell_files_load_and_state_the_cut`` checks. Strict: when a
     ``benchmark`` PR repairs that test, this mark fails and goes."""
     for item in items:
-        if item.name == (
+        if item.name in (
             "test_configuration_file_says_what_benchmark_json_says"
-            "[mellum2_12b_a2p5b]"
+            "[mellum2_12b_a2p5b]",
+            # PR 34's ``zaya1_8b`` is cut the same three ways; its own
+            # case is ``test_benchmark_zaya1.py::
+            # test_cell_files_load_and_state_the_cut``.
+            "test_configuration_file_says_what_benchmark_json_says"
+            "[zaya1_8b]",
         ):
             item.add_marker(pytest.mark.xfail(
                 reason="the accepted test asserts reduced == [] of every "

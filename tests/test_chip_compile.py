@@ -69,6 +69,17 @@ def described():
         # SambaY's sliding window of 512 at block 512 (two steps).
         ("block_attention_mellum_band_x2", 2, 0),
         ("block_attention_sambay_band_x2", 2, 0),
+        # ZAYA1-8B's attention inside the latent (2 key heads x 4 query
+        # heads of 128, 8192 keys: block 512 exactly at ``_TILE_BYTES``,
+        # 32 MiB of dq) takes the kernels as they are; its experts' six
+        # grouped products at 2048 x 4096 (tiles of 1024, the weight
+        # gradient's cut to 1024 x 512 to fit VMEM); and ONE whole block
+        # — the convolutions, the router MLP and its state, the head of
+        # the row buffer and its rest: 2 attention kernels + 2 x 7
+        # grouped products.
+        ("block_attention_zaya_x2", 2, 0),
+        ("grouped_products_zaya_x2", 6, 0),
+        ("zaya_block_x2", 16, 0),
         # The VMEM guard's edges (``flash_kernel.tiles``): the tallest
         # tile with the longest resident dq, bf16 and float32 gradients.
         ("flash_64k_d128", 2, 0),
@@ -93,6 +104,9 @@ def test_kernel_compiles_for_described_v5e(described, case, kernels, permutes):
         # float32 tile would be 8 MB), in a banded layer as in a full one.
         pytest.param(32, (2, 8192, 4, 128), 128, 8192, 256, id="mellum"),
         pytest.param(32, (2, 2048, 4, 128), 128, 2048, 256, id="mellum_check"),
+        # ... and 4 query heads a key head of 128 keep 512 (a 4 MiB tile).
+        pytest.param(8, (2, 8192, 2, 128), 128, 8192, 512, id="zaya"),
+        pytest.param(8, (1, 2048, 2, 128), 128, 2048, 512, id="zaya_check"),
         # A short sequence is one block; one the kernels cannot tile too.
         pytest.param(12, (4, 256, 12, 64), 64, 256, 256, id="short"),
         pytest.param(12, (4, 100, 12, 64), 64, 100, 100, id="unaligned"),

@@ -29,7 +29,9 @@ def _share(x, router, w_in, w_out, first, count, bias=0.0):
     """The part of the layer experts ``first .. first + count - 1`` give."""
     gate, expert, _ = moe.route_top_k(x @ router + bias, K)
     held = slice(first, first + count)
-    return moe.held_experts_moe(x, gate, expert, w_in[held], w_out[held], first)
+    return moe.held_experts_moe(
+        x, gate, expert, w_in[held], w_out[held], first, E
+    )
 
 
 def _uncut(x, router, w_in, w_out, bias=0.0, only=None):
@@ -94,13 +96,14 @@ def _loss(x, router, w_in, w_out, bias=0.0):
 
 @pytest.mark.parametrize("held_bias", [0.0, 50.0, -50.0], ids=["balanced", "all", "none"])
 def test_buffer_past_its_head_is_entered_when_it_is_live(monkeypatch, held_bias):
-    """The layer always works on the head of the row buffer (three
-    eighths of its slots) and enters the rest only when a live row lies
+    """The layer always works on the head of the row buffer (one and a
+    half times a balanced load: three quarters of the slots with half
+    the experts held) and enters the rest only when a live row lies
     there: with every choice of every token held it does, with none held
     (or few) it does not — value and gradients equal the uncut layer's
-    either way, under vmap (2 x 256 slots, a head of 192)."""
+    either way, under vmap (2 x 256 slots, a head of 384)."""
     monkeypatch.setattr(moe, "_TILE_ROWS", 8)
-    assert moe._head_rows(2 * T * K) == 192
+    assert moe._head_rows(2 * T * K, (8, E)) == 384
     args = _weights(5, silos=2)
     bias = jnp.where((jnp.arange(E) >= 4) & (jnp.arange(E) < 12), held_bias, 0.0)
     gate, expert, _ = moe.route_top_k(args[0][0] @ args[1][0] + bias, K)
@@ -216,3 +219,133 @@ def test_router_is_float32_whatever_the_compute_dtype(dtype):
     assert len(routers) == 1
     assert {v.aval.dtype for v in routers[0].invars} == {jnp.dtype("float32")}
     assert "HIGHEST" in str(routers[0].params["precision"])
+
+
+# --- one expert a token, half the experts held (ZAYA1's layer) ----------------
+
+
+def _share_top1(x, router, w_in, w_out, first, count, bias=0.0):
+    """Experts ``first .. first + count - 1``'s part of a top-1 layer
+    whose gate is the chosen expert's probability."""
+    gate, expert, _ = moe.route_by_probability(x @ router, 1, bias)
+    held = slice(first, first + count)
+    return moe.held_experts_moe(
+        x, gate, expert, w_in[held], w_out[held], first, E
+    )
+
+
+def _uncut_top1(x, router, w_in, w_out, bias=0.0):
+    """The whole top-1 layer as the published equations state it: the
+    most probable expert (``bias`` moves the choice only) on each token,
+    weighted by its probability under the softmax over all experts."""
+    probs = jax.nn.softmax(x @ router, axis=-1)
+    chosen = jnp.argmax(probs + bias, axis=-1)[:, None] == jnp.arange(E)
+    g, u = jnp.split(jnp.einsum("td,edf->tef", x, w_in), 2, axis=-1)
+    each = jnp.einsum("tef,efd->ted", jax.nn.silu(g) * u, w_out)
+    return jnp.einsum("ted,te->td", each, jnp.where(chosen, probs, 0.0))
+
+
+def test_the_two_top1_shares_add_up_to_the_uncut_layer():
+    args = _weights(6)
+    with jax.default_matmul_precision("highest"):
+        low, high = (_share_top1(*args, first, 8) for first in (0, 8))
+        whole = _uncut_top1(*args)
+    _close(low + high, whole)
+    # A token's one expert is on one chip: its row of the other is zero.
+    on_low = np.asarray(jnp.abs(low).max(-1) > 0)
+    on_high = np.asarray(jnp.abs(high).max(-1) > 0)
+    assert (on_low ^ on_high).all() and 8 < on_low.sum() < T - 8
+
+
+def test_top1_with_every_token_on_one_held_expert():
+    """A selection bias sends every token to expert 5: one group holds
+    the whole buffer, the gate stays the probability the bias does not
+    see, and the other chip's share is exactly zero."""
+    args = _weights(7)
+    bias = jnp.zeros(E).at[5].set(10.0)
+    with jax.default_matmul_precision("highest"):
+        out = _share_top1(*args, 0, 8, bias=bias)
+        want = _uncut_top1(*args, bias=bias)
+        other = _share_top1(*args, 8, 8, bias=bias)
+    _close(out, want)
+    assert float(jnp.abs(other).max()) == 0.0
+    gate, expert, load = moe.route_by_probability(args[0] @ args[1], 1, bias)
+    assert (np.asarray(expert) == 5).all() and float(load[5]) == 1.0
+    np.testing.assert_allclose(
+        gate[:, 0], jax.nn.softmax(args[0] @ args[1], axis=-1)[:, 5], rtol=1e-6
+    )
+
+
+def test_top1_gate_is_the_probability_and_reaches_the_router():
+    """At k = 1 a gate normalised over the chosen is 1 for every token
+    and its gradient to the router is zero; the probability is neither."""
+    x, router, w_in, w_out = _weights(8)
+    logits = x @ router
+    gate, expert, load = moe.route_by_probability(logits, 1)
+    probs = jax.nn.softmax(logits, axis=-1)
+    np.testing.assert_allclose(gate[:, 0], probs.max(-1), rtol=1e-6)
+    assert (np.asarray(expert[:, 0]) == np.asarray(probs.argmax(-1))).all()
+    assert float(gate.max()) < 1.0 and float(load.sum()) == pytest.approx(1.0)
+    normalised, same_expert, _ = moe.route_top_k(logits, 1)
+    assert (np.asarray(normalised) == 1.0).all()
+    assert (np.asarray(same_expert) == np.asarray(expert)).all()
+
+    def loss(router, route):
+        gate, expert, _ = route(x @ router, 1)
+        out = moe.held_experts_moe(x, gate, expert, w_in[:8], w_out[:8], 0, E)
+        return jnp.sum(out ** 2)
+
+    with jax.default_matmul_precision("highest"):
+        grad = jax.grad(loss)(router, moe.route_by_probability)
+        cut_off = jax.grad(loss)(router, moe.route_top_k)
+        # Experts 8..15 zeroed: the uncut layer is then the held share.
+        want = jax.grad(
+            lambda r: jnp.sum(_uncut_top1(x, r, w_in.at[8:].set(0.0), w_out) ** 2)
+        )(router)
+    _close(grad, want, 1e-4)
+    # p / p has a gradient of rounding alone.
+    assert float(jnp.abs(cut_off).max()) < 1e-4 * float(jnp.abs(grad).max())
+
+
+def test_head_follows_the_held_share():
+    """The row buffer's head is one and a half balanced loads: three
+    eighths of the slots with a quarter of the experts held (Mellum 2, as
+    before this was an argument), three quarters with half of them held
+    (ZAYA1), everything where all are."""
+    slots = 2 * 16384 * 8
+    assert moe._head_rows(slots, (16, 64)) == 3 * slots // 8 == 98304
+    slots = 2 * 16384
+    assert moe._head_rows(slots, (8, 16)) == 3 * slots // 4 == 24576
+    assert moe._head_rows(slots, (16, 16)) == slots
+    assert moe._head_rows(slots + 512, (4, 16)) == 12800  # whole row tiles
+    assert moe._head_rows(4096, (1, 64)) == 4096  # a small buffer is all head
+
+
+def test_balanced_half_held_step_stays_in_the_head(monkeypatch):
+    """Balanced top-1 routing with 8 of 16 experts held fills HALF the
+    slots: inside a head of three quarters (the rest is not entered),
+    past one of three eighths — the size that was Mellum's."""
+    monkeypatch.setattr(moe, "_TILE_ROWS", 8)
+    tokens = 512
+    expert = jnp.arange(tokens, dtype=jnp.int32).reshape(tokens, 1) % E
+    key = jnp.where(expert < 8, expert, 8)
+    order, pos, sizes = moe._plan(key, 8)
+    assert int(sizes.sum()) == tokens // 2
+    held = key < 8
+    overflows, parts = moe._parts(order, pos.reshape(held.shape), sizes, held, (8, E))
+    assert not bool(overflows)
+    assert parts[0][0].shape == (3 * tokens // 4,) and len(parts) == 2
+    past_mellums, _ = moe._parts(
+        order, pos.reshape(held.shape), sizes, held, (4, E)
+    )
+    assert bool(past_mellums)
+    # Two thirds more than balanced still fits; every token held does not.
+    crowded = jnp.where(jnp.arange(tokens)[:, None] % 4 < 3, expert % 8, 8)
+    order, pos, sizes = moe._plan(crowded, 8)
+    assert not bool(moe._parts(
+        order, pos.reshape(held.shape), sizes, crowded < 8, (8, E)
+    )[0])
+    order, pos, sizes = moe._plan(expert % 8, 8)
+    assert bool(moe._parts(
+        order, pos.reshape(held.shape), sizes, expert % 8 < 8, (8, E)
+    )[0])

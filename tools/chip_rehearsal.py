@@ -237,6 +237,43 @@ def grouped_products_case(rows: int, groups: int, d: int, f: int, device) -> tup
     return jax.jit(products), args
 
 
+def zaya_block_case(silos: int, batch: int, seq: int, device) -> tuple:
+    """(jitted fwd+bwd of ONE ``ZayaBlock`` at ZAYA1-8B's published
+    widths — compressed convolutional attention, the MLP router with the
+    router state of the layer below, 8 of 16 experts held — under the
+    engine's vmap over ``silos``, args)."""
+    from tpfl.models.zaya import ZayaBlock
+
+    block = ZayaBlock(
+        heads=8, kv_heads=2, head_dim=128, conv_time=2, conv_head=2,
+        rotary_fraction=0.5, theta=5e6, n_experts=16, expert_dim=2048,
+        router_dim=256, held_experts=8, first_expert=0, norm_eps=1e-5, out_std=0.001,
+        compute_dtype=jnp.bfloat16,
+    )
+    x = jax.ShapeDtypeStruct((batch, seq, 2048), jnp.bfloat16)
+    z = jax.ShapeDtypeStruct((batch, seq, 256), jnp.float32)
+    variables = jax.eval_shape(
+        lambda x, z: block.init(jax.random.PRNGKey(0), x, z), x, z
+    )
+    params = variables["params"]
+    stats = jax.tree_util.tree_map(
+        lambda a: jnp.zeros(a.shape, a.dtype), variables["moe_stats"]
+    )
+
+    def loss(params, x, z):
+        def one(p, x, z):
+            out, z = block.apply({"params": p, "moe_stats": stats}, x, z)
+            return jnp.sum(out.astype(jnp.float32) ** 2) + jnp.sum(z ** 2)
+
+        return jnp.sum(jax.vmap(one)(params, x, z))
+
+    sharding = SingleDeviceSharding(device)
+    stacked = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct((silos, *a.shape), a.dtype), (params, x, z)
+    )
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))), _sds(stacked, sharding)
+
+
 def scan_case(shape, states: int, silos: int, device) -> tuple:
     """(jitted fwd+bwd of the selective scan's Pallas kernels under the
     engine's vmap over ``silos``, args) for ``shape = (batch, S, D)``."""
@@ -443,6 +480,19 @@ def cases(devices) -> dict:
         "grouped_products_mellum_x2": lambda: grouped_products_case(
             98304, 32, 2304, 896, d0
         ),
+        # ZAYA1-8B's cell: the attention call inside the latent (2 key
+        # heads, 4 query heads a key head, d = 128: block 512 exactly at
+        # ``_TILE_BYTES``), the experts' grouped products on the head of
+        # the row buffer (three quarters of 2 silos x 16384 tokens x 1
+        # choice, 2 x 8 experts of 2048 x 4096: tiles of 1024), and one
+        # whole block (the convolutions, the router MLP and its state).
+        "block_attention_zaya_x2": lambda: block_attention_case(
+            2, (2, 8192, 8, 128), (2, 8192, 2, 128), (2, 8192, 2, 128), d0
+        ),
+        "grouped_products_zaya_x2": lambda: grouped_products_case(
+            24576, 16, 2048, 2048, d0
+        ),
+        "zaya_block_x2": lambda: zaya_block_case(2, 1, 8192, d0),
         # The Mamba layer of the benchmark's SambaY cell: one 8192-token
         # sequence a silo, 5120 channels x 16 states, two silos vmapped.
         "ssm_scan_8k_x2": lambda: scan_case((1, 8192, 5120), 16, 2, d0),

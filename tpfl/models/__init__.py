@@ -11,8 +11,9 @@ stay float32.
 
 from tpfl.models.mellum import MellumLM
 from tpfl.models.sambay import SambaYLM
+from tpfl.models.zaya import ZayaLM
 from tpfl.models.zoo import (CNN, MLP, ResNet18, TransformerBlock,
                              TransformerLM, create_model)
 
 __all__ = ["MLP", "CNN", "ResNet18", "TransformerBlock",
-           "TransformerLM", "SambaYLM", "MellumLM", "create_model"]
+           "TransformerLM", "SambaYLM", "MellumLM", "ZayaLM", "create_model"]

@@ -189,7 +189,7 @@ class MellumMoE(nn.Module):
         with jax.named_scope("moe_dispatch"):
             rows = tokens.astype(self.compute_dtype)
         out = held_experts_moe(
-            rows, gate, expert, w_in, w_out, self.first_expert
+            rows, gate, expert, w_in, w_out, self.first_expert, self.n_experts
         )
         return out.reshape(b, s, dim)
 

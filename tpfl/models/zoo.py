@@ -19,6 +19,7 @@ from tpfl.learning.model import TpflModel
 from tpfl.models.head_loss import head_cross_entropy
 from tpfl.models.mellum import MellumLM
 from tpfl.models.sambay import SambaYLM
+from tpfl.models.zaya import ZayaLM
 
 
 class MLP(nn.Module):
@@ -263,7 +264,7 @@ def create_model(
     """Initialize a flax module into a :class:`TpflModel`.
 
     ``module`` may be a module instance or a zoo name ("mlp", "cnn",
-    "resnet18", "transformer_lm", "sambay_lm", "mellum_lm").
+    "resnet18", "transformer_lm", "sambay_lm", "mellum_lm", "zaya_lm").
     ``input_shape`` excludes the batch dimension.
     """
     if isinstance(module, str):
@@ -274,6 +275,7 @@ def create_model(
             "transformer_lm": TransformerLM,
             "sambay_lm": SambaYLM,
             "mellum_lm": MellumLM,
+            "zaya_lm": ZayaLM,
         }
         if module not in zoo:
             raise KeyError(f"Unknown model {module!r}; have {sorted(zoo)}")

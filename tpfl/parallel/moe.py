@@ -2,7 +2,8 @@
 ``ep``-axis originals.
 
 **On the engine's path** — :func:`held_experts_moe` (with
-:func:`route_top_k`): a DROPLESS top-k SwiGLU layer that is told which
+:func:`route_top_k`, or :func:`route_by_probability` where the gate is
+the probability itself): a DROPLESS top-k SwiGLU layer that is told which
 experts it holds. The router keeps its published width (all ``E``
 experts) and its ``k`` experts a token; this chip computes the part of
 the result that its ``n_held`` experts give — the (token, choice) pairs
@@ -18,8 +19,9 @@ exchange). Under the engine's ``jax.vmap`` over silos the batch FOLDS
 into the groups (one row buffer, ``silos x n_held`` groups: no batched
 kernel and no loop over silos); it runs inside ``nn.remat``; its
 backward recomputes its row buffer, so nothing of the buffer's size is
-banked. ``tpfl.models.MellumLM`` is its user; the benchmark cell
-``mellum2_silo_8k`` runs it.
+banked. ``tpfl.models.MellumLM`` (top-8 of 64, a quarter held) and
+``tpfl.models.ZayaLM`` (top-1 of 16, half held) are its users; the
+benchmark cells ``mellum2_silo_8k`` and ``zaya1_silo_8k`` run it.
 
 **The ``ep``-axis originals, which no cell and no zoo model runs** —
 ``make_moe_layer`` (top-1 serving dispatch) and ``make_moe_train_layer``
@@ -51,6 +53,13 @@ from tpfl.parallel.compat import shard_map
 # --- the held-experts layer (the engine's path) -------------------------------
 
 
+def _load(expert: jnp.ndarray, n_experts: int) -> jnp.ndarray:
+    """The share of the token-choices ``expert [T, k]`` each of the
+    ``n_experts`` received."""
+    chosen = expert[..., None] == jnp.arange(n_experts)
+    return jnp.sum(chosen, axis=(0, 1), dtype=jnp.float32) / expert.size
+
+
 def route_top_k(logits: jnp.ndarray, k: int) -> tuple:
     """``logits [T, E]`` -> ``(gate [T, k], expert [T, k], load [E])``:
     softmax over ALL ``E`` experts in float32, the ``k`` largest, their
@@ -59,9 +68,23 @@ def route_top_k(logits: jnp.ndarray, k: int) -> tuple:
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     top_p, top_e = lax.top_k(probs, k)
     gate = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-    chosen = top_e[..., None] == jnp.arange(logits.shape[-1])
-    load = jnp.sum(chosen, axis=(0, 1), dtype=jnp.float32) / top_e.size
-    return gate, top_e, load
+    return gate, top_e, _load(top_e, logits.shape[-1])
+
+
+def route_by_probability(
+    logits: jnp.ndarray, k: int, bias: "jnp.ndarray | float" = 0.0
+) -> tuple:
+    """:func:`route_top_k` with the gate left as it is: ``gate [T, k]``
+    is the chosen experts' PROBABILITY under the softmax over all ``E``,
+    not normalised over the ``k`` — at ``k = 1`` normalising makes every
+    gate 1 and cuts the router off from the loss. ``bias [E]`` (a
+    load-balancing bias kept as state, not trained by the loss:
+    ``ZayaLM``'s ``balance_bias``) moves the CHOICE, ``top_k(probs +
+    bias)``, and not the gate."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    _, top_e = lax.top_k(probs + lax.stop_gradient(bias), k)
+    gate = jnp.take_along_axis(probs, top_e, axis=-1)
+    return gate, top_e, _load(top_e, logits.shape[-1])
 
 
 # The three grouped products. ``sizes [G]`` splits the leading rows of a
@@ -81,17 +104,36 @@ def route_top_k(logits: jnp.ndarray, k: int) -> tuple:
 #: Rows a tile of the Pallas kernels, at most (a buffer that is no
 #: multiple takes the largest power of two that divides it, down to a
 #: sublane tile); the other two tile sizes are ``_tile`` of the widths
-#: (2304 -> 768, 896 -> 896, 1792 -> 896: whole divisors, within the
-#: 16 MB of VMEM a kernel gets by default; 1024 rows do not fit it).
+#: (2304 -> 768, 896 -> 896, 1792 -> 896, 2048 and 4096 -> 1024: whole
+#: divisors, within the 16 MiB of VMEM a kernel gets by default; 1024
+#: rows do not fit it).
 _TILE_ROWS = 512
+#: What the weight-gradient kernel's tiles may take of those 16 MiB (its
+#: float32 output tile is held three times over: ``_weight_grad_tiles``).
+_VMEM_BYTES = 14 * 2**20
 
 
-def _tile(width: int) -> int:
-    """The largest multiple of 128 up to 1024 that divides ``width``;
-    ``width`` itself where none does (a block as wide as the array is
-    always legal)."""
-    fits = [t for t in range(128, 1024 + 1, 128) if width % t == 0]
+def _tile(width: int, most: int = 1024) -> int:
+    """The largest multiple of 128 up to ``most`` that divides
+    ``width``; ``width`` itself where none does (a block as wide as the
+    array is always legal)."""
+    fits = [t for t in range(128, most + 1, 128) if width % t == 0]
     return max(fits) if fits else width
+
+
+def _weight_grad_tiles(rows: int, k: int, n: int) -> tuple:
+    """Tiles of ``_gmm_tn``'s kernel, ``(rows, k, n)``: ``_tile`` of
+    each width, the wider halved (to its next divisor) while the float32
+    output tile — an accumulator and a double-buffered block, 12 bytes
+    an element — and the double-buffered bf16 operand tiles pass
+    ``_VMEM_BYTES``: 768 x 896 stands, 1024 x 1024 becomes 1024 x 512."""
+    tm, tk, tn = _row_tile(rows), _tile(k), _tile(n)
+    while 12 * tk * tn + 4 * tm * (tk + tn) > _VMEM_BYTES and max(tk, tn) > 128:
+        if tn >= tk:
+            tn = _tile(n, tn - 128)
+        else:
+            tk = _tile(k, tk - 128)
+    return tm, tk, tn
 
 
 def _row_tile(rows: int) -> int:
@@ -155,7 +197,7 @@ def _gmm_tn(rows, grads, sizes):
     if _pallas(rows.shape[0]):
         return _megablox().tgmm(
             rows.T, grads, sizes, jnp.float32,
-            (_row_tile(rows.shape[0]), _tile(rows.shape[1]), _tile(grads.shape[1])),
+            _weight_grad_tiles(rows.shape[0], rows.shape[1], grads.shape[1]),
             interpret=compat.pallas_interpret(None),
         )
     return lax.ragged_dot_general(
@@ -168,10 +210,11 @@ def _gmm_tn(rows, grads, sizes):
     )
 
 
-def _fold_silos(fn: Callable) -> Callable:
-    """``fn(x [T, d], gate [T, k], key [T, k], w_in [G, ..], w_out [G,
-    ..], *token_arrays) -> (token arrays.., group arrays..)`` made
-    batchable by FOLDING the batch into the groups: under ``vmap`` over
+def _fold_silos(fn: Callable, share: tuple) -> Callable:
+    """``fn(share, x [T, d], gate [T, k], key [T, k], w_in [G, ..],
+    w_out [G, ..], *token_arrays) -> (token arrays.., group arrays..)``
+    (``share`` static: the layer's held share, which no fold changes)
+    made batchable by FOLDING the batch into the groups: under ``vmap`` over
     S silos it is one call on ``S T`` tokens and ``S G`` groups (silo
     ``s``'s group ``g`` is group ``s G + g``; key ``G``, "not held",
     becomes ``S G``), so the live rows of all silos lie side by side at
@@ -179,7 +222,7 @@ def _fold_silos(fn: Callable) -> Callable:
     dimension. A result whose leading size is the tokens' unfolds as a
     token array, one whose leading size is the groups' as a group
     array."""
-    folded = jax.custom_batching.custom_vmap(fn)
+    folded = jax.custom_batching.custom_vmap(partial(fn, share))
 
     @folded.def_vmap
     def rule(axis_size, in_batched, x, gate, key, w_in, w_out, *more):
@@ -238,29 +281,33 @@ def _plan(key, groups: int) -> tuple:
     return order, pos, counts[:groups].astype(jnp.int32)
 
 
-def _head_rows(slots: int) -> int:
+def _head_rows(slots: int, share: tuple) -> int:
     """Slots of the row buffer's HEAD, the part the layer always works
     on. The held pairs lie sorted at the front of ``slots`` = ``k T``
     slots, the worst case; everything that is no grouped product
     (gathers, the gates, their gradients) costs by the SLOT. So the
-    layer splits the buffer in two: a head of three eighths of the
-    slots — one and a half times the load of balanced routing when a
-    quarter of the experts is held — and the rest, which it enters only
-    when a live row lies there (``lax.cond``): as a rule never, always
-    when every choice of every token is held. A small buffer is all
-    head."""
+    layer splits the buffer in two: a head of one and a half times the
+    load of balanced routing — ``share`` = (experts held, experts the
+    router chooses among): three eighths of the slots when a quarter of
+    the experts is held, three quarters when half of them is — and the
+    rest, which it enters only when a live row lies there
+    (``lax.cond``): as a rule never, always when every choice of every
+    token is held. A small buffer, or one whose experts are all held,
+    is all head."""
+    n_held, n_experts = share
     if slots <= 8 * _TILE_ROWS:
         return slots
-    return -(-(3 * slots // 8) // _TILE_ROWS) * _TILE_ROWS
+    balanced_x3 = 3 * slots * n_held // (2 * n_experts)
+    return min(slots, -(-balanced_x3 // _TILE_ROWS) * _TILE_ROWS)
 
 
-def _parts(order, pos, sizes, held) -> tuple:
+def _parts(order, pos, sizes, held, share) -> tuple:
     """(whether a live row lies past the head, the buffer's parts): a
     part is (its slice of ``order``, the rows of each group inside it,
     and for the way back each pair's row within it with whether the
     pair is held AND there)."""
     slots = order.shape[0]
-    head = _head_rows(slots)
+    head = _head_rows(slots, share)
     ends = jnp.cumsum(sizes)
 
     def part(lo: int, hi: int) -> tuple:
@@ -309,15 +356,16 @@ def _expert_rows(x, gate, order, sizes, w_in, k: int):
     return rows, row_gate, jnp.split(_gmm(rows, w_in, sizes), 2, axis=-1)
 
 
-@_fold_silos
-def _moe_forward(x, gate, key, w_in, w_out):
+def _moe_forward(share, x, gate, key, w_in, w_out):
     """(y [T, d], the plan: order and pos [T k])."""
     groups, k = w_in.shape[0], key.shape[1]
     f32, dtype = jnp.float32, x.dtype
     held = key < groups
     with jax.named_scope("moe_dispatch"):
         order, pos, sizes = _plan(key, groups)
-        overflows, parts = _parts(order, pos.reshape(held.shape), sizes, held)
+        overflows, parts = _parts(
+            order, pos.reshape(held.shape), sizes, held, share
+        )
     with jax.named_scope("moe_experts"):
         w_in, w_out = w_in.astype(dtype), w_out.astype(dtype)
 
@@ -339,8 +387,7 @@ def _moe_forward(x, gate, key, w_in, w_out):
         return y.astype(dtype), order, pos
 
 
-@_fold_silos
-def _moe_backward(x, gate, key, w_in, w_out, dy, order, pos):
+def _moe_backward(share, x, gate, key, w_in, w_out, dy, order, pos):
     """(dx, dgate, dw_in, dw_out) from the layer's inputs, the forward's
     plan and ``dy``: a RECOMPUTE backward — a part's rows and its first
     product are made again, so the forward banks nothing of the buffer's
@@ -352,7 +399,9 @@ def _moe_backward(x, gate, key, w_in, w_out, dy, order, pos):
         sizes = jnp.sum(
             key.reshape(-1, 1) == jnp.arange(groups), axis=0, dtype=jnp.int32
         )
-        overflows, parts = _parts(order, pos.reshape(held.shape), sizes, held)
+        overflows, parts = _parts(
+            order, pos.reshape(held.shape), sizes, held, share
+        )
     with jax.named_scope("moe_experts"):
         w_in_c, w_out_c = w_in.astype(dtype), w_out.astype(dtype)
 
@@ -398,19 +447,19 @@ def _moe_backward(x, gate, key, w_in, w_out, dy, order, pos):
     )
 
 
-@jax.custom_vjp
-def _held_experts(x, gate, key, w_in, w_out):
-    return _moe_forward(x, gate, key, w_in, w_out)[0]
+@partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _held_experts(share, x, gate, key, w_in, w_out):
+    return _fold_silos(_moe_forward, share)(x, gate, key, w_in, w_out)[0]
 
 
-def _held_experts_fwd(x, gate, key, w_in, w_out):
-    y, order, pos = _moe_forward(x, gate, key, w_in, w_out)
+def _held_experts_fwd(share, x, gate, key, w_in, w_out):
+    y, order, pos = _fold_silos(_moe_forward, share)(x, gate, key, w_in, w_out)
     return y, (x, gate, key, w_in, w_out, order, pos)
 
 
-def _held_experts_bwd(res, dy):
+def _held_experts_bwd(share, res, dy):
     x, gate, key, w_in, w_out, order, pos = res
-    dx, d_gate, d_w_in, d_w_out = _moe_backward(
+    dx, d_gate, d_w_in, d_w_out = _fold_silos(_moe_backward, share)(
         x, gate, key, w_in, w_out, dy, order, pos
     )
     return dx, d_gate, None, d_w_in, d_w_out
@@ -421,12 +470,15 @@ _held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 def held_experts_moe(
     x: jnp.ndarray, gate: jnp.ndarray, expert: jnp.ndarray,
-    w_in: jnp.ndarray, w_out: jnp.ndarray, first: int = 0,
+    w_in: jnp.ndarray, w_out: jnp.ndarray, first: int, n_experts: int,
 ) -> jnp.ndarray:
     """The held experts' part of a top-k SwiGLU expert layer.
 
     ``x [T, d]`` tokens (the compute dtype); ``gate [T, k]`` float32 and
-    ``expert [T, k]`` int32 from :func:`route_top_k` over ALL experts;
+    ``expert [T, k]`` int32 from :func:`route_top_k` or
+    :func:`route_by_probability` over ALL ``n_experts`` experts (the
+    router's width, static: it sizes the row buffer's head,
+    ``_head_rows``, and nothing else);
     ``w_in [n_held, d, 2 f]`` (gate and up projections side by side) and
     ``w_out [n_held, f, d]`` the weights of experts ``first .. first +
     n_held - 1``, the ones this chip holds (any float dtype: multiplied
@@ -434,8 +486,9 @@ def held_experts_moe(
     float32 accumulators unrounded, as ``head_cross_entropy``'s).
     Returns ``sum over a token's choices e that are held of gate_e *
     down_e(silu(gate_e x) * up_e x)`` as ``[T, d]`` in ``x``'s dtype —
-    ``gate`` stays normalised over all ``k`` choices, so the shares of
-    the chips that hold the other experts add up to the whole layer.
+    ``gate`` is used as it comes (normalised over all ``k`` choices or
+    not), so the shares of the chips that hold the other experts add up
+    to the whole layer.
 
     Dropless: the (token, choice) pairs whose expert is held are sorted
     by expert, the others behind them, and their tokens gathered into a
@@ -453,7 +506,9 @@ def held_experts_moe(
     n_held = w_in.shape[0]
     local = expert - first
     key = jnp.where((local >= 0) & (local < n_held), local, n_held)
-    return _held_experts(x, gate, key.astype(jnp.int32), w_in, w_out)
+    return _held_experts(
+        (n_held, n_experts), x, gate, key.astype(jnp.int32), w_in, w_out
+    )
 
 
 # --- the ep-axis originals -----------------------------------------------------
