@@ -4,7 +4,7 @@
 One process, the entry points a user calls, one model of the zoo at
 full width (depth and step counts are what is cut; weights and data
 are random, made from ``--seed``). With no arguments it needs ONE TPU
-chip and runs five phases:
+chip and runs six phases:
 
 - ``engine`` — ``VmapFederation`` -> ``FederationEngine``: ResNet-18
   (100 classes) x 16 nodes, 2 batches of 128 32x32x3 images, FedAvg
@@ -23,6 +23,11 @@ chip and runs five phases:
   and ``blockwise_attention`` with a band (window 1024, 8 query heads
   a key head of 128, 8192 tokens) against the same under the band's
   mask.
+- ``experts`` — the expert layer's way back to tokens
+  (``tpfl.parallel.moe_kernel``, the Pallas kernel a TPU runs) against
+  the gather it replaces, on the head of ``mellum2_silo_8k``'s row
+  buffer: 2 silos x 16384 tokens x 8 choices of 64 experts, 16 held,
+  2304-wide bf16 rows, NaN past the groups; both timed (information).
 - ``sync`` — INFORMATION for the benchmark PR: one window timed with
   ``jax.block_until_ready`` and with the scalar fetch, plus the
   dispatch round trip.
@@ -69,6 +74,12 @@ FLASH_PARITY_TOL = 2e-2
 #: The band of the banded comparison: Mellum 2's published window.
 MELLUM_WINDOW = 1024
 
+#: The expert layer's way back to tokens as the Pallas kernel against
+#: the gather, float32 results of bf16 rows: max |a - b| / max |b|. A
+#: product by 0 or 1 is exact, so the two differ by the ORDER of a
+#: float32 sum of a token's at most 8 rows: a few units of 2^-24.
+RETURN_PARITY_TOL = 1e-6
+
 #: Mesh vs one device, mean last-round loss, relative: 2%, measured
 #: 1e-5 to 2e-5 on the chip (ROADMAP S1, PR 21). Reduction order differs
 #: (per-device partial sums + all-reduce), and on the 2D mesh the
@@ -103,6 +114,7 @@ class Sizes:
     lm_steps: int = 3
     parity_seq: int = 2048
     band_seq: int = 8192
+    moe_tokens: int = 16384
     mesh_lm_seq: int = 2048
     mesh_lm_batch: int = 8
 
@@ -623,6 +635,73 @@ def phase_kernel(ph: Phase, sz: Sizes, seed: int) -> None:
     )
 
 
+def phase_experts(ph: Phase, sz: Sizes, seed: int) -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from tpfl.parallel import moe
+
+    # Mellum 2's layer as its cell folds it: 2 silos, 8 choices of 64
+    # experts a token, this chip's 16 held, rows of the hidden width.
+    silos, tokens, k, held, experts, d = 2, sz.moe_tokens, 8, 16, 64, 2304
+    groups = silos * held
+    k_route, k_rows = jax.random.split(jax.random.PRNGKey(seed))
+    _, expert = jax.lax.top_k(
+        jax.random.normal(k_route, (silos, tokens, experts)), k
+    )
+    silo = jnp.arange(silos)[:, None, None]
+    key = jnp.where(expert < held, expert + silo * held, groups)
+    key = key.reshape(silos * tokens, k).astype(jnp.int32)
+    order, pos, sizes = moe._plan(key, groups)
+    live = int(sizes.sum())
+    is_held = key < groups
+    _, parts = moe._parts(
+        order, pos.reshape(key.shape), sizes, is_held, (held, experts)
+    )
+    x = jax.ShapeDtypeStruct((silos * tokens, d), jnp.bfloat16)
+    by_gather = (*parts[0], None)
+    by_kernel = moe._with_runs(parts[:1], x, key, (held, experts))[0]
+    n = parts[0][0].shape[0]
+    # Past the groups a buffer holds whatever its kernels left: NaN here.
+    rows = jnp.where(
+        jnp.arange(n)[:, None] < live,
+        jax.random.normal(k_rows, (n, d)), jnp.nan,
+    ).astype(jnp.bfloat16)
+    back = jax.jit(partial(moe._rows_to_tokens, k=k))
+
+    def timed(part):
+        out = jax.block_until_ready(back(rows, part))
+        t0 = time.perf_counter()
+        for _ in range(5):
+            out = back(rows, part)
+        jax.block_until_ready(out)
+        return out, (time.perf_counter() - t0) / 5 * 1e3
+
+    want, gather_ms = timed(by_gather)
+    ph.check(bool(jnp.isfinite(want).all()), "the gather's sums are finite")
+    ph.check(live <= n, f"the live rows ({live}) lie in the head ({n})")
+    got, kernel_ms = timed(by_kernel if by_kernel[-1] is not None else by_gather)
+    err = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+    ph.check(
+        err <= RETURN_PARITY_TOL,
+        f"moe_rows_to_tokens ~ the gather back to tokens, bf16 rows, float32 "
+        f"sums: within {RETURN_PARITY_TOL} (worst {err:.3g})",
+    )
+    ph.facts.update(
+        shape={"tokens": silos * tokens, "k": k, "groups": groups,
+               "rows": n, "live_rows": live, "d": d, "dtype": "bf16"},
+        return_parity_rel_err=float(f"{err:.3g}"),
+        gather_ms_per_call=round(gather_ms, 3),
+        kernel_ms_per_call=round(kernel_ms, 3),
+        kernel_live_gb_per_s=round(live * d * 2 / kernel_ms / 1e6, 1),
+    )
+    # Last: what a CPU cannot hold (tests/test_chip_smoke.py).
+    ph.check(
+        by_kernel[-1] is not None,
+        "the way back to tokens runs the Pallas kernel here",
+    )
+
+
 def phase_sync(ph: Phase, sz: Sizes, seed: int) -> None:
     """Does ``block_until_ready`` block, and what does a dispatch cost,
     on THIS host? Information for the benchmark PR; the one assertion
@@ -894,6 +973,7 @@ def main(argv: "list[str] | None" = None) -> int:
             ("engine", partial(phase_engine, sz=sz, seed=seed, meter=meter)),
             ("gossip", partial(phase_gossip, seed=seed)),
             ("kernel", partial(phase_kernel, sz=sz, seed=seed)),
+            ("experts", partial(phase_experts, sz=sz, seed=seed)),
             ("sync", partial(phase_sync, sz=sz, seed=seed)),
             ("cache", partial(phase_cache, meter=meter, cache_dir=cache_dir)),
         ]

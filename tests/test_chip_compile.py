@@ -63,6 +63,11 @@ def described():
         # six grouped products a step are the megablox kernels.
         ("block_attention_mellum_x2", 2, 0),
         ("grouped_products_mellum_x2", 6, 0),
+        # The way back from that head's rows to the tokens, and from the
+        # buffer's rest (PR 35): one kernel over 64 token tiles of 512, a
+        # tile's runs copied block by block.
+        ("rows_to_tokens_mellum_x2", 1, 0),
+        ("rows_to_tokens_mellum_rest_x2", 1, 0),
         # The band inside the kernels (PR 33), the block left to
         # ``blockwise_attention``: Mellum 2's window of 1024 at block 256
         # (a sweep of five steps, dq resident as in the full layer) and
@@ -76,7 +81,8 @@ def described():
         # gradient's cut to 1024 x 512 to fit VMEM); and ONE whole block
         # — the convolutions, the router MLP and its state, the head of
         # the row buffer and its rest: 2 attention kernels + 2 x 7
-        # grouped products.
+        # grouped products (its way back to tokens stays the gather: one
+        # choice a token, ``moe._RUN_SLOTS``).
         ("block_attention_zaya_x2", 2, 0),
         ("grouped_products_zaya_x2", 6, 0),
         ("zaya_block_x2", 16, 0),

@@ -187,6 +187,7 @@ def test_one_chip_phases_walk_through_on_cpu(monkeypatch, tmp_path):
     sz = chip_smoke.Sizes(
         resnet_nodes=2, resnet_batches=1, cnn_nodes=4, cnn_batches=1,
         batch=8, sync_rounds=1, lm_seq=256, parity_seq=256, band_seq=1280,
+        moe_tokens=256,
     )
     cache_dir = profiling.ensure_compile_cache(str(tmp_path / "cache"))
     meter = chip_smoke.CompileMeter().install()
@@ -206,6 +207,14 @@ def test_one_chip_phases_walk_through_on_cpu(monkeypatch, tmp_path):
     ]
     with pytest.raises(chip_smoke.SmokeFailure, match="tpu_custom_call"):
         chip_smoke.phase_kernel(chip_smoke.Phase("kernel"), sz=sz, seed=0)
+    # The gather against itself off a TPU; steered onto the TPU branch,
+    # the kernel (in the emulator) against the gather, every check held.
+    with pytest.raises(chip_smoke.SmokeFailure, match="runs the Pallas kernel"):
+        chip_smoke.phase_experts(chip_smoke.Phase("experts"), sz=sz, seed=0)
+    monkeypatch.setattr(compat, "on_tpu", lambda: True)
+    experts = chip_smoke.Phase("experts")
+    chip_smoke.phase_experts(experts, sz=sz, seed=0)
+    assert experts.facts["shape"]["rows"] == 2 * 256 * 8  # small: all head
 
 
 @pytest.mark.slow

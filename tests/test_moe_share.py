@@ -157,6 +157,178 @@ def test_pallas_form_equals_the_xla_form(monkeypatch):
     _close(pallas, xla, 1e-5)
 
 
+#: The way back to tokens as the Pallas kernel against the gather, a
+#: case: (tokens a silo, choices a token, first held expert, held
+#: experts, ``_TILE_ROWS``, ``TOKEN_TILE``, the buffer's parts, bias).
+_HELD = (jnp.arange(E) >= 4) & (jnp.arange(E) < 12)
+_RETURN_CASES = {
+    # 8 tiles of 16 tokens over 16 groups: a run is ~4 rows of a block.
+    "balanced": (64, K, 4, 8, 128, 16, 1, 0.0),
+    # Every token on expert 6: a tile's run there is 256 rows, two or
+    # three blocks long; the buffer has a rest, not entered.
+    "one_expert": (256, K, 4, 8, 128, 256, 2, jnp.zeros(E).at[6].set(80.0)),
+    # Expert 5 is chosen by no token (an empty group in every tile), and
+    # the tokens of each silo's second tile choose no held expert at all.
+    "empty_group_and_tile": (
+        64, K, 4, 8, 128, 16, 1,
+        jnp.zeros(E).at[5].set(-80.0)
+        + jnp.where(
+            ((jnp.arange(64) // 16 == 1)[:, None]) & _HELD, -80.0, 0.0
+        ),
+    ),
+    # Every choice of every token held: 512 live rows, a head of 384.
+    "past_the_head": (64, K, 4, 8, 8, 16, 2, jnp.where(_HELD, 50.0, 0.0)),
+    # ZAYA's share: one expert a token, half the experts held.
+    "top1_half_held": (256, 1, 0, 8, 128, 32, 1, 0.0),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("case", list(_RETURN_CASES))
+def test_return_kernel_equals_the_gather(monkeypatch, case, dtype):
+    """``_rows_to_tokens`` as the Pallas kernel (``moe_kernel``, here in
+    the emulator) against the gather it replaces on a TPU: value and all
+    four gradients of the layer under ``vmap`` over 2 silos, everything
+    else the same (the grouped products are the Pallas ones on both
+    sides). float32 rows: the 0 / 1 product runs at the highest
+    precision, so both forms make the same float32 sum in another order
+    — 1e-6. bf16 rows: a product by 0 or 1 is exact and the float32 sums
+    differ by their order alone, but the layer then rounds that sum to
+    bf16, where a difference in the last float32 bit can flip a rounding:
+    one bf16 step is 2^-8 of an element, so 2^-7 of the largest (nearly
+    every element is equal bit for bit)."""
+    from tpfl.parallel import moe_kernel
+
+    tokens, k, first, count, tile_rows, token_tile, n_parts, bias = _RETURN_CASES[case]
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    d, f = 128, 16
+    args = (
+        jax.random.normal(ks[0], (2, tokens, d)).astype(dtype),
+        jax.random.normal(ks[1], (2, d, E)),
+        jax.random.normal(ks[2], (2, E, d, 2 * f)) / np.sqrt(d),
+        jax.random.normal(ks[3], (2, E, f, d)) / np.sqrt(f),
+    )
+
+    def loss(x, router, w_in, w_out):
+        logits = x.astype(jnp.float32) @ router + bias
+        gate, expert, _ = (
+            moe.route_by_probability(logits, 1) if k == 1
+            else moe.route_top_k(logits, k)
+        )
+        held = slice(first, first + count)
+        out = moe.held_experts_moe(
+            x, gate, expert, w_in[held], w_out[held], first, E
+        )
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    monkeypatch.setattr(compat, "on_tpu", lambda: True)
+    monkeypatch.setattr(compat, "pallas_interpret", lambda _: True)
+    monkeypatch.setattr(moe, "_TILE_ROWS", tile_rows)
+    monkeypatch.setattr(moe_kernel, "TOKEN_TILE", token_tile)
+    monkeypatch.setattr(moe, "_RUN_SLOTS", 1)  # toy tiles: runs of a few slots
+    grad = jax.vmap(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)))
+
+    def kernels(fn):
+        return sum(
+            e.primitive.name == "pallas_call"
+            and e.params["name"] == "moe_rows_to_tokens"
+            for e in _eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+        )
+
+    with jax.default_matmul_precision("highest"):
+        # Forward and backward, each part of the buffer.
+        assert kernels(lambda *a: grad(*a)) == 2 * n_parts
+        through_kernel = jax.jit(lambda *a: grad(*a))(*args)
+        monkeypatch.setattr(moe_kernel, "tiles", lambda *a: 0)
+        assert kernels(lambda *a: grad(*a)) == 0
+        through_gather = jax.jit(lambda *a: grad(*a))(*args)
+    assert float(jnp.abs(through_gather[1][0]).max()) > 0.0
+    _close(
+        jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), through_kernel),
+        jax.tree_util.tree_map(lambda a: a.astype(jnp.float32), through_gather),
+        2.0 ** -7 if dtype == jnp.bfloat16 else 1e-6,
+    )
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_return_kernel_reads_no_value_outside_its_runs(monkeypatch, dtype):
+    """Past the groups a row buffer holds whatever its kernels left
+    there (here NaN), and a staged block holds other tiles' and other
+    groups' rows beside the run: the kernel zeroes them by ``where``
+    before its 0 / 1 product — never ``0 x NaN`` — and counts no row
+    twice. Against the gather on the same buffer: bf16 rows, products
+    by 0 and 1 exact, the float32 sums of at most ``K`` rows differ by
+    their order alone (1e-6 of the largest); float32 rows the same at
+    the highest precision."""
+    from tpfl.parallel import moe_kernel
+
+    monkeypatch.setattr(compat, "on_tpu", lambda: True)
+    monkeypatch.setattr(compat, "pallas_interpret", lambda _: True)
+    monkeypatch.setattr(moe_kernel, "TOKEN_TILE", 32)
+    monkeypatch.setattr(moe, "_RUN_SLOTS", 1)
+    tokens, d, groups = 256, 128, 8
+    k_route, k_rows = jax.random.split(jax.random.PRNGKey(12))
+    _, expert = jax.lax.top_k(jax.random.normal(k_route, (tokens, E)), K)
+    key = jnp.where(expert < groups, expert, groups).astype(jnp.int32)
+    order, pos, sizes = moe._plan(key, groups)
+    live = int(sizes.sum())
+    _, parts = moe._parts(
+        order, pos.reshape(key.shape), sizes, key < groups, (groups, E)
+    )
+    assert len(parts) == 1 and 256 < live < tokens * K - 128
+    rows = jnp.where(
+        jnp.arange(tokens * K)[:, None] < live,
+        jax.random.normal(k_rows, (tokens * K, d)), jnp.nan,
+    ).astype(dtype)
+    x = jax.ShapeDtypeStruct((tokens, d), dtype)
+    (by_kernel,) = moe._with_runs(parts, x, key, (groups, E))
+    assert by_kernel[-1] is not None
+    got = moe._rows_to_tokens(rows, by_kernel, K)
+    want = moe._rows_to_tokens(rows, (*parts[0], None), K)
+    assert got.dtype == want.dtype == jnp.float32
+    assert bool(jnp.isfinite(got).all())
+    _close(got, want, 1e-6)
+    # Off a TPU, and for rows the kernel does not take (no whole lane
+    # tile; a dtype the MXU would round), the gather stays.
+    assert moe_kernel.tiles((1024, 96), dtype, tokens, groups) == 0
+    assert moe_kernel.tiles((1000, d), dtype, tokens, groups) == 0
+    assert moe_kernel.tiles((1024, d), jnp.float16, tokens, groups) == 0
+    monkeypatch.setattr(compat, "on_tpu", lambda: False)
+    assert moe._with_runs(parts, x, key, (groups, E))[0][-1] is None
+
+
+@pytest.mark.parametrize(
+    "k, share, d, by_kernel",
+    [(8, (16, 64), 2304, True), (1, (8, 16), 2048, False)],
+    ids=["mellum2", "zaya1"],
+)
+def test_way_back_follows_the_slots_a_run(monkeypatch, k, share, d, by_kernel):
+    """Which form the way back takes on a TPU is read off shapes: the
+    slots the gather fetches for each run the kernel copies, ``k`` x the
+    token tile / the experts held. At the cells' shapes (2 silos x 16384
+    tokens, bf16): Mellum 2's 8 choices over 16 held — 256 slots a run —
+    take the kernel, head and rest; ZAYA1's one choice over 8 held — 64
+    — keeps the gather (measured both ways on the v5e: ``_RUN_SLOTS``)."""
+    monkeypatch.setattr(compat, "on_tpu", lambda: True)
+    tokens, groups = 2 * 16384, 2 * share[0]
+    x = jax.ShapeDtypeStruct((tokens, d), jnp.bfloat16)
+    forms = []
+
+    def plan(key):
+        order, pos, sizes = moe._plan(key, groups)
+        _, parts = moe._parts(
+            order, pos.reshape(key.shape), sizes, key < groups, share
+        )
+        forms.extend(
+            part[-1] is not None
+            for part in moe._with_runs(parts, x, key, share)
+        )
+        return sizes
+
+    jax.eval_shape(plan, jax.ShapeDtypeStruct((tokens, k), jnp.int32))
+    assert forms == [by_kernel, by_kernel]
+
+
 def _eqns(jaxpr):
     for eqn in jaxpr.eqns:
         yield eqn

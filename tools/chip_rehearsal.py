@@ -237,6 +237,26 @@ def grouped_products_case(rows: int, groups: int, d: int, f: int, device) -> tup
     return jax.jit(products), args
 
 
+def rows_to_tokens_case(rows: int, d: int, tokens: int, groups: int, device) -> tuple:
+    """(the expert layer's way back to tokens as its Pallas kernel —
+    ``tpfl.parallel.moe_kernel`` — jitted, args) from a bf16 row buffer
+    ``[rows, d]`` to ``tokens`` tokens over ``groups`` groups, at the
+    token tile ``moe_kernel.tiles`` gives the shape."""
+    from tpfl.parallel import moe_kernel
+
+    tile = moe_kernel.tiles((rows, d), jnp.bfloat16, tokens, groups)
+    assert tile, (rows, d, tokens, groups)
+    sharding = SingleDeviceSharding(device)
+    sds = lambda shape, dtype=jnp.int32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=sharding
+    )
+    runs = sds((tokens // tile, groups))
+    back = lambda rows, token, lo, hi: moe_kernel.rows_to_tokens(  # noqa: E731
+        rows, token, lo, hi, tile, False
+    )
+    return jax.jit(back), (sds((rows, d), jnp.bfloat16), sds((rows,)), runs, runs)
+
+
 def zaya_block_case(silos: int, batch: int, seq: int, device) -> tuple:
     """(jitted fwd+bwd of ONE ``ZayaBlock`` at ZAYA1-8B's published
     widths — compressed convolutional attention, the MLP router with the
@@ -479,6 +499,15 @@ def cases(devices) -> dict:
         ),
         "grouped_products_mellum_x2": lambda: grouped_products_case(
             98304, 32, 2304, 896, d0
+        ),
+        # ... and the way back to the 2 x 16384 tokens from that head and
+        # from the buffer's rest (``moe_kernel``, PR 35: 64 token tiles of
+        # 512 over 32 groups).
+        "rows_to_tokens_mellum_x2": lambda: rows_to_tokens_case(
+            98304, 2304, 32768, 32, d0
+        ),
+        "rows_to_tokens_mellum_rest_x2": lambda: rows_to_tokens_case(
+            163840, 2304, 32768, 32, d0
         ),
         # ZAYA1-8B's cell: the attention call inside the latent (2 key
         # heads, 4 query heads a key head, d = 128: block 512 exactly at

@@ -19,7 +19,10 @@ exchange). Under the engine's ``jax.vmap`` over silos the batch FOLDS
 into the groups (one row buffer, ``silos x n_held`` groups: no batched
 kernel and no loop over silos); it runs inside ``nn.remat``; its
 backward recomputes its row buffer, so nothing of the buffer's size is
-banked. ``tpfl.models.MellumLM`` (top-8 of 64, a quarter held) and
+banked. On a TPU the way back from rows to tokens, forward and
+backward, is the Pallas kernel of :mod:`tpfl.parallel.moe_kernel` where
+a token's choices are many enough to pay for it (``_with_runs``).
+``tpfl.models.MellumLM`` (top-8 of 64, a quarter held) and
 ``tpfl.models.ZayaLM`` (top-1 of 16, half held) are its users; the
 benchmark cells ``mellum2_silo_8k`` and ``zaya1_silo_8k`` run it.
 
@@ -47,7 +50,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from tpfl.parallel import compat
+from tpfl.parallel import compat, moe_kernel
 from tpfl.parallel.compat import shard_map
 
 # --- the held-experts layer (the engine's path) -------------------------------
@@ -301,6 +304,21 @@ def _head_rows(slots: int, share: tuple) -> int:
     return min(slots, -(-balanced_x3 // _TILE_ROWS) * _TILE_ROWS)
 
 
+def _run_edges(key, groups: int, tile: int):
+    """``[T / tile + 1, groups]``: the row of the buffer at which the
+    pairs that the tokens from tile ``i`` on have in group ``g`` begin —
+    the buffer is sorted by group and by token inside a group, so the
+    rows of tile ``i``'s tokens in group ``g`` are the ONE run
+    ``[edges[i, g], edges[i + 1, g])``."""
+    t, k = key.shape
+    chosen = key.reshape(t // tile, tile * k, 1) == jnp.arange(groups)
+    per_tile = jnp.sum(chosen, axis=1, dtype=jnp.int32)
+    sizes = jnp.sum(per_tile, axis=0)
+    return (jnp.cumsum(sizes) - sizes) + jnp.concatenate(
+        [jnp.zeros((1, groups), jnp.int32), jnp.cumsum(per_tile, axis=0)]
+    )
+
+
 def _parts(order, pos, sizes, held, share) -> tuple:
     """(whether a live row lies past the head, the buffer's parts): a
     part is (its slice of ``order``, the rows of each group inside it,
@@ -322,6 +340,40 @@ def _parts(order, pos, sizes, held, share) -> tuple:
     return ends[-1] > head, parts
 
 
+#: Slots the gather back to tokens fetches for each run the kernel
+#: copies, from which the kernel is taken. A run costs the kernel a
+#: 128-row block or two (~1.9 us on the v5e) whatever it holds; the
+#: gather pays 27-56 ns a slot. Measured both ways (PERF.md §6, PR 35):
+#: 256 slots a run at Mellum 2's shapes — 14.7 ms a call by gather, 2.9
+#: by kernel; 64 at ZAYA1's — 0.90 by gather, 1.27 by kernel.
+_RUN_SLOTS = 128
+
+
+def _with_runs(parts: list, x, key, share: tuple) -> list:
+    """Each part with the form its way back to tokens takes
+    (``_rows_to_tokens``), chosen as ``_pallas`` chooses the grouped
+    products' — by ``compat.on_tpu`` and the shapes: where the Pallas
+    kernel takes the part's rows (``moe_kernel.tiles``) and a token
+    tile's ``k`` slots a token are at least ``_RUN_SLOTS`` for each of
+    its ``n_held`` runs, each token tile's run of rows in each group,
+    ``(first row, end)`` within the part and cut at its edges; ``None``
+    where it is the gather."""
+    groups, k = parts[0][1].shape[0], key.shape[1]
+    out, lo = [], 0
+    for part in parts:
+        hi = lo + part[0].shape[0]
+        tile = compat.on_tpu() and moe_kernel.tiles(
+            (hi - lo, x.shape[1]), x.dtype, key.shape[0], groups
+        )
+        runs = None
+        if tile and k * tile >= _RUN_SLOTS * share[0]:
+            edges = jnp.clip(_run_edges(key, groups, tile), lo, hi) - lo
+            runs = (edges[:-1], edges[1:])
+        out.append((*part, runs))
+        lo = hi
+    return out
+
+
 def _over_parts(overflows, parts: list, work: Callable):
     """``work(part)`` summed over the buffer's parts, the part past the
     head entered only when it holds a live row."""
@@ -335,12 +387,22 @@ def _over_parts(overflows, parts: list, work: Callable):
     return jax.tree_util.tree_map(jnp.add, total, rest)
 
 
-def _rows_to_tokens(rows, at, here):
+def _rows_to_tokens(rows, part, k: int):
     """``out[t] = sum over a token's k choices of rows[at[t, c]]`` where
-    ``here``: a gather by the pair's row and a masked float32 sum. Both
-    directions of the layer move rows by GATHER (by a row's token one
-    way, by a pair's row the other): a scatter-add of ``k T`` rows,
-    which autodiff of a gather brings, a TPU runs row by row."""
+    ``here``, float32. Both directions of the layer move rows by GATHER
+    (by a row's token one way, by a pair's row the other): a scatter-add
+    of ``k T`` rows, which autodiff of a gather brings, a TPU runs row by
+    row. Two forms of the same sum, chosen by what can be observed
+    (``_with_runs``): on a TPU the Pallas kernel of ``moe_kernel``, which
+    reads each token tile's runs of rows where they lie; elsewhere, and
+    for a shape the kernel does not take, a gather by the pair's row —
+    ``k`` slots a token, the pairs not held masked away."""
+    part_order, _, at, here, runs = part
+    if runs is not None:
+        return moe_kernel.rows_to_tokens(
+            rows, part_order // k, *runs, here.shape[0] // runs[0].shape[0],
+            compat.pallas_interpret(None),
+        )
     t, k = here.shape
     picked = jnp.take(rows, at.reshape(-1), axis=0).reshape(t, k, rows.shape[-1])
     return jnp.sum(
@@ -366,11 +428,12 @@ def _moe_forward(share, x, gate, key, w_in, w_out):
         overflows, parts = _parts(
             order, pos.reshape(held.shape), sizes, held, share
         )
+        parts = _with_runs(parts, x, key, share)
     with jax.named_scope("moe_experts"):
         w_in, w_out = w_in.astype(dtype), w_out.astype(dtype)
 
     def work(part):
-        part_order, part_sizes, at, here = part
+        part_order, part_sizes = part[:2]
         with jax.named_scope("moe_experts"):
             _, row_gate, (g, u) = _expert_rows(x, gate, part_order, part_sizes, w_in, k)
             # The router's weight on the rows of the NARROW product: the
@@ -380,7 +443,7 @@ def _moe_forward(share, x, gate, key, w_in, w_out):
             ).astype(dtype)
             out_rows = _gmm(hidden, w_out, part_sizes)
         with jax.named_scope("moe_combine"):
-            return _rows_to_tokens(out_rows, at, here)
+            return _rows_to_tokens(out_rows, part, k)
 
     y = _over_parts(overflows, parts, work)
     with jax.named_scope("moe_combine"):
@@ -402,11 +465,12 @@ def _moe_backward(share, x, gate, key, w_in, w_out, dy, order, pos):
         overflows, parts = _parts(
             order, pos.reshape(held.shape), sizes, held, share
         )
+        parts = _with_runs(parts, x, key, share)
     with jax.named_scope("moe_experts"):
         w_in_c, w_out_c = w_in.astype(dtype), w_out.astype(dtype)
 
     def work(part):
-        part_order, part_sizes, at, here = part
+        part_order, part_sizes, at, here = part[:4]
         with jax.named_scope("moe_combine"):
             # The transpose of the combine: a row's gradient is its
             # token's. (Rows past the groups carry their pairs' tokens'
@@ -434,7 +498,7 @@ def _moe_backward(share, x, gate, key, w_in, w_out, dy, order, pos):
             # The transpose of the dispatch. A pair that is not held has
             # its row past the groups, where gradients are unspecified:
             # masked by ``where``, never by a product.
-            dx = _rows_to_tokens(d_rows, at, here)
+            dx = _rows_to_tokens(d_rows, part, k)
             d_gate = jnp.where(
                 here, jnp.take(d_row_gate, at.reshape(-1)).reshape(here.shape), 0.0
             )
@@ -500,9 +564,10 @@ def held_experts_moe(
     ``S n_held`` groups): no batched kernel, no loop over silos.
 
     Named scopes ``moe_dispatch`` (sort, plan, and in the backward the
-    gather of the tokens' gradients), ``moe_experts`` (the row gather,
-    the grouped products and the gates) and ``moe_combine`` (the gather
-    back to tokens), forward and backward."""
+    way back of the tokens' gradients), ``moe_experts`` (the row gather,
+    the grouped products and the gates) and ``moe_combine`` (the way
+    back to tokens: on a TPU the kernel ``moe_rows_to_tokens``),
+    forward and backward."""
     n_held = w_in.shape[0]
     local = expert - first
     key = jnp.where((local >= 0) & (local < n_held), local, n_held)
