@@ -58,7 +58,7 @@ import sys
 import time
 from dataclasses import dataclass, field
 from functools import lru_cache, partial
-from typing import Any, Callable, Optional
+from typing import Any, Callable
 
 #: The attention kernels against a dense S x S float32 softmax on the
 #: chip: max |a - b| / max |b| for the output and each of dq / dk / dv.
@@ -119,35 +119,25 @@ class Sizes:
     mesh_lm_batch: int = 8
 
 
-class CompileMeter:
-    """Counts what JAX's own monitoring says about compilation: backend
-    compiles, persistent-cache hits and misses, and seconds spent
-    tracing + lowering + compiling."""
+def compile_account() -> dict:
+    """What the program's own set-up account
+    (``profiling.observatory.setup_account``) says about compilation so
+    far: backend compiles (loads from the persistent cache among them),
+    the cache's hits and misses, and seconds spent tracing + lowering +
+    loading + compiling, no second counted twice."""
+    from tpfl.management import profiling
 
-    def __init__(self) -> None:
-        self.compiles = 0
-        self.hits = 0
-        self.misses = 0
-        self.seconds = 0.0
-
-    def install(self) -> "CompileMeter":
-        import jax.monitoring as jmon
-
-        def on_event(event: str, **kw: Any) -> None:
-            if event.endswith("/compilation_cache/cache_hits"):
-                self.hits += 1
-            elif event.endswith("/compilation_cache/cache_misses"):
-                self.misses += 1
-
-        def on_duration(event: str, duration: float, **kw: Any) -> None:
-            if event.startswith("/jax/core/compile/"):
-                self.seconds += float(duration)
-                if event.endswith("/backend_compile_duration"):
-                    self.compiles += 1
-
-        jmon.register_event_listener(on_event)
-        jmon.register_event_duration_secs_listener(on_duration)
-        return self
+    account = profiling.observatory.setup_account()
+    phases = account["phases"]
+    return {
+        "compiles": sum(
+            phases[p]["events"] + phases[p]["nested_events"]
+            for p in ("load", "compile")
+        ),
+        "hits": account["cache"]["hits"],
+        "misses": account["cache"]["misses"],
+        "seconds": sum(p["seconds"] for p in phases.values()),
+    }
 
 
 @dataclass
@@ -167,7 +157,6 @@ class Phase:
 def run_phases(
     phases: "list[tuple[str, Callable[[Phase], None]]]",
     device: dict,
-    meter: Optional[CompileMeter] = None,
     emit: Callable[[str], None] = print,
 ) -> None:
     """Run every phase in order, one JSON line each, then the result
@@ -176,18 +165,17 @@ def run_phases(
     for name, fn in phases:
         ph = Phase(name)
         t0 = time.perf_counter()
-        c0 = (meter.seconds, meter.compiles) if meter else (0.0, 0)
+        before = compile_account()
         fn(ph)
-        line = {
+        after = compile_account()
+        emit(json.dumps({
             "phase": name,
             "seconds": round(time.perf_counter() - t0, 3),
             **ph.facts,
             "checked": ph.checked,
-        }
-        if meter:
-            line["compile_seconds"] = round(meter.seconds - c0[0], 3)
-            line["compiles"] = meter.compiles - c0[1]
-        emit(json.dumps(line))
+            "compile_seconds": round(after["seconds"] - before["seconds"], 3),
+            "compiles": after["compiles"] - before["compiles"],
+        }))
     emit(json.dumps({"ok": True, "device": device}))
 
 
@@ -271,7 +259,7 @@ def _cnn_federation(sz: Sizes, seed: int, mesh: Any = None) -> tuple:
 # --- one chip ----------------------------------------------------------------
 
 
-def phase_engine(ph: Phase, sz: Sizes, seed: int, meter: CompileMeter) -> None:
+def phase_engine(ph: Phase, sz: Sizes, seed: int) -> None:
     import jax
     import numpy as np
 
@@ -314,7 +302,7 @@ def phase_engine(ph: Phase, sz: Sizes, seed: int, meter: CompileMeter) -> None:
             raised = True
         ph.check(raised, "a read of a donated input raises")
         sigs = profiling.observatory.signature_counts()
-        compiles = meter.compiles
+        compiles = compile_account()["compiles"]
         p2, a2, l2 = fed.run_rounds(
             p1, xs, ys, aux=a1, n_rounds=rounds, donate=True
         )
@@ -324,7 +312,7 @@ def phase_engine(ph: Phase, sz: Sizes, seed: int, meter: CompileMeter) -> None:
             "CompileObservatory saw no new program signature in window 2",
         )
         ph.check(
-            meter.compiles == compiles,
+            compile_account()["compiles"] == compiles,
             "jax compiled nothing in window 2",
         )
         ph.check(
@@ -749,7 +737,7 @@ def phase_sync(ph: Phase, sz: Sizes, seed: int) -> None:
     )
 
 
-def phase_cache(ph: Phase, meter: CompileMeter, cache_dir: str) -> None:
+def phase_cache(ph: Phase, cache_dir: str) -> None:
     import os
 
     import jax
@@ -763,7 +751,11 @@ def phase_cache(ph: Phase, meter: CompileMeter, cache_dir: str) -> None:
     )
     entries = len(os.listdir(cache_dir))
     ph.check(entries > 0, f"entries exist under {cache_dir}")
-    ph.check(meter.hits + meter.misses > 0, "jax consulted the persistent cache")
+    account = compile_account()
+    ph.check(
+        account["hits"] + account["misses"] > 0,
+        "jax consulted the persistent cache",
+    )
     warm = sum(
         v for k, v in metrics.fold()["counters"].items()
         if k[0] == "tpfl_compile_cache_warm_total"
@@ -776,10 +768,10 @@ def phase_cache(ph: Phase, meter: CompileMeter, cache_dir: str) -> None:
             else "<checkout>/.jax_cache"
         ),
         entries=entries,
-        hits=meter.hits,
-        misses=meter.misses,
+        hits=account["hits"],
+        misses=account["misses"],
         tpfl_compile_cache_warm_total=int(warm),
-        compile_seconds_total=round(meter.seconds, 3),
+        compile_seconds_total=round(account["seconds"], 3),
     )
 
 
@@ -960,8 +952,8 @@ def main(argv: "list[str] | None" = None) -> int:
     # First act: no TPU (or a kind the peaks table does not know, or too
     # few chips) raises here — nothing below ever runs on a CPU fallback.
     device = require_chip(min_count=args.chips)
+    # Arms the cache and opens the set-up account compile_account reads.
     cache_dir = profiling.ensure_compile_cache()
-    meter = CompileMeter().install()
     sz, seed = Sizes(), args.seed
     if args.chips == 4:
         phases = [
@@ -970,19 +962,19 @@ def main(argv: "list[str] | None" = None) -> int:
         ]
     else:
         phases = [
-            ("engine", partial(phase_engine, sz=sz, seed=seed, meter=meter)),
+            ("engine", partial(phase_engine, sz=sz, seed=seed)),
             ("gossip", partial(phase_gossip, seed=seed)),
             ("kernel", partial(phase_kernel, sz=sz, seed=seed)),
             ("experts", partial(phase_experts, sz=sz, seed=seed)),
             ("sync", partial(phase_sync, sz=sz, seed=seed)),
-            ("cache", partial(phase_cache, meter=meter, cache_dir=cache_dir)),
+            ("cache", partial(phase_cache, cache_dir=cache_dir)),
         ]
     print(json.dumps({
         "chip_smoke": "start", "device": device, "seed": seed,
         "compile_cache": cache_dir,
         "phases": [name for name, _ in phases],
     }), flush=True)
-    run_phases(phases, device, meter)
+    run_phases(phases, device)
     return 0
 
 

@@ -190,17 +190,16 @@ def test_one_chip_phases_walk_through_on_cpu(monkeypatch, tmp_path):
         moe_tokens=256,
     )
     cache_dir = profiling.ensure_compile_cache(str(tmp_path / "cache"))
-    meter = chip_smoke.CompileMeter().install()
     clear_registry()
     lines = []
     chip_smoke.run_phases(
         [
-            ("engine", partial(chip_smoke.phase_engine, sz=sz, seed=0, meter=meter)),
+            ("engine", partial(chip_smoke.phase_engine, sz=sz, seed=0)),
             ("gossip", partial(chip_smoke.phase_gossip, seed=0)),
             ("sync", partial(chip_smoke.phase_sync, sz=sz, seed=0)),
-            ("cache", partial(chip_smoke.phase_cache, meter=meter, cache_dir=cache_dir)),
+            ("cache", partial(chip_smoke.phase_cache, cache_dir=cache_dir)),
         ],
-        DEVICE, meter, emit=lines.append,
+        DEVICE, emit=lines.append,
     )
     assert [json.loads(x).get("phase") for x in lines[:-1]] == [
         "engine", "gossip", "sync", "cache"

@@ -9,9 +9,11 @@ shares of a timed run come from the chip benchmark (``BENCHMARK.json``,
 
 - :class:`CompileObservatory` — wraps the jit/lower/compile seams
   (``jax_learner._shared_program``, ``VmapFederation._build_round*``,
-  ``batched_fit.BatchedFitProgram``): compile wall-time histograms,
-  program-cache hit/miss counters, persistent-cache events lifted from
-  ``jax.monitoring``, and RECOMPILATION detection keyed by
+  ``batched_fit.BatchedFitProgram``): program-cache hit/miss counters,
+  the SET-UP ACCOUNT (every ``jax.monitoring`` compile event split into
+  trace / lower / load / compile by program on ``time.monotonic``,
+  nested events kept apart — :meth:`CompileObservatory.setup_account`),
+  and RECOMPILATION detection keyed by
   (fn, abstract shapes/dtypes of the arguments) with a recompile-storm
   warning event when one program keeps re-specializing (the silent
   killer of steady-state throughput — every distinct vmap width or
@@ -32,7 +34,8 @@ shares of a timed run come from the chip benchmark (``BENCHMARK.json``,
   from ``node_monitor``'s ``memory_stats`` read into a peak-tracking
   registry collector.
 Gating: the metrics REGISTRY side (cache hit/miss counters, cache-size
-gauges, HBM gauges) always records — cheap dict updates, PR-5's rule.
+gauges, HBM gauges) and the set-up account (fed only at compile seams:
+no compile, no event) always record — cheap dict updates, PR-5's rule.
 Everything that costs per-call work on a hot path (abstract-signature
 extraction in :meth:`CompileObservatory.wrap`, round spans, the
 ``block_until_ready`` splits in the learner) is gated by
@@ -51,6 +54,7 @@ from __future__ import annotations
 
 import contextlib
 import sys
+import threading
 import time
 import zlib
 from collections import deque
@@ -70,13 +74,6 @@ from tpfl.settings import Settings
 PEAK_FLOPS: dict[str, float] = {
     "TPU v5 lite": 197e12,  # v5e, as jax.devices()[0].device_kind spells it
 }
-
-#: Compile wall times span ms (cache hit replay) to minutes (the big
-#: vmapped round programs) — the default seconds-flavored buckets top
-#: out at 10 s and would collapse every real compile into +Inf.
-COMPILE_BUCKETS: tuple[float, ...] = (
-    0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 15.0, 30.0, 60.0, 120.0, 300.0,
-)
 
 #: Round components run 10 ms (device round) to minutes (timeout-bound
 #: protocol rounds).
@@ -132,25 +129,83 @@ def module_tag(module: Any) -> str:
     return f"{zlib.crc32(repr(module).encode()) & 0xFFFF:04x}"
 
 
+#: The set-up account's phases, in the order a program passes them.
+#: ``load`` and ``compile`` are the two outcomes of one JAX event.
+SETUP_PHASES = ("trace", "lower", "load", "compile")
+
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+#: jax.monitoring duration event -> phase (the backend event is split
+#: by the cache outcome seen before it on the same thread).
+_PHASE_OF_EVENT = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    _BACKEND_EVENT: "compile",
+}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+_CACHE_SECONDS_OF_EVENT = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "retrieval_seconds",
+    "/jax/compilation_cache/compile_time_saved_sec": "saved_seconds",
+}
+#: Events a thread keeps open to the question "does a later event
+#: contain this one?". Past it the older half is taken as outermost for
+#: good (counted as ``frozen_events``: a parent that closes later would
+#: find them counted beside it): memory is bounded whatever a trace
+#: fires. The cells' set-ups keep under 11,000 nested events in all.
+_PENDING_CAP = 65536
+#: Rows of the account's ``nested`` table and ``first_calls`` list.
+_NESTED_TOP = 16
+_FIRST_CALLS_KEPT = 256
+
+
+def _program_name(fun_name: str) -> str:
+    """JAX names a program ``f`` while tracing and ``jit(f)`` from
+    lowering on: one program, one row."""
+    if fun_name.endswith(")") and "(" in fun_name:
+        return fun_name[fun_name.index("(") + 1:-1]
+    return fun_name
+
+
+def process_started() -> "float | None":
+    """When this process started, on ``time.monotonic``'s axis, or None
+    where the platform cannot say. Linux: ``/proc/self/stat`` field 22
+    is the start in clock ticks since boot (10 ms steps), read against
+    ``CLOCK_BOOTTIME``. What lies between it and the first engine is the
+    interpreter, the imports and the caller's own first acts."""
+    import os
+
+    try:
+        with open("/proc/self/stat") as f:
+            # The command (field 2) may hold spaces: count from its ")".
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - ticks / os.sysconf(
+            "SC_CLK_TCK"
+        )
+    except (OSError, AttributeError, ValueError, IndexError):
+        return None
+    return time.monotonic() - age
+
+
 class CompileObservatory:
-    """Compile-seam accounting: cache hits/misses, compile wall time,
+    """Compile-seam accounting: cache hits/misses, the set-up account,
     recompile detection keyed by (fn, abstract shapes/dtypes).
 
     Two halves:
 
-    - ALWAYS-ON counters (plain registry updates, PR-5 rule): the
-      process program-cache traffic (:meth:`cache_event`,
+    - ALWAYS-ON (plain dict updates, PR-5 rule): the process
+      program-cache traffic (:meth:`cache_event`,
       :meth:`cache_cleared`) — how the r3 "caches accrete forever" bug
-      class becomes visible instead of latent.
+      class becomes visible instead of latent — and the SET-UP ACCOUNT
+      (:meth:`open_setup_account`, :meth:`setup_account`): what JAX's
+      own monitoring says each program cost to trace, lower, load from
+      the persistent cache or compile, on ``time.monotonic``. It is fed
+      only where JAX compiles, so a steady state adds nothing to it.
     - GATED per-call work (``Settings.PROFILING_ENABLED``):
       :meth:`wrap` puts a signature probe in front of a jitted
-      callable; a never-seen (fn, signature) is a (re)compilation —
-      its call is timed into ``tpfl_compile_seconds`` (compile +
-      first-run wall; jit exposes no cleaner split without a separate
-      lower/compile, which :meth:`compile_span` serves for callers
-      that do lower explicitly), and when one fn accretes
-      ``Settings.PROFILING_RECOMPILE_WARN`` distinct signatures a
-      ``recompile_storm`` event lands in the flight ring and the log.
+      callable; a never-seen (fn, signature) is a (re)compilation, and
+      when one fn accretes ``Settings.PROFILING_RECOMPILE_WARN``
+      distinct signatures a ``recompile_storm`` event lands in the
+      flight ring and the log.
     """
 
     def __init__(self) -> None:
@@ -159,9 +214,32 @@ class CompileObservatory:
         self._signatures: dict[str, set] = {}
         # guarded-by: _lock
         self._warned: set[str] = set()
-        # unguarded: single flag flipped under _lock in _install only;
-        # racy double-read would at worst double-install a no-op pair.
-        self._listeners_installed = False
+        # guarded-by: _lock. When the account opened on time.monotonic
+        # (None: no listener yet). jax's listeners are global and
+        # permanent, so it opens once and is never reset.
+        self._account_started: "float | None" = None
+        # guarded-by: _lock. (phase, fun_name) -> [outermost events,
+        # their seconds, nested events, their seconds].
+        self._by_name: dict[tuple[str, str], list] = {}
+        # guarded-by: _lock
+        self._cache = {
+            "hits": 0, "misses": 0,
+            "retrieval_seconds": 0.0, "saved_seconds": 0.0,
+        }
+        # guarded-by: _lock
+        self._first_calls: deque = deque(maxlen=_FIRST_CALLS_KEPT)
+        # guarded-by: _lock
+        self._frozen_events = 0
+        # unguarded: written once, in open_setup_account.
+        self._process_started: "float | None" = None
+        # unguarded: the registry's collectors run one at a time.
+        # Series -> the value :meth:`publish` last brought it to.
+        self._published: dict[tuple, float] = {}
+        # Per thread: ``pending`` (events no later event contains so
+        # far, oldest first, as (midpoint, seconds, _by_name key)) and
+        # ``hit`` (the persistent cache answered since this
+        # thread's last backend event).
+        self._thread = threading.local()
 
     # --- always-on cache accounting ---
 
@@ -185,34 +263,27 @@ class CompileObservatory:
         """Signature-probe wrapper around a jitted callable. With
         profiling off the wrapper is one attribute read + passthrough
         (zero added dispatches); with it on, each call abstracts its
-        arguments and a fresh signature counts (and times) as a
-        compilation."""
-        self._install_jax_listeners()
+        arguments and a fresh signature counts as a compilation (what
+        it cost is the set-up account's to say)."""
 
         def observed(*args: Any, **kwargs: Any) -> Any:
-            if not Settings.PROFILING_ENABLED:
-                return fn(*args, **kwargs)
-            sig = _abstract_signature(args, kwargs)
-            fresh, n_sigs = self._note(name, sig)
-            if not fresh:
-                metrics.counter(
-                    "tpfl_compile_signature_hits_total", labels={"fn": name}
+            if Settings.PROFILING_ENABLED:
+                fresh, n_sigs = self._note(
+                    name, _abstract_signature(args, kwargs)
                 )
-                return fn(*args, **kwargs)
-            t0 = time.perf_counter()
-            out = fn(*args, **kwargs)
-            dt = time.perf_counter() - t0
-            metrics.observe(
-                "tpfl_compile_seconds", dt,
-                labels={"fn": name}, buckets=COMPILE_BUCKETS,
-            )
-            metrics.gauge(
-                "tpfl_compile_signatures", float(n_sigs), labels={"fn": name}
-            )
-            if n_sigs > 1:
-                metrics.counter("tpfl_recompiles_total", labels={"fn": name})
-            self._maybe_warn_storm(name, n_sigs)
-            return out
+                labels = {"fn": name}
+                if fresh:
+                    metrics.gauge(
+                        "tpfl_compile_signatures", float(n_sigs), labels=labels
+                    )
+                    if n_sigs > 1:
+                        metrics.counter("tpfl_recompiles_total", labels=labels)
+                    self._maybe_warn_storm(name, n_sigs)
+                else:
+                    metrics.counter(
+                        "tpfl_compile_signature_hits_total", labels=labels
+                    )
+            return fn(*args, **kwargs)
 
         # Keep the lowering escape hatch static analysis uses on raw
         # jitted fns (tests/test_profiling.py::
@@ -261,20 +332,6 @@ class CompileObservatory:
             f"{warn_at}) — shape/dtype churn is defeating the jit cache",
         )
 
-    @contextlib.contextmanager
-    def compile_span(self, name: str) -> Iterator[None]:
-        """Time an explicit lower/compile block into the compile
-        histogram (for callers that hold the seam open themselves,
-        e.g. ``.lower(...).compile()`` in scaling analysis)."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            metrics.observe(
-                "tpfl_compile_seconds", time.perf_counter() - t0,
-                labels={"fn": name}, buckets=COMPILE_BUCKETS,
-            )
-
     def signature_counts(self) -> dict[str, int]:
         """fn name -> distinct abstract signatures seen (the recompile
         receipt of ``tests/test_elastic.py`` and ``chip_smoke.py``)."""
@@ -286,48 +343,175 @@ class CompileObservatory:
             self._signatures.clear()
             self._warned.clear()
 
-    # --- persistent-cache / backend-compile events (jax.monitoring) ---
+    # --- the set-up account (jax.monitoring) ---
 
-    def _install_jax_listeners(self) -> None:
-        """Mirror jax's own monitoring events (persistent compilation
-        cache hits/misses, backend compile durations) into the
-        registry. Listeners are global and permanent in jax, so they
-        install once and gate per-event on PROFILING_ENABLED."""
-        if self._listeners_installed:
-            return
+    def open_setup_account(self) -> None:
+        """Start listening to jax's own monitoring events: the
+        persistent compilation cache's answers and the three durations
+        of every jit seam. Listeners are global and permanent in jax,
+        so they install once: called where a process first means to
+        compile (``FederationEngine.__init__``,
+        :func:`ensure_compile_cache`); what jitted before that is not in
+        the account."""
         with self._lock:
-            if self._listeners_installed:
+            if self._account_started is not None:
                 return
-            self._listeners_installed = True
+            self._account_started = time.monotonic()
+        self._process_started = process_started()
         import jax.monitoring as jmon
 
-        def on_event(event: str, **kw: Any) -> None:
-            # UNGATED (PR-5 always-on rule): persistent-cache warm
-            # hits are the cold-start receipt the compile cache is
-            # judged by — they must count even with profiling off
-            # (jax emits "/jax/compilation_cache/cache_hits").
-            if "/compilation_cache/cache_hits" in event:
-                metrics.counter("tpfl_compile_cache_warm_total")
-            if not Settings.PROFILING_ENABLED:
-                return
-            if "cache" in event or "compile" in event:
-                metrics.counter(
-                    "tpfl_jax_monitoring_events_total",
-                    labels={"event": event.rsplit("/", 1)[-1]},
-                )
+        jmon.register_event_listener(self._on_cache_event)
+        jmon.register_event_duration_secs_listener(self._on_duration)
 
-        def on_duration(event: str, duration: float, **kw: Any) -> None:
-            if not Settings.PROFILING_ENABLED:
-                return
-            if "compile" in event:
-                metrics.observe(
-                    "tpfl_jax_compile_seconds", float(duration),
-                    labels={"event": event.rsplit("/", 1)[-1]},
-                    buckets=COMPILE_BUCKETS,
-                )
+    def _on_cache_event(self, event: str, **kw: Any) -> None:
+        # jax fires both in the compiling thread, just before that
+        # program's backend_compile_duration.
+        if event == _CACHE_HIT_EVENT:
+            self._thread.hit = True
+            # The cold-start receipt the compile cache is judged by.
+            metrics.counter("tpfl_compile_cache_warm_total")
+            with self._lock:
+                self._cache["hits"] += 1
+        elif event == _CACHE_MISS_EVENT:
+            with self._lock:
+                self._cache["misses"] += 1
 
-        jmon.register_event_listener(on_event)
-        jmon.register_event_duration_secs_listener(on_duration)
+    def _on_duration(self, event: str, duration: float, **kw: Any) -> None:
+        """One compile event, O(1) amortised: every event is pushed
+        once and popped at most once."""
+        phase = _PHASE_OF_EVENT.get(event)
+        if phase is None:
+            field = _CACHE_SECONDS_OF_EVENT.get(event)
+            if field is not None:
+                with self._lock:
+                    self._cache[field] += duration
+            return
+        # jax timed the span on time.time(); only its length is taken
+        # from there, so the account stays on ONE clock.
+        end = time.monotonic()
+        start = end - duration
+        thread = self._thread.__dict__
+        if event == _BACKEND_EVENT and thread.pop("hit", False):
+            phase = "load"  # fires on a hit too, holding the retrieval
+        pending = thread.setdefault("pending", [])
+        key = (phase, str(kw.get("fun_name", "")))
+        with self._lock:
+            # One thread's events are nested or disjoint, so what this
+            # one contains is a suffix of ``pending``; the midpoint
+            # decides, which a skew between the two clocks of up to
+            # half the inner event's length cannot turn.
+            while pending and pending[-1][0] > start:
+                _, seconds, inner = pending.pop()
+                row = self._by_name[inner]
+                row[0] -= 1
+                row[1] -= seconds
+                row[2] += 1
+                row[3] += seconds
+            row = self._by_name.get(key)
+            if row is None:
+                row = self._by_name[key] = [0, 0.0, 0, 0.0]
+            row[0] += 1
+            row[1] += duration
+        pending.append((end - duration / 2, duration, key))
+        if len(pending) > _PENDING_CAP:
+            del pending[: _PENDING_CAP // 2]
+            with self._lock:
+                self._frozen_events += _PENDING_CAP // 2
+
+    def first_call(self, program: str, t0: float, t1: float) -> None:
+        """One row a NEW program (or ``engine_init``): the wall of the
+        call that traced and compiled it, on ``time.monotonic``. Less
+        the program's own trace + lower + load + compile, it is what
+        the caller spent dispatching and enqueueing a new program."""
+        with self._lock:
+            self._first_calls.append({"program": program, "t0": t0, "t1": t1})
+
+    def setup_account(self) -> dict:
+        """The account so far, as a plain snapshot (JSON-ready):
+
+        - ``started`` / ``process_started``: when the account opened
+          and when the process did, on ``time.monotonic`` (None where
+          unknown);
+        - ``phases[phase]``: ``seconds`` / ``events`` of OUTERMOST
+          events — those no other compile event of the same thread
+          contains, so the four phases never count a second twice —
+          and ``nested_seconds`` / ``nested_events`` of the rest;
+        - ``cache``: the persistent cache's ``hits`` / ``misses`` and
+          jax's ``retrieval_seconds`` / ``saved_seconds``;
+        - ``programs[name]``: ``seconds`` and ``events`` by phase of
+          that program's outermost events (``tpfl_window``: the
+          engine's window programs; the callers' own jits under theirs);
+        - ``nested``: the names that cost most inside other events,
+          as ``{phase, name, events, seconds}``;
+        - ``first_calls``: :meth:`first_call`'s rows, oldest first;
+        - ``frozen_events``: events taken as outermost unasked because
+          a thread held ``_PENDING_CAP`` open questions (0 unless one
+          event has tens of thousands of direct children)."""
+        with self._lock:
+            rows = [(key, tuple(row)) for key, row in self._by_name.items()]
+            out: dict = {
+                "started": self._account_started,
+                "process_started": self._process_started,
+                "cache": dict(self._cache),
+                "first_calls": [dict(r) for r in self._first_calls],
+                "frozen_events": self._frozen_events,
+            }
+        phases = {
+            phase: {
+                "seconds": 0.0, "events": 0,
+                "nested_seconds": 0.0, "nested_events": 0,
+            }
+            for phase in SETUP_PHASES
+        }
+        programs: dict = {}
+        nested = []
+        for (phase, name), (events, seconds, n_events, n_seconds) in rows:
+            total = phases[phase]
+            if events:  # else the float left over from moving it out
+                total["events"] += events
+                total["seconds"] += seconds
+                program = programs.setdefault(_program_name(name), {
+                    "seconds": dict.fromkeys(SETUP_PHASES, 0.0),
+                    "events": dict.fromkeys(SETUP_PHASES, 0),
+                })
+                program["seconds"][phase] += seconds
+                program["events"][phase] += events
+            if n_events:
+                total["nested_events"] += n_events
+                total["nested_seconds"] += n_seconds
+                nested.append({
+                    "phase": phase, "name": name,
+                    "events": n_events, "seconds": n_seconds,
+                })
+        nested.sort(key=lambda r: (-r["seconds"], -r["events"], r["name"]))
+        out.update(
+            phases=phases, programs=programs, nested=nested[:_NESTED_TOP]
+        )
+        return out
+
+    def publish(self, registry: Any) -> None:
+        """Registry collector: the account's phase totals as
+        ``tpfl_setup_seconds_total{phase}`` (outermost events) and
+        ``tpfl_setup_events_total{phase,nested}``. Pull-style — the
+        compile callbacks never touch the registry — adding what came
+        since the last scrape (an event found to be nested after one
+        moves between the two ``nested`` series)."""
+        for phase, total in self.setup_account()["phases"].items():
+            for name, labels, value in (
+                ("tpfl_setup_seconds_total", {}, total["seconds"]),
+                ("tpfl_setup_events_total", {"nested": "false"}, total["events"]),
+                (
+                    "tpfl_setup_events_total", {"nested": "true"},
+                    total["nested_events"],
+                ),
+            ):
+                series = (name, phase, labels.get("nested"))
+                delta = value - self._published.get(series, 0.0)
+                if delta:
+                    registry.counter(
+                        name, float(delta), labels={"phase": phase, **labels}
+                    )
+                    self._published[series] = value
 
 
 # --- round profiler -------------------------------------------------------
@@ -1049,9 +1233,9 @@ def ensure_compile_cache(directory: "str | None" = None) -> str:
         # environment was bound by jax itself and is never re-pointed.
         _jax_cc.reset_cache()
     _COMPILE_CACHE_DIR = d
-    # Make sure the monitoring listener that counts warm hits exists
-    # even if profiling never wrapped a program in this process.
-    observatory._install_jax_listeners()
+    # The listener that counts warm hits (and keeps the set-up account)
+    # exists from here on, engine or no engine.
+    observatory.open_setup_account()
     return d
 
 
@@ -1064,3 +1248,4 @@ hbm = HbmTracker()
 
 metrics.register_collector(_compiled_cache_collector)
 metrics.register_collector(_hbm_collector)
+metrics.register_collector(observatory.publish)

@@ -647,6 +647,10 @@ class FederationEngine:
         layout: "SpecLayout | str | None" = None,
         sequence_parallel: bool = True,
     ) -> None:
+        # The set-up account opens with the engine, on the constructor's
+        # first line (see _account_init).
+        profiling.observatory.open_setup_account()
+        t_init = time.monotonic()
         if aux_mode not in ("mean", "local"):
             raise ValueError(f"aux_mode must be 'mean' or 'local', got {aux_mode!r}")
         if algorithm not in _ALGORITHMS:
@@ -699,6 +703,9 @@ class FederationEngine:
         # ephemeral: observatory/contract wrappers over _programs.
         self._wrapped: dict[tuple, Callable] = {}
         # unguarded: single-owner (see _programs)
+        # ephemeral: per-dispatch scratch (see _wrapped_program).
+        self._fresh_program: Optional[str] = None
+        # unguarded: single-owner (see _programs)
         # ephemeral: compiled-program cache (see _programs).
         self._eval_fns: dict[bool, Callable] = {}
         # unguarded: single-owner (see _programs) — per-cache-key
@@ -743,14 +750,7 @@ class FederationEngine:
         # ephemeral: derived — resize_nodes/import_state re-derive it
         # from the checkpointed n_nodes (see padded_nodes).
         self.valid = valid_node_mask(self.n_nodes, self.padded_nodes)
-        if Settings.COMPILE_CACHE_DIR:
-            # Persistent compilation cache (COMPILE_CACHE_DIR, unless
-            # JAX_COMPILATION_CACHE_DIR already placed it — see
-            # profiling.compile_cache_dir): warm processes reload
-            # lowered executables instead of recompiling; the
-            # observatory's tpfl_compile_cache_warm_total counts the
-            # reloads.
-            profiling.ensure_compile_cache(str(Settings.COMPILE_CACHE_DIR))
+        self._account_init(t_init)
 
     # --- state / data placement ---
 
@@ -1974,7 +1974,10 @@ class FederationEngine:
         every other jit seam). Variant programs get their own names —
         the telemetry/attack/codec/2D-mesh/fedbuff (and capacity-tier
         / hosts-axis / population) signatures differ by construction
-        and must not read as recompile storms of the base program."""
+        and must not read as recompile storms of the base program.
+        A program built here leaves its observatory name in
+        ``_fresh_program`` until the dispatch that fetched it takes it
+        for its ``first_call`` row of the set-up account."""
         key = (
             kind, int(epochs), int(n_rounds), int(w_ndim), bool(donate),
             bool(telemetry), int(a_ndim), int(codec), float(topk_frac),
@@ -1995,10 +1998,12 @@ class FederationEngine:
                 + (f":pop{int(pop_size)}" if pop_size else "")
                 + (":hl" if self.head_owns_loss else "")
             )
-            wrapped = profiling.observatory.wrap(
-                self.program(*key),
+            self._fresh_program = (
                 f"engine_round:{kind}x{n_rounds}{suffix}:"
-                f"{profiling.module_tag(self.module)}",
+                f"{profiling.module_tag(self.module)}"
+            )
+            wrapped = profiling.observatory.wrap(
+                self.program(*key), self._fresh_program
             )
             # TRACE_CONTRACTS (off = no wrapper): stamp the program
             # with the knob values its cache key encodes, so a future
@@ -2384,6 +2389,7 @@ class FederationEngine:
                     codec, frac, model_axes, mesh_layout, fedbuff, stale_exp,
                     capacity, mesh_nodes, mesh_hosts, pop_size,
                 )
+                fresh, self._fresh_program = self._fresh_program, None
                 if Settings.TRACE_CONTRACTS:
                     # Dispatch-time contract: the fetched program's build-time
                     # stamp must match THIS dispatch's resolved knob values.
@@ -2423,9 +2429,11 @@ class FederationEngine:
             if prof:
                 self._windows += 1
                 profiling.rounds.begin_round(node_tag, self._windows)
-            t0 = time.monotonic() if (prof or tele_on) else 0.0
+            timed = prof or tele_on or fresh is not None
+            t0 = time.monotonic() if timed else 0.0
             # A new program is traced and compiled inside its first
-            # call: a recompile shows as one long program_call.
+            # call: a recompile shows as one long program_call, and as
+            # a first_call row of the set-up account.
             with tracing.engine_span("program_call", window_start):
                 try:
                     out = fn(*args)
@@ -2443,13 +2451,33 @@ class FederationEngine:
                 else:
                     out_params, out_c, out_cg, out_aux, losses = out
             self._rounds_done += n_rounds
-            t1 = time.monotonic() if (prof or tele_on) else 0.0
+            t1 = time.monotonic() if timed else 0.0
+            if fresh is not None:
+                profiling.observatory.first_call(fresh, t0, t1)
             return EngineWindow(
                 self, kind, aux is not None,
                 (out_params, out_c, out_cg, out_aux, losses), tele, w,
                 n_rounds, window_start, self._windows, prof, node_tag,
                 t0, t1,
             )
+
+    def _account_init(self, t_init: float) -> None:
+        """The tail of ``__init__`` (kept down here so that the line
+        numbers of the round body above, which the Pallas kernels'
+        serialized bodies carry, move only with the round body): arm
+        the persistent compilation cache where the knob asks, and row
+        the constructor in the set-up account — what this process
+        traces, lowers, loads and compiles from the constructor's first
+        line on is in it."""
+        if Settings.COMPILE_CACHE_DIR:
+            # COMPILE_CACHE_DIR, unless JAX_COMPILATION_CACHE_DIR
+            # already placed it (profiling.compile_cache_dir): warm
+            # processes reload lowered executables instead of
+            # recompiling; tpfl_compile_cache_warm_total counts them.
+            profiling.ensure_compile_cache(str(Settings.COMPILE_CACHE_DIR))
+        profiling.observatory.first_call(
+            "engine_init", t_init, time.monotonic()
+        )
 
     def _hlo_digest(self, key: tuple, args: tuple) -> str:
         """Lowered-HLO fingerprint of the cached program behind
