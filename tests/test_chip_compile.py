@@ -10,6 +10,8 @@ executes: a pass here is not a chip run. The cases are
 20-second engine windows stay in that script.
 """
 
+import re
+
 import jax
 import pytest
 
@@ -55,14 +57,20 @@ def described():
         # blockwise_attention's TPU branch at the benchmark's shapes,
         # under the engine's vmap: GPT-2 (two 64-wide heads a lane tile,
         # two blocks) and SambaY (grouped rows, a value width of its own,
-        # sixteen blocks, 8 MB of float32 dq resident in VMEM).
+        # sixteen blocks, 8 MB of float32 dq resident in VMEM). Grouped
+        # heads of whole lane tiles bring a third, small kernel: delta
+        # summed from dO and O where they lie (PR 37).
         ("block_attention_gpt2_x4", 2, 0),
-        ("block_attention_sambay_x2", 2, 0),
+        ("block_attention_sambay_x2", 3, 0),
         # Mellum 2's full layer (8 query heads a key head, block 256: a
         # [256, 2048] tile, 64 MiB of dq) takes the kernels; its experts'
         # six grouped products a step are the megablox kernels.
-        ("block_attention_mellum_x2", 2, 0),
+        ("block_attention_mellum_x2", 3, 0),
         ("grouped_products_mellum_x2", 6, 0),
+        # ... and one whole banded block under ``nn.remat``: the forward
+        # kernel twice (once recomputed), delta and one backward sweep,
+        # 3 x 5 grouped products, the way back to tokens three times.
+        ("mellum_block_x2", 22, 0),
         # The way back from that head's rows to the tokens, and from the
         # buffer's rest (PR 35): one kernel over 64 token tiles of 512, a
         # tile's runs copied block by block.
@@ -72,20 +80,20 @@ def described():
         # ``blockwise_attention``: Mellum 2's window of 1024 at block 256
         # (a sweep of five steps, dq resident as in the full layer) and
         # SambaY's sliding window of 512 at block 512 (two steps).
-        ("block_attention_mellum_band_x2", 2, 0),
-        ("block_attention_sambay_band_x2", 2, 0),
+        ("block_attention_mellum_band_x2", 3, 0),
+        ("block_attention_sambay_band_x2", 3, 0),
         # ZAYA1-8B's attention inside the latent (2 key heads x 4 query
         # heads of 128, 8192 keys: block 512 exactly at ``_TILE_BYTES``,
         # 32 MiB of dq) takes the kernels as they are; its experts' six
         # grouped products at 2048 x 4096 (tiles of 1024, the weight
         # gradient's cut to 1024 x 512 to fit VMEM); and ONE whole block
         # — the convolutions, the router MLP and its state, the head of
-        # the row buffer and its rest: 2 attention kernels + 2 x 7
+        # the row buffer and its rest: 3 attention kernels + 2 x 7
         # grouped products (its way back to tokens stays the gather: one
         # choice a token, ``moe._RUN_SLOTS``).
-        ("block_attention_zaya_x2", 2, 0),
+        ("block_attention_zaya_x2", 3, 0),
         ("grouped_products_zaya_x2", 6, 0),
-        ("zaya_block_x2", 16, 0),
+        ("zaya_block_x2", 17, 0),
         # The VMEM guard's edges (``flash_kernel.tiles``): the tallest
         # tile with the longest resident dq, bf16 and float32 gradients.
         ("flash_64k_d128", 2, 0),
@@ -93,10 +101,21 @@ def described():
     ],
 )
 def test_kernel_compiles_for_described_v5e(described, case, kernels, permutes):
-    fn, args = rehearsal.cases(described)[case]()
-    report = rehearsal.compile_report(case, fn, args)
-    assert report["tpu_custom_call"] == kernels, report
-    assert report["collective_permute"] >= permutes, report
+    text = _compiled_text(described, case)
+    assert text.count("tpu_custom_call") == kernels
+    assert len(re.findall(r" collective-permute(-start)?\(", text)) >= permutes
+
+
+_COMPILED: dict = {}
+
+
+def _compiled_text(described, case: str) -> str:
+    """The text of a rehearsal case compiled for the described v5e, once
+    a session (a whole block takes 20 seconds)."""
+    if case not in _COMPILED:
+        fn, args = rehearsal.cases(described)[case]()
+        _COMPILED[case] = fn.lower(*args).compile().as_text()
+    return _COMPILED[case]
 
 
 @pytest.mark.parametrize(
@@ -141,8 +160,7 @@ def test_default_block_is_the_largest_the_kernels_admit(
 def gpt2_window_text(described):
     """The compiled text of a window of GPT-2 small's width, heads,
     context and vocabulary (one block, two silos)."""
-    fn, args = rehearsal.cases(described)["engine_gpt2_head_x2"]()
-    return fn.lower(*args).compile().as_text()
+    return _compiled_text(described, "engine_gpt2_head_x2")
 
 
 def test_attention_kernels_are_in_the_compiled_window(gpt2_window_text):
@@ -150,8 +168,6 @@ def test_attention_kernels_are_in_the_compiled_window(gpt2_window_text):
     the compiled window calls one forward and one backward kernel by
     name, and no ``while`` of the XLA block loop is left under the
     scope."""
-    import re
-
     calls = [
         line for line in gpt2_window_text.splitlines()
         if "tpu_custom_call" in line and " custom-call(" in line
@@ -185,6 +201,38 @@ def test_attention_operands_are_not_copied_between_layouts(gpt2_window_text):
     assert not copies, copies
 
 
+def _grouped_row_operations(text: str) -> list:
+    """The operations of a compiled program that exist only to lay a key
+    head's query heads side by side as ROWS (PERF.md §6, PR 37): the
+    transposes of ``blockwise_attention``'s old grouped-row layout —
+    operations named ``.../attention/transpose``, the attention module's
+    own (the scalars' ``.../block_attention/transpose`` of delta, a
+    ``[B, S, Hq]`` float32, stays) — and copies of the 7-dimensional
+    ``[.., blocks, groups, block, heads, d]`` arrays they made of q,
+    out, dO and dq."""
+    return [
+        op for op in rehearsal.estimated_operations(text)
+        if op["op_name_tail"].endswith("/attention/transpose")
+        or (op["name"].startswith("copy") and op["shape"].count(",") == 6)
+    ]
+
+
+@pytest.mark.parametrize("case", ["mellum_block_x2", "zaya_block_x2"])
+def test_grouped_query_heads_are_not_transposed_around_the_kernels(described, case):
+    """The kernels read a key head's query heads as LANES of ``[B, S, Hq
+    * D]``, where the projection and rotary left them, and write out and
+    dq there: in one whole block at published widths (Mellum 2's banded
+    layer, 8 query heads a key head, forward and backward under
+    ``nn.remat``; ZAYA1's, 4 a key head) no operation transposes q, out,
+    dO or dq into grouped rows and none copies their 7-dimensional
+    ``[.., 8, 256, 4, 128]`` / ``[.., 256, 4, 8, 128]`` form. Before PR
+    37 a round of the Mellum 2 cell held 28 + 4 such operations (ZAYA1's
+    20 + 5), 87 ms of ``copy`` and ``reshape`` a round on the chip."""
+    text = _compiled_text(described, case)
+    assert "block_attention_backward" in text  # the kernels are there
+    assert not _grouped_row_operations(text)
+
+
 def test_lm_head_owns_its_loss_in_the_compiled_window(described, gpt2_window_text):
     """A window of GPT-2 small's width and vocabulary (one block, two
     silos) compiled for the described v5e: with the head owning its
@@ -193,8 +241,6 @@ def test_lm_head_owns_its_loss_in_the_compiled_window(described, gpt2_window_tex
     a compare-and-select), and the bias gradient comes out of a matmul
     (on the TPU a ``convolution`` at the root of an output fusion), not
     a reduction of its own."""
-    import re
-
     from tpfl.models.head_loss import _ONES_ROWS
 
     text = gpt2_window_text

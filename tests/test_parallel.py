@@ -761,6 +761,24 @@ def _attention_and_gradients(q, k, v, cot, silos: bool, **kw):
         (4, 2, 64, 128, 512, 128, True, True, jnp.float32, 200),
         # bf16 as the cell runs it.
         (8, 1, 128, 128, 1024, 128, True, True, jnp.bfloat16, 512),
+        # --- grouped query heads as LANES of the q / out / dO / dq blocks ---
+        # Mellum 2's full layer, scaled down: 8 query heads a key head of
+        # 128 lie on 1024 lanes of a block and are stacked in VMEM.
+        (8, 1, 128, 128, 512, 128, True, False, jnp.float32, None),
+        (8, 1, 128, 128, 512, 256, True, True, jnp.bfloat16, None),
+        # ZAYA1's: two key heads (two head blocks of the grid), 4 query
+        # heads each — with a band, without, and not causal over a padded
+        # sequence (the last key block masks its padding).
+        (8, 2, 128, 128, 512, 128, True, False, jnp.float32, None),
+        (8, 2, 128, 128, 768, 128, True, False, jnp.float32, 300),
+        (8, 2, 128, 128, 300, 128, False, False, jnp.float32, None),
+        (8, 2, 128, 128, 512, 256, True, False, jnp.bfloat16, None),
+        (8, 2, 128, 128, 768, 256, True, True, jnp.bfloat16, 256),
+        # 64-wide query heads (SambaY's two a key head, two key heads an
+        # instance) are rolled onto their key head's lanes: bf16 too, and
+        # four a key head.
+        (4, 2, 64, 128, 768, 256, True, False, jnp.bfloat16, 256),
+        (8, 2, 64, 64, 512, 128, True, False, jnp.float32, None),
     ],
 )
 def test_blockwise_attention_kernels_match_the_xla_loop(
@@ -820,15 +838,23 @@ def _dense_band(q, k, v, window):
     return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v)
 
 
-def test_banded_kernels_match_a_dense_masked_softmax(kernels_on_cpu):
+@pytest.mark.parametrize(
+    "hq, hkv, d, dv",
+    [
+        (4, 2, 64, 128),  # SambaY's: 64-wide query heads rolled in VMEM
+        (8, 1, 128, 128),  # Mellum 2's: 8 query heads a key head as lanes
+        (8, 2, 128, 128),  # ZAYA1's: 4 a key head, two head blocks
+    ],
+)
+def test_banded_kernels_match_a_dense_masked_softmax(kernels_on_cpu, hq, hkv, d, dv):
     """The band inside the kernels against plain dense attention under
     the mask ``0 <= q - k < window``, output and gradients: grouped
     heads, a padded sequence, a window that cuts two far blocks."""
     keys = jax.random.split(jax.random.PRNGKey(11), 4)
-    q = jax.random.normal(keys[0], (2, 700, 4, 64))
-    k = jax.random.normal(keys[1], (2, 700, 2, 64))
-    v = jax.random.normal(keys[2], (2, 700, 2, 128))
-    cot = jax.random.normal(keys[3], (2, 700, 4, 128))
+    q = jax.random.normal(keys[0], (2, 700, hq, d))
+    k = jax.random.normal(keys[1], (2, 700, hkv, d))
+    v = jax.random.normal(keys[2], (2, 700, hkv, dv))
+    cot = jax.random.normal(keys[3], (2, 700, hq, dv))
     got = _attention_and_gradients(
         q, k, v, cot, False, causal=True, block_size=128, window=300
     )
@@ -933,6 +959,108 @@ def test_blockwise_attention_kernels_are_the_tpu_branch_with_a_band_too(kernels_
     assert kernels(100) == 0
     assert kernels(120, block_size=40) == 0
     assert kernels(4096, block_size=2048) == 0
+
+
+def _kernel_calls(fn, *args) -> list:
+    """The ``pallas_call`` equations of ``fn``'s jaxpr, through its
+    ``custom_vjp`` and ``jit`` calls."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(eqn)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+@pytest.mark.parametrize(
+    "hq, hkv, d, block, q_block, dq_block",
+    [
+        # Equal heads (GPT-2; ``flash_attention``; the ring's inner): the
+        # kernel call it was before grouped heads became lanes — a block
+        # of ``block`` rows x two 64-wide heads, dq for the whole
+        # sequence.
+        (12, 12, 64, 512, (None, 512, 128), (None, 1024, 128)),
+        # Grouped heads: ``block`` rows still, the key head's query
+        # heads side by side on the lanes (Mellum 2, ZAYA1, SambaY).
+        (32, 4, 128, 256, (None, 256, 1024), (None, 1024, 1024)),
+        (8, 2, 128, 512, (None, 512, 512), (None, 1024, 512)),
+        (40, 20, 64, 512, (None, 512, 256), (None, 1024, 256)),
+    ],
+)
+def test_kernels_take_query_heads_where_the_projection_left_them(
+    kernels_on_cpu, hq, hkv, d, block, q_block, dq_block
+):
+    """No operand of the kernels is transposed on its way in or out:
+    q, dO, out and dq are ``[B, S, Hq * D]`` — the free reshape of what
+    the projections and rotary hold — whatever the groups; k, v, dk and
+    dv ``[B, S, Hkv * D]``; only lse and delta, ``[B, S, Hq]`` float32,
+    are in the kernels' own row order."""
+    from tpfl.parallel.ring_attention import blockwise_attention
+
+    q = jnp.zeros((1, 1024, hq, d), jnp.bfloat16)
+    k = jnp.zeros((1, 1024, hkv, d), jnp.bfloat16)
+
+    def grads(q, k, v):
+        return jax.grad(lambda *x: jnp.sum(blockwise_attention(
+            *x, causal=True, block_size=block
+        ).astype(jnp.float32)), argnums=(0, 1, 2))(q, k, v)
+
+    from tpfl.parallel import flash_kernel
+
+    calls = {eqn.params["name"]: eqn for eqn in _kernel_calls(grads, q, k, k)}
+    forward, backward = calls[flash_kernel.FORWARD], calls[flash_kernel.BACKWARD]
+    # (grouped heads of whole lane tiles: delta by a third, small kernel)
+    assert len(calls) == 2 + (hq != hkv and d % 128 == 0)
+
+    def blocks(eqn):
+        return [tuple(
+            None if type(n).__name__ in ("Squeezed", "Mapped") else int(getattr(n, "block_size", n))
+            for n in m.block_shape
+        ) for m in eqn.params["grid_mapping"].block_mappings]
+
+    wide, narrow = (1, 1024, hq * d), (1, 1024, hkv * d)
+    assert [v.aval.shape for v in forward.invars] == [wide, narrow, narrow]
+    assert forward.outvars[0].aval.shape == wide
+    assert [v.aval.shape for v in backward.invars[:4]] == [wide, narrow, narrow, wide]
+    assert [v.aval.shape for v in backward.outvars] == [wide, narrow, narrow]
+    assert blocks(forward)[0] == blocks(forward)[3] == q_block
+    assert blocks(backward)[0] == blocks(backward)[3] == q_block
+    assert blocks(backward)[6] == dq_block
+    assert forward.params["grid_mapping"].grid == backward.params["grid_mapping"].grid
+
+
+def test_delta_rows_are_in_the_kernels_row_order():
+    """``flash_kernel.delta_rows`` gives ``rowsum(dO * O)`` of the
+    natural ``[B, S, Hq, Dv]`` arrays in the order the block loop keeps
+    its scalars in — what the XLA loop computes on grouped rows."""
+    from tpfl.parallel import flash_kernel
+    from tpfl.parallel.ring_attention import _grouped_rows, _natural_rows
+
+    do, out = jax.random.normal(jax.random.PRNGKey(5), (2, 2, 512, 8, 16))
+    got = flash_kernel.delta_rows(do, out, 128, 4)
+    rows = [_grouped_rows(x, 128, 4) for x in (do, out)]
+    want = jnp.moveaxis(jnp.sum(rows[0] * rows[1], axis=-1), 1, 2)
+    assert got.shape == (2, 2, 4 * 512)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    np.testing.assert_array_equal(
+        flash_kernel.delta_rows(do, out), jnp.moveaxis(jnp.sum(do * out, -1), 1, 2)
+    )
+    np.testing.assert_array_equal(_natural_rows(rows[0], 128, 4), do)
+    # Heads of whole lane tiles: the small kernel, from lanes.
+    do, out = jax.random.normal(jax.random.PRNGKey(6), (2, 1, 256, 8, 128))
+    calls = _kernel_calls(
+        lambda *x: flash_kernel.delta_rows(*x, 128, 4, interpret=True), do, out
+    )
+    assert [v.aval.shape for v in calls[0].invars] == [(1, 256, 1024)] * 2
+    got = flash_kernel.delta_rows(do, out, 128, 4, interpret=True)
+    rows = [_grouped_rows(x, 128, 4) for x in (do, out)]
+    want = jnp.moveaxis(jnp.sum(rows[0] * rows[1], axis=-1), 1, 2)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
 def test_ring_auto_takes_the_kernels_only_at_blocks_they_tile(kernels_on_cpu):

@@ -257,11 +257,40 @@ def rows_to_tokens_case(rows: int, d: int, tokens: int, groups: int, device) -> 
     return jax.jit(back), (sds((rows, d), jnp.bfloat16), sds((rows,)), runs, runs)
 
 
+def _expert_block_case(block, inputs: tuple, silos: int, device) -> tuple:
+    """(jitted fwd+bwd — gradients of the parameters and every input —
+    of ONE flax ``block`` with a ``moe_stats`` collection on ``inputs``
+    (ShapeDtypeStructs) under the engine's vmap over ``silos``, args)."""
+    variables = jax.eval_shape(
+        lambda *x: block.init(jax.random.PRNGKey(0), *x), *inputs
+    )
+    params = variables["params"]
+    stats = jax.tree_util.tree_map(
+        lambda a: jnp.zeros(a.shape, a.dtype), variables["moe_stats"]
+    )
+
+    def loss(params, *x):
+        def one(p, *x):
+            outs = block.apply({"params": p, "moe_stats": stats}, *x)
+            return sum(
+                jnp.sum(out.astype(jnp.float32) ** 2)
+                for out in jax.tree_util.tree_leaves(outs)
+            )
+
+        return jnp.sum(jax.vmap(one)(params, *x))
+
+    stacked = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct((silos, *a.shape), a.dtype),
+        (params, *inputs),
+    )
+    grads = jax.jit(jax.grad(loss, argnums=tuple(range(len(stacked)))))
+    return grads, _sds(stacked, SingleDeviceSharding(device))
+
+
 def zaya_block_case(silos: int, batch: int, seq: int, device) -> tuple:
-    """(jitted fwd+bwd of ONE ``ZayaBlock`` at ZAYA1-8B's published
-    widths — compressed convolutional attention, the MLP router with the
-    router state of the layer below, 8 of 16 experts held — under the
-    engine's vmap over ``silos``, args)."""
+    """ONE ``ZayaBlock`` at ZAYA1-8B's published widths — compressed
+    convolutional attention, the MLP router with the router state of the
+    layer below, 8 of 16 experts held (``_expert_block_case``)."""
     from tpfl.models.zaya import ZayaBlock
 
     block = ZayaBlock(
@@ -272,26 +301,26 @@ def zaya_block_case(silos: int, batch: int, seq: int, device) -> tuple:
     )
     x = jax.ShapeDtypeStruct((batch, seq, 2048), jnp.bfloat16)
     z = jax.ShapeDtypeStruct((batch, seq, 256), jnp.float32)
-    variables = jax.eval_shape(
-        lambda x, z: block.init(jax.random.PRNGKey(0), x, z), x, z
-    )
-    params = variables["params"]
-    stats = jax.tree_util.tree_map(
-        lambda a: jnp.zeros(a.shape, a.dtype), variables["moe_stats"]
-    )
+    return _expert_block_case(block, (x, z), silos, device)
 
-    def loss(params, x, z):
-        def one(p, x, z):
-            out, z = block.apply({"params": p, "moe_stats": stats}, x, z)
-            return jnp.sum(out.astype(jnp.float32) ** 2) + jnp.sum(z ** 2)
 
-        return jnp.sum(jax.vmap(one)(params, x, z))
+def mellum_block_case(silos: int, batch: int, seq: int, device) -> tuple:
+    """ONE banded ``MellumBlock`` at Mellum 2's published widths —
+    rotary positions, 8 query heads a key head, a window of 1024, 16 of
+    64 experts held — recomputed in the backward pass (``nn.remat``, as
+    ``MellumLM`` runs it; ``_expert_block_case``)."""
+    import flax.linen as nn
 
-    sharding = SingleDeviceSharding(device)
-    stacked = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct((silos, *a.shape), a.dtype), (params, x, z)
+    from tpfl.models.mellum import MellumBlock, MellumLM
+
+    block = nn.remat(MellumBlock)(
+        full=False, heads=32, kv_heads=4, head_dim=128, window=1024,
+        theta=500000.0, yarn=MellumLM.yarn, n_experts=64, top_k=8,
+        expert_dim=896, held_experts=16, first_expert=0, norm_eps=1e-6,
+        compute_dtype=jnp.bfloat16,
     )
-    return jax.jit(jax.grad(loss, argnums=(0, 1, 2))), _sds(stacked, sharding)
+    x = jax.ShapeDtypeStruct((batch, seq, 2304), jnp.bfloat16)
+    return _expert_block_case(block, (x,), silos, device)
 
 
 def scan_case(shape, states: int, silos: int, device) -> tuple:
@@ -500,6 +529,11 @@ def cases(devices) -> dict:
         "grouped_products_mellum_x2": lambda: grouped_products_case(
             98304, 32, 2304, 896, d0
         ),
+        # ... and ONE whole banded block as the cell runs it (rotary, the
+        # kernels on grouped heads as lanes, the expert layer, recomputed
+        # in the backward pass): what lies between the projections and
+        # the kernels is in its compiled text.
+        "mellum_block_x2": lambda: mellum_block_case(2, 2, 8192, d0),
         # ... and the way back to the 2 x 16384 tokens from that head and
         # from the buffer's rest (``moe_kernel``, PR 35: 64 token tiles of
         # 512 over 32 groups).

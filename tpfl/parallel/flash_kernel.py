@@ -5,9 +5,9 @@ The kernels behind :func:`tpfl.parallel.ring_attention.blockwise_attention`
 on a TPU (its XLA block loop is the path everywhere else, and what the
 kernels are tested against), behind :func:`flash_attention` and behind
 the flash ring's per-step inner. One family, static shapes differ:
-grouped query rows (``rows = groups * block`` query rows a key block, a
-key head's query heads side by side), a value width of its own, equal
-heads as the case ``groups = 1``.
+grouped query heads (``rows = groups * block`` query rows meet a key
+block: a key head's ``groups`` query heads), a value width of its own,
+equal heads as the case ``groups = 1``.
 
 Layout. The kernels index ``[B, S', H, D]`` as ``[B, S', H * D]``, and
 a program instance takes the fewest heads whose lanes are whole
@@ -22,6 +22,21 @@ hands over slices of a ``[.., 3 * H, D]`` array paid 4.3 ms a round on
 GPT-2. Inside, a head whose lanes are not whole tiles is taken by
 ZEROING the other heads' lanes of the resident operand: the contraction then adds exact
 zeros, and a 64-wide head half-fills the 128 x 128 MXU either way.
+
+**Grouped query heads are LANES** (PR 37): q, out, dO and dq are ``[B,
+S', H * groups * D]`` too, query head ``h`` on lanes ``[h * D, (h + 1) *
+D)`` as the projection and rotary left it, so a key head's ``groups``
+query heads are one run of ``groups * D`` lanes of a ``[block, heads *
+groups * D]`` block. A pair's tile still holds all of them, ``[block,
+rows]``: the ``groups`` lane slices of a q or dO block are stacked on
+sublanes IN VMEM (``_query_rows``; a query head narrower than a lane
+tile is rolled onto its key head's lanes first), and ``acc^T`` and dq
+are written back slice by slice. Only a query's scalars keep the tile's
+row order, a key head's query heads one after the other within a block:
+lse and delta, ``[B, H, S' * groups]`` float32 (``delta_rows``). Before,
+a transpose in HBM laid the heads of a group side by side as rows: 87 ms
+a round of copies on Mellum 2's cell (PERF.md §6, PR 37).
+
 ``jax.vmap`` (the engine's silos) becomes one more grid dimension, so
 no tile size depends on how many silos share the chip.
 
@@ -99,6 +114,9 @@ _DQ_BYTES = 64 * 1024 * 1024
 _TILE_BYTES = 4 * 1024 * 1024
 #: The kernels' names in a device trace (``attention_kernel_share_pct``).
 FORWARD, BACKWARD = "block_attention_forward", "block_attention_backward"
+#: ... and the small kernel beside them (``delta_rows``): not one of the
+#: block loop's two, so that share keeps reading what it read.
+DELTA = "attention_delta_rows"
 
 _NT = ((1,), (1,))  # a @ b^T
 _NN = ((1,), (0,))  # a @ b
@@ -224,24 +242,66 @@ def _lanes_of(h: int, width: int, shape: tuple):
     return (lane >= h * width) & (lane < (h + 1) * width)
 
 
-def _resident(plan: _Plan, x, h: int, width: int, pre: float = 1.0):
-    """Head ``h``'s operand from a block that stays for a whole sweep,
-    times ``pre``: its own lanes where they are whole tiles, else the
-    block with the other heads' lanes zeroed (module docstring)."""
+def _only(plan: _Plan, x, h: int, width: int, pre: float = 1.0):
+    """Head ``h``'s operand ``x`` times ``pre``, fit to stay for a whole
+    sweep: where heads share lane tiles the other heads' lanes are
+    zeroed (module docstring)."""
     if plan.own_tiles(width):
-        x = x[:, h * width:(h + 1) * width]
         return x if pre == 1.0 else (x.astype(F32) * pre).astype(x.dtype)
     wide = x.astype(F32) * pre
     return jnp.where(_lanes_of(h, width, x.shape), wide, 0.0).astype(x.dtype)
 
 
+def _resident(plan: _Plan, x, h: int, width: int, pre: float = 1.0):
+    """Head ``h``'s operand from a key-side block that stays for a whole
+    sweep, times ``pre``: its own lanes where they are whole tiles, else
+    the block with the other heads' lanes zeroed."""
+    return _only(plan, _streamed(plan, x, h, width), h, width, pre)
+
+
 def _streamed(plan: _Plan, x, h: int, width: int):
-    """Head ``h``'s operand from a block that changes every step: its
-    own lanes where they are whole tiles, else the block as it is (the
-    resident operand it meets has the other heads zeroed)."""
+    """Head ``h``'s operand from a key-side block that changes every
+    step: its own lanes where they are whole tiles, else the block as it
+    is (the resident operand it meets has the other heads zeroed)."""
     if plan.own_tiles(width):
         return x[:, h * width:(h + 1) * width]
     return x
+
+
+def _group_lanes(plan: _Plan, h: int, g: int, width: int):
+    """Where query head ``g`` of key head ``h`` lies in a query-side
+    block ``[block, heads * groups * width]``: ``(the lanes of its
+    operand, how far to roll them)``. A head of whole lane tiles is its
+    own operand; a narrower one comes with the heads that share its
+    ``heads * width`` lanes, rolled until it stands on key head ``h``'s
+    lanes — where the key-side operand it meets is not zero."""
+    head = h * plan.groups + g
+    if plan.own_tiles(width):
+        return slice(head * width, (head + 1) * width), 0
+    wide = plan.heads * width
+    at = head * width // wide * wide
+    return slice(at, at + wide), (h - head) % plan.heads * width
+
+
+def _query_rows(plan: _Plan, x, h: int, width: int):
+    """Key head ``h``'s query rows ``[rows, operand width]`` from a
+    query-side block (q, dO): its ``groups`` query heads are runs of
+    LANES there, as the projection left them, and are stacked on
+    sublanes here, in VMEM. With equal heads this is ``_streamed``."""
+    if plan.groups == 1:
+        return _streamed(plan, x, h, width)
+    runs = []
+    for g in range(plan.groups):
+        lanes, shift = _group_lanes(plan, h, g, width)
+        run = x[:, lanes]
+        runs.append(_rolled(run, shift) if shift else run)
+    return jnp.concatenate(runs, axis=0)
+
+
+def _rolled(x, shift: int):
+    """``x`` with its lanes rolled ``shift`` to the right (the chip
+    rotates 32-bit lanes alone)."""
+    return pltpu.roll(x.astype(F32), shift, 1).astype(x.dtype)
 
 
 def _add(plan: _Plan, ref, h: int, width: int, value) -> None:
@@ -317,7 +377,9 @@ def _forward_kernel(
     def _():
         q = q_ref[...]
         for h in range(plan.heads):
-            qh_scr[h] = _resident(plan, q, h, plan.d, plan.pre)
+            qh_scr[h] = _only(
+                plan, _query_rows(plan, q, h, plan.d), h, plan.d, plan.pre
+            )
         acc_scr[...] = jnp.zeros_like(acc_scr)
         m_scr[...] = jnp.full_like(m_scr, _NEG)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -365,7 +427,14 @@ def _forward_kernel(
             lse_ref[h:h + 1, :] = jnp.where(
                 l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), 1e30
             )
-        o_ref[...] = jnp.transpose(acc_scr[...]).astype(o_ref.dtype)
+        if plan.groups == 1:
+            o_ref[...] = jnp.transpose(acc_scr[...]).astype(o_ref.dtype)
+        else:  # a query head's values go back to its own lanes of the block
+            for head in range(plan.heads * plan.groups):
+                h, g = divmod(head, plan.groups)
+                o_ref[:, head * d_v:(head + 1) * d_v] = jnp.transpose(acc_scr[
+                    h * d_v:(h + 1) * d_v, g * plan.block:(g + 1) * plan.block
+                ]).astype(o_ref.dtype)
 
 
 def _backward_kernel(
@@ -394,10 +463,10 @@ def _backward_kernel(
     def pair(masked: bool):
         q, do = q_ref[...], do_ref[...]
         seen = _visible(plan, i, j) if masked else None
-        at = pl.ds(pl.multiple_of(i * plan.rows, plan.rows), plan.rows)
+        at = pl.ds(pl.multiple_of(i * plan.block, plan.block), plan.block)
         for h in range(plan.heads):
-            q_h = _streamed(plan, q, h, plan.d)
-            do_h = _streamed(plan, do, h, plan.d_v)
+            q_h = _query_rows(plan, q, h, plan.d)
+            do_h = _query_rows(plan, do, h, plan.d_v)
             k_h = kh_scr[h]
             s = _dot(k_h, q_h, _NT)  # [block, rows]: keys x queries
             if not plan.fold:
@@ -410,11 +479,14 @@ def _backward_kernel(
             _add(plan, dv_scr, h, plan.d_v, _dot(p.astype(lowp), do_h, _NN))
             _add(plan, dk_scr, h, plan.d, _dot(ds, q_h, _NN))
             # dS^T K: the other heads' lanes of k_h are zero, so are dq's.
+            # A query head's rows go back to the lanes q has it on.
             dq = _dot(ds, k_h, _TN)
-            if plan.own_tiles(plan.d):
-                dq_scr[at, h * plan.d:(h + 1) * plan.d] += dq
-            else:
-                dq_scr[at, :] += dq
+            for g in range(plan.groups):
+                lanes, shift = _group_lanes(plan, h, g, plan.d)
+                run = dq[g * plan.block:(g + 1) * plan.block]
+                if shift:
+                    run = _rolled(run, run.shape[1] - shift)
+                dq_scr[at, lanes] += run
 
     _on_pairs(plan, i, j, last_j, pair)
 
@@ -451,14 +523,17 @@ def attention_forward(
     window: Optional[int] = None, interpret: bool = False, out_dtype=None,
 ):
     """Padded k ``[B, nb * block, H, D]``, v ``[.., Dv]`` and q ``[B, nb *
-    rows, H, D]`` with ``rows = groups * block`` (the layout of
-    ``ring_attention._blockwise_fwd_core``) -> ``(out [B, nb * rows, H,
-    Dv], lse [B, H, nb * rows] float32)``. ``window`` (causal only): the
-    band ``0 <= q - k < window``."""
+    block, H * groups, D]`` (query head ``h`` reads key head ``h //
+    groups``) -> ``(out [B, nb * block, H * groups, Dv], lse [B, H, nb *
+    rows] float32)``, lse in the kernels' own row order: a key head's
+    ``rows = groups * block`` query rows a block, its query heads one
+    after the other (``delta_rows``). ``window`` (causal only): the band
+    ``0 <= q - k < window``."""
     plan = _plan(k, v, causal, block, s_len, groups, window)
     b, _, h, _ = k.shape
     n, rows, heads = plan.n_blocks, plan.rows, plan.heads
     w, w_v = heads * plan.d, heads * plan.d_v
+    query = lambda bi, hi, i, j: (bi, i, hi)  # noqa: E731
 
     def key_block(bi, hi, i, t):
         if window is not None:  # steps before the sequence refetch block 0
@@ -468,17 +543,19 @@ def attention_forward(
     out, lse = pl.pallas_call(
         functools.partial(_forward_kernel, plan=plan),
         out_shape=[
-            jax.ShapeDtypeStruct((b, n * rows, h * plan.d_v), out_dtype or q.dtype),
+            jax.ShapeDtypeStruct(
+                (b, n * block, groups * h * plan.d_v), out_dtype or q.dtype
+            ),
             jax.ShapeDtypeStruct((b, h // heads, heads, n * rows), F32),
         ],
         grid=(b, h // heads, n, plan.sweep),
         in_specs=[
-            pl.BlockSpec((None, rows, w), lambda bi, hi, i, j: (bi, i, hi)),
+            pl.BlockSpec((None, block, groups * w), query),
             pl.BlockSpec((None, block, w), key_block),
             pl.BlockSpec((None, block, w_v), key_block),
         ],
         out_specs=[
-            pl.BlockSpec((None, rows, w_v), lambda bi, hi, i, j: (bi, i, hi)),
+            pl.BlockSpec((None, block, groups * w_v), query),
             pl.BlockSpec(
                 (None, None, heads, rows), lambda bi, hi, i, j: (bi, hi, 0, i)
             ),
@@ -493,7 +570,8 @@ def attention_forward(
         interpret=interpret,
         name=FORWARD,
     )(_lanes(q), _lanes(k), _lanes(v))
-    return out.reshape(b, n * rows, h, plan.d_v), lse.reshape(b, h, n * rows)
+    out = out.reshape(b, n * block, groups * h, plan.d_v)
+    return out, lse.reshape(b, h, n * rows)
 
 
 def attention_backward(
@@ -502,9 +580,10 @@ def attention_backward(
     grad_dtype=None,
 ):
     """dq, dk, dv of :func:`attention_forward`'s operands from its
-    ``lse``, the cotangent ``do`` and ``delta = rowsum(do * out)`` ``[B,
-    H, nb * rows]`` float32 (both may cover MORE keys than this call
-    sees: the ring's are the whole row's)."""
+    ``lse``, the cotangent ``do`` (laid out as ``out``) and ``delta =
+    rowsum(do * out)`` in lse's row order (``delta_rows``), ``[B, H, nb *
+    rows]`` float32 (both may cover MORE keys than this call sees: the
+    ring's are the whole row's)."""
     plan = _plan(k, v, causal, block, s_len, groups, window)
     b, _, h, _ = k.shape
     n, rows, heads = plan.n_blocks, plan.rows, plan.heads
@@ -531,21 +610,25 @@ def attention_backward(
     dq, dk, dv = pl.pallas_call(
         functools.partial(_backward_kernel, plan=plan),
         out_shape=[
-            jax.ShapeDtypeStruct((b, n * rows, h * plan.d), grad_dtype or q.dtype),
+            jax.ShapeDtypeStruct(
+                (b, n * block, groups * h * plan.d), grad_dtype or q.dtype
+            ),
             jax.ShapeDtypeStruct((b, n * block, h * plan.d), grad_dtype or k.dtype),
             jax.ShapeDtypeStruct((b, n * block, h * plan.d_v), grad_dtype or v.dtype),
         ],
         grid=(b, h // heads, n, plan.sweep),
         in_specs=[
-            pl.BlockSpec((None, rows, w), query_block),
+            pl.BlockSpec((None, block, groups * w), query_block),
             pl.BlockSpec((None, block, w), key_block),
             pl.BlockSpec((None, block, w_v), key_block),
-            pl.BlockSpec((None, rows, w_v), query_block),
+            pl.BlockSpec((None, block, groups * w_v), query_block),
             per_row, per_row,
         ],
         out_specs=[
             # One head block's dq for the whole sequence stays in VMEM.
-            pl.BlockSpec((None, n * rows, w), lambda bi, hi, j, i: (bi, 0, hi)),
+            pl.BlockSpec(
+                (None, n * block, groups * w), lambda bi, hi, j, i: (bi, 0, hi)
+            ),
             pl.BlockSpec((None, block, w), key_block),
             pl.BlockSpec((None, block, w_v), key_block),
         ],
@@ -554,7 +637,7 @@ def attention_backward(
             pltpu.VMEM((heads, block, plan.operand_width(plan.d_v)), v.dtype),
             pltpu.VMEM((block, w), F32),
             pltpu.VMEM((block, w_v), F32),
-            pltpu.VMEM((n * rows, w), F32),
+            pltpu.VMEM((n * block, groups * w), F32),
         ],
         compiler_params=_params("parallel", "parallel", "arbitrary", "arbitrary"),
         interpret=interpret,
@@ -567,12 +650,56 @@ def attention_backward(
 # --- entry points ------------------------------------------------------------
 
 
-def _delta(do, out):
-    """``rowsum(dO * O)`` as ``[B, H, S]`` float32: the softmax
-    derivative's correction term."""
-    return jnp.moveaxis(
-        jnp.sum(do.astype(F32) * out.astype(F32), axis=-1), 1, 2
-    )
+def _delta_kernel(do_ref, o_ref, delta_ref, *, groups: int, d_v: int):
+    """One key head's ``[1, rows]`` of delta from its query heads' lanes
+    of a dO and an O block: a head's row sums, turned to lie along the
+    lanes, one head after the other."""
+    prod = do_ref[...].astype(F32) * o_ref[...].astype(F32)
+    delta_ref[...] = jnp.concatenate([
+        jnp.sum(
+            jnp.transpose(prod[:, g * d_v:(g + 1) * d_v]), axis=0, keepdims=True
+        )
+        for g in range(groups)
+    ], axis=1)
+
+
+def delta_rows(
+    do, out, block: int = 0, groups: int = 1, interpret: bool = False
+):
+    """``rowsum(dO * O)`` of ``[B, S, H * groups, Dv]`` as ``[B, H, S *
+    groups]`` float32, the softmax derivative's correction term, in the
+    row order the kernels keep their scalars in: within each ``block``
+    of positions a key head's query heads one after the other — the only
+    array that is ever rearranged for the groups. Grouped heads of whole
+    lane tiles are summed by one more small kernel, which reads dO and O
+    where they lie, ``[B, S, Hq * Dv]``: XLA's sum would first copy
+    their float32 product, 0.5 GB in Mellum 2's cell, into the ``[..,
+    Hq, Dv]`` tiling (2.7 ms a layer against 0.72, PERF.md §6, PR 37).
+    Equal heads keep XLA's sum, and their program what it was."""
+    b, s, hq, d_v = do.shape
+    h = hq // groups
+    if groups > 1 and d_v % LANES == 0:
+        rows = groups * block
+        query = pl.BlockSpec(
+            (None, block, groups * d_v), lambda bi, hi, i: (bi, i, hi)
+        )
+        return pl.pallas_call(
+            functools.partial(_delta_kernel, groups=groups, d_v=d_v),
+            out_shape=jax.ShapeDtypeStruct((b, h, 1, s * groups), F32),
+            grid=(b, h, s // block),
+            in_specs=[query, query],
+            out_specs=pl.BlockSpec(
+                (None, None, 1, rows), lambda bi, hi, i: (bi, hi, 0, i)
+            ),
+            compiler_params=_params("parallel", "parallel", "parallel"),
+            interpret=interpret,
+            name=DELTA,
+        )(_lanes(do), _lanes(out)).reshape(b, h, -1)
+    delta = jnp.sum(do.astype(F32) * out.astype(F32), axis=-1)
+    if groups == 1:
+        return jnp.moveaxis(delta, 1, 2)
+    delta = delta.reshape(b, s // block, block, h, groups)
+    return delta.transpose(0, 3, 1, 4, 2).reshape(b, h, -1)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
@@ -590,7 +717,7 @@ def _flash_fwd(q, k, v, causal, block, s_len, interpret):
 def _flash_bwd(causal, block, s_len, interpret, res, do):
     q, k, v, out, lse = res
     return attention_backward(
-        q, k, v, do, lse, _delta(do, out), causal=causal, block=block,
+        q, k, v, do, lse, delta_rows(do, out), causal=causal, block=block,
         s_len=s_len, interpret=interpret,
     )
 

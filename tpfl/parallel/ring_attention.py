@@ -91,10 +91,13 @@ def blockwise_attention(
     v: [B, S, Hkv, Dv] -> [B, S, Hq, Dv].
 
     **Grouped key/value heads**: ``Hq`` may be a multiple of ``Hkv``;
-    query head ``h`` reads key/value head ``h // (Hq // Hkv)``. The
-    group's query heads are laid side by side as extra query ROWS of
-    their key/value head's block, so the block loop and its matmuls are
-    the equal-head ones with taller tiles. **Value width**: ``Dv`` need
+    query head ``h`` reads key/value head ``h // (Hq // Hkv)``. A
+    group's query heads meet their key/value head's block together, as
+    extra query ROWS of one tile, so the block loop and its matmuls are
+    the equal-head ones with taller tiles: the kernels stack them in
+    VMEM from where they lie, lanes of ``[B, S, Hq * D]``; the XLA loop
+    lays them side by side in HBM first (``_grouped_rows``, a
+    transpose). **Value width**: ``Dv`` need
     not be ``D``. **Band**: ``window`` (causal only) keeps the keys with
     ``0 <= q - k < window``; key blocks wholly outside the band are
     skipped on both sides. ``k`` / ``v`` may come from anywhere (another
@@ -119,8 +122,10 @@ def blockwise_attention(
     unaligned length too — it is the XLA loop below
     (``_blockwise_fwd_core`` / ``_blockwise_vjp_bwd``, a few key heads a
     backward step), which is also what the kernels are tested against.
-    Both run under the named scope ``block_attention``; padding, the
-    grouped-row layout and what the forward banks are the same.
+    Both run under the named scope ``block_attention`` inside ONE
+    ``custom_vjp`` that takes q and returns out and dq in the layout the
+    caller holds them in; padding, the row order of the banked lse and
+    what else the forward banks are the same.
 
     **The block** a call that names none gets is the largest of 512 /
     256 / 128 that the kernels admit for its shapes
@@ -132,7 +137,7 @@ def blockwise_attention(
     v and dO as they arrive, and accumulate in float32; the running max,
     denominator, ``acc``, lse and delta are float32. float32 inputs
     compute in float32 throughout."""
-    b, s, hq, d = q.shape
+    s, hq = q.shape[1:3]
     hkv = k.shape[2]
     groups = hq // hkv
     if groups * hkv != hq or v.shape[2] != hkv:
@@ -151,15 +156,27 @@ def blockwise_attention(
         q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    if groups > 1:
-        # [B, nb, block, Hkv, G, D] -> [B, nb, G * block, Hkv, D]
-        q = q.reshape(b, n_blocks, block, hkv, groups, d)
-        q = jnp.moveaxis(q, 4, 2).reshape(b, -1, hkv, d)
-    out = _blockwise(q, k, v, causal, block, s, groups, window)
-    if groups > 1:
-        out = out.reshape(b, n_blocks, groups, block, hkv, -1)
-        out = jnp.moveaxis(out, 2, 4).reshape(b, n_blocks * block, hq, -1)
-    return out[:, :s]
+    return _blockwise(q, k, v, causal, block, s, groups, window)[:, :s]
+
+
+def _grouped_rows(x, block: int, groups: int):
+    """``[B, nb * block, H * G, D] -> [B, nb * G * block, H, D]``: a key
+    head's ``G`` query heads side by side as extra ROWS of its block (a
+    transpose: the XLA loop's layout alone)."""
+    if groups == 1:
+        return x
+    b, sp, hq, d = x.shape
+    x = x.reshape(b, sp // block, block, hq // groups, groups, d)
+    return jnp.moveaxis(x, 4, 2).reshape(b, -1, hq // groups, d)
+
+
+def _natural_rows(x, block: int, groups: int):
+    """``_grouped_rows`` undone."""
+    if groups == 1:
+        return x
+    b, _, h, d = x.shape
+    x = x.reshape(b, -1, groups, block, h, d)
+    return jnp.moveaxis(x, 2, 4).reshape(b, -1, h * groups, d)
 
 
 def _bw_mask(q_idx, k_idx, s_len: int, causal: bool, window=None):
@@ -231,11 +248,14 @@ def _blockwise_fwd_core(
     q, k, v, causal: bool, block: int, s_len: int, groups: int = 1,
     window=None,
 ):
-    """Padded k [B, nb·block, H, D], v [.., Dv] and q [B, nb·rows, H, D]
-    with ``rows = groups·block`` query rows a block (a key head's
-    ``groups`` query heads side by side) -> (out [B, nb·rows, H, Dv],
-    lse [B, H, nb·rows]). lse rows with no visible key get +LARGE so
-    the backward's exp(s - lse) is exactly 0 for them."""
+    """Padded k [B, nb·block, H, D], v [.., Dv] and q [B, nb·block, H·G,
+    D] -> (out [B, nb·block, H·G, Dv], lse [B, H, nb·rows]) with ``rows
+    = groups·block`` query rows a block: lse is in the block loop's own
+    row order, a key head's ``groups`` query heads one after the other
+    within a block. The kernels read q's heads where they lie, as lanes;
+    the XLA loop lays them side by side as rows first
+    (``_grouped_rows``). lse rows with no visible key get +LARGE so the
+    backward's exp(s - lse) is exactly 0 for them."""
     b, sp, h, d = k.shape
     d_v = v.shape[-1]
     n_blocks = sp // block
@@ -246,6 +266,7 @@ def _blockwise_fwd_core(
             q, k, v, causal=causal, block=block, s_len=s_len, groups=groups,
             window=window, interpret=compat.pallas_interpret(None),
         )
+    q = _grouped_rows(q, block, groups)
     rows = groups * block
     qb = q.reshape(b, n_blocks, rows, h, d)
     kb = k.reshape(b, n_blocks, block, h, d)
@@ -289,7 +310,7 @@ def _blockwise_fwd_core(
     out = jnp.moveaxis(blocks, 0, 1).reshape(b, n_blocks * rows, h, d_v)
     # lses: [nb, B, H, rows] -> [B, H, nb, rows] -> [B, H, S']
     lse = jnp.moveaxis(lses, 0, 2).reshape(b, h, n_blocks * rows)
-    return out.astype(q.dtype), lse
+    return _natural_rows(out.astype(q.dtype), block, groups), lse
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -333,10 +354,11 @@ def _heads_per_step(b: int, h: int, rows: int, block: int) -> int:
 
 @jax.named_scope("block_attention")
 def _blockwise_vjp_bwd(causal, block, s_len, groups, window, res, g):
-    """dq, dk, dv of padded, grouped-row q / k / v (``_blockwise_fwd_core``'s
-    shapes) from the forward's ``out`` and ``lse`` and the cotangent ``g``:
-    a flash-style recompute backward in ONE sweep (FlashAttention-2's, at
-    the XLA level). Each visible (query block i, key block j) pair is
+    """dq, dk, dv of padded q / k / v (``_blockwise_fwd_core``'s shapes)
+    from the forward's ``out`` and ``lse`` and the cotangent ``g``: the
+    kernels' backward where ``_kernels`` takes the shape, else, on
+    grouped rows, a flash-style recompute backward in ONE sweep
+    (FlashAttention-2's, at the XLA level). Each visible (query block i, key block j) pair is
     visited once, ``_heads_per_step`` key heads a step (heads never mix):
     S, P = exp(S - lse), dP and dS are computed once and feed all three of
     dQ, dK, dV — 5 tile matmuls a step. The outer loop runs over key
@@ -354,18 +376,20 @@ def _blockwise_vjp_bwd(causal, block, s_len, groups, window, res, g):
     d_v = v.shape[-1]
     n_blocks = sp // block
     rows = groups * block
+    if _kernels(k, v, block, groups):
+        from tpfl.parallel import flash_kernel
+
+        interpret = compat.pallas_interpret(None)
+        delta = flash_kernel.delta_rows(g, out, block, groups, interpret)
+        return flash_kernel.attention_backward(
+            q, k, v, g, lse, delta, causal=causal, block=block, s_len=s_len,
+            groups=groups, window=window, interpret=interpret,
+        )
+    q, out, g = (_grouped_rows(x, block, groups) for x in (q, out, g))
     delta = jnp.moveaxis(
         jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1),
         1, 2,
     )  # [B, H, S']
-    if _kernels(k, v, block, groups):
-        from tpfl.parallel import flash_kernel
-
-        return flash_kernel.attention_backward(
-            q, k, v, g, lse, delta, causal=causal, block=block, s_len=s_len,
-            groups=groups, window=window,
-            interpret=compat.pallas_interpret(None),
-        )
     heads = _heads_per_step(b, h, rows, block)
     chunks = h // heads
     scale = 1.0 / jnp.sqrt(d)
@@ -434,7 +458,7 @@ def _blockwise_vjp_bwd(causal, block, s_len, groups, window, res, g):
         return jnp.moveaxis(x, 0, 1).reshape(b, -1, *x.shape[3:])
 
     return (
-        dq.reshape(q.shape).astype(q.dtype),
+        _natural_rows(dq.reshape(q.shape).astype(q.dtype), block, groups),
         unblk(dk).astype(k.dtype),
         unblk(dv).astype(v.dtype),
     )
